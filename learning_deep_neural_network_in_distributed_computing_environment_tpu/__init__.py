@@ -32,17 +32,4 @@ __version__ = "0.1.0"
 # users; keep the package root import cheap (no jax import at package import
 # time so that tests can set XLA_FLAGS first).
 
-__all__ = [
-    "__version__",
-    "shard_map",
-]
-
-
-def __getattr__(name):
-    # lazy: ``ldnde_tpu.shard_map`` resolves the JAX-version compat shim
-    # (jax.shard_map, or the experimental one on legacy JAX) without making
-    # the package root import jax eagerly
-    if name == "shard_map":
-        from .compat import shard_map
-        return shard_map
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+__all__ = ["__version__"]

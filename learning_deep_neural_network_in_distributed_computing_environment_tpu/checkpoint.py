@@ -1028,7 +1028,7 @@ def _relayout_round_opt(key: str, val: np.ndarray,
 def _reshard_leaf(tmpl, val):
     if isinstance(tmpl, jax.Array) and hasattr(tmpl, "sharding"):
         # .copy() materializes an XLA-owned buffer: device_put of host
-        # numpy on jax 0.4.x XLA:CPU can ZERO-COPY (the jax.Array aliases
+        # numpy on XLA:CPU can ZERO-COPY (the jax.Array aliases
         # numpy-owned malloc memory), and the round program then DONATES
         # that buffer — XLA freeing memory it never allocated corrupts
         # the heap (reproducible segfault: resume + a warm persistent

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .compat import axis_size, optimization_barrier, psum_scatter, shard_map
 from .mesh import DATA_AXIS, SLICE_AXIS
 
 PyTree = Any
@@ -103,7 +102,7 @@ def aggregate(tree: PyTree, *, how: str = "equal",
         raise ValueError(f"how must be one of {HOWS}, got {how!r}")
     if topology not in TOPOLOGIES:
         raise ValueError(f"topology must be one of {TOPOLOGIES}, got {topology!r}")
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         if poison is not None:
             ok1 = _contribution_ok(
@@ -1021,7 +1020,7 @@ def resident_gather(shards: dict, per_worker_template: PyTree, *,
     all_gather used to move, so entry-gather(exit-scatter) reproduces
     the replicated twin's tree bit-for-bit."""
     leaves, treedef = jax.tree_util.tree_flatten(per_worker_template)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     out: list = [None] * len(leaves)
     plan = bucket_plan(leaves, n, bucket_bytes)
     for i, b in enumerate(plan):
@@ -1067,8 +1066,8 @@ def make_resident_gather(mesh, per_worker_template: PyTree, *,
             tree = resident_gather(sq, per_worker_template,
                                    bucket_bytes=bucket_bytes)
             return jax.tree_util.tree_map(lambda x: x[None], tree)
-        return shard_map(inner, mesh=mesh, in_specs=(spec,),
-                         out_specs=spec)(shards)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec)(shards)
 
     return jax.jit(_gather, donate_argnums=(0,) if donate else ())
 
@@ -1168,7 +1167,7 @@ def sharded_opt_sync(tree: PyTree, *, how: str = "equal",
             f"opt_placement={opt_placement!r}; config.py resolves these "
             "combinations to the replicated residency)")
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if buddy and n < 2:
         raise ValueError(
             "buddy redundancy needs a worker axis of size >= 2 (a lone "
@@ -1269,7 +1268,7 @@ def sharded_opt_sync(tree: PyTree, *, how: str = "equal",
             else:
                 shard32 = jnp.sum(pieces.astype(jnp.float32), axis=0)
         else:
-            shard32 = psum_scatter(sent, axis_name, scatter_dimension=0,
+            shard32 = lax.psum_scatter(sent, axis_name, scatter_dimension=0,
                                    tiled=True).astype(jnp.float32)
         track32 = None   # fp32 mean the round-optimizer tracker consumes
         if how == "equal":
@@ -1508,7 +1507,7 @@ def gossip_sync(tree: PyTree, *, topology: str, how: str = "equal",
     if how not in HOWS:
         raise ValueError(f"how must be one of {HOWS}, got {how!r}")
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if not leaves or n == 1:
         if poison is not None:
             ok1 = _contribution_ok(poison, leaves, None)
@@ -1598,7 +1597,7 @@ def gossip_sync(tree: PyTree, *, topology: str, how: str = "equal",
             # the barrier keeps XLA from serializing the shift-2
             # collective behind the shift-1 blend, so the second hop's
             # wire time overlaps the first hop's arithmetic
-            h1, h2 = optimization_barrier((hop(1), hop(2)))
+            h1, h2 = lax.optimization_barrier((hop(1), hop(2)))
             r1, r2 = dec(h1), dec(h2)
             # exact dense expressions (comms.aggregate per_leaf) for the
             # fp32 bit-identity guarantee
@@ -1709,8 +1708,8 @@ def aggregate_hier(tree: PyTree, *, topology: str, how: str = "equal",
             "level is the flat S*W engine)")
     if how not in HOWS:
         raise ValueError(f"how must be one of {HOWS}, got {how!r}")
-    nw = axis_size(inner_axis)
-    ns = axis_size(outer_axis)
+    nw = lax.axis_size(inner_axis)
+    ns = lax.axis_size(outer_axis)
     w = local_weight
 
     def per_leaf(x: jnp.ndarray) -> jnp.ndarray:
@@ -1835,8 +1834,8 @@ def hierarchical_sync(tree: PyTree, *, topology: str, how: str = "equal",
             "per-worker state (config.py resolves weighted to the "
             "replicated residency)")
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    nw = axis_size(inner_axis)
-    ns = axis_size(outer_axis)
+    nw = lax.axis_size(inner_axis)
+    ns = lax.axis_size(outer_axis)
     if nw < 2:
         raise ValueError(
             "the hierarchical sync needs an inner worker axis of size "
@@ -1890,7 +1889,7 @@ def hierarchical_sync(tree: PyTree, *, topology: str, how: str = "equal",
             else:
                 shard32 = jnp.sum(pieces.astype(jnp.float32), axis=0)
         else:
-            shard32 = psum_scatter(sent, inner_axis, scatter_dimension=0,
+            shard32 = lax.psum_scatter(sent, inner_axis, scatter_dimension=0,
                                    tiled=True).astype(jnp.float32)
         # ---- the slice mean on the shard: worker-invariant WITHIN the
         # slice, which is what lets the outer hop ride the shard ----
@@ -1938,7 +1937,7 @@ def hierarchical_sync(tree: PyTree, *, topology: str, how: str = "equal",
             # both shifts issued before either blend term is consumed
             # (the PR 4 double-ring overlap fence): the shift-2 hop's
             # DCN time rides under the shift-1 blend
-            h1, h2 = optimization_barrier((hop(1), hop(2)))
+            h1, h2 = lax.optimization_barrier((hop(1), hop(2)))
             r1, r2 = dec(h1), dec(h2)
             g32 = (m32 + r1 + r2) / 3.0 if how == "equal" \
                 else w * m32 + ((1.0 - w) / 2.0) * (r1 + r2)
@@ -2021,8 +2020,9 @@ def make_hier_host_sync(mesh, *, topology: str, how: str = "equal",
                 outer_residual=sq(ores), bucket_bytes=bucket_bytes,
                 residency=residency)
             return tuple(ex(o) for o in outs)
-        return shard_map(inner, mesh=mesh, in_specs=(spec,) * 3,
-                         out_specs=(spec,) * 3)(tree, residual, outer_res)
+        return jax.shard_map(
+            inner, mesh=mesh, in_specs=(spec,) * 3,
+            out_specs=(spec,) * 3)(tree, residual, outer_res)
 
     jitted = jax.jit(_sync)
 
@@ -2047,7 +2047,7 @@ def make_hier_host_aggregator(mesh, *, topology: str, how: str = "equal",
             out = aggregate_hier(squeezed, topology=topology, how=how,
                                  local_weight=local_weight)
             return jax.tree_util.tree_map(lambda x: x[None], out)
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(spec,), out_specs=spec)(tree)
 
     return jax.jit(_agg)
@@ -2166,11 +2166,11 @@ def make_host_sync(mesh, *, mode: str = "sharded", how: str = "equal",
                     **{k: ex(v) for k, v in extra.items()}}
         n_in = 4 if (buddy_on or screen) else 3
         args = (tree, residual, tracker, poison)[:n_in]
-        return shard_map(inner if n_in == 4 else
-                         (lambda a, b, c: inner(a, b, c, None)),
-                         mesh=mesh, in_specs=(spec,) * n_in,
-                         out_specs=spec if (buddy_on or screen)
-                         else (spec, spec, spec))(*args)
+        return jax.shard_map(inner if n_in == 4 else
+                             (lambda a, b, c: inner(a, b, c, None)),
+                             mesh=mesh, in_specs=(spec,) * n_in,
+                             out_specs=spec if (buddy_on or screen)
+                             else (spec, spec, spec))(*args)
 
     jitted = jax.jit(_sync)
 
@@ -2216,7 +2216,7 @@ def make_host_aggregator(mesh, *, how: str, topology: str,
             out = aggregate(squeezed, how=how, topology=topology,
                             local_weight=local_weight)
             return jax.tree_util.tree_map(lambda x: x[None], out)
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(spec,), out_specs=spec)(tree)
 
     return jax.jit(_agg)

@@ -265,14 +265,15 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
             "the training driver never runs the serve engine; drop the "
             "flags from this run")
     initialize_distributed()
-    from .xla_flags import compile_cache_counts, install_cache_counter
+    from .xla_flags import (compile_cache_counts, install_cache_counter,
+                            setup_compile_cache)
     if cfg.compile_cache_dir:
-        # persistent XLA compilation cache: bench/test/multi-run
-        # invocations on the same host stop paying round-program recompiles
-        from .xla_flags import setup_compile_cache
-        setup_compile_cache(cfg.compile_cache_dir)
-    # hit/miss telemetry even when the cache was armed earlier (CLI) or is
-    # off (counts then stay zero); the per-run delta lands in results
+        # persistent XLA compilation cache, at the one directory
+        # xla_flags.compile_cache_dir() names: repeated runs on one
+        # machine stop paying the round-program compiles
+        setup_compile_cache()
+    # hit/miss telemetry even when the cache is off (counts then stay
+    # zero); the per-run delta lands in results
     install_cache_counter()
     cache_counts0 = compile_cache_counts()
     # --- runtime sanitizer (ISSUE 6) -----------------------------------
@@ -287,14 +288,10 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                            "transfer_guard_violations": 0,
                            "retrace_count": 0, "recompile_count": 0,
                            "donation_failures": 0}
-    san_counter_ok = False
     san_warmup: dict | None = None
     if sanitize:
         from .xla_flags import compile_event_counts, install_compile_counter
-        san_counter_ok = install_compile_counter()
-        if not san_counter_ok:
-            log.warning("sanitizer: trace/compile monitoring unavailable "
-                        "on this jax — the retrace budget is not enforced")
+        install_compile_counter()
     # --- scenario lab (ISSUE 14) ---------------------------------------
     # --sim_workers N simulates the whole worker axis as one vmap'd jit
     # on a single chip (sim.SimEngine); the orchestration loop below is
@@ -987,11 +984,10 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     # --- optional profiler trace (beyond-reference, SURVEY.md section 5) --
     profiling = False
     if cfg.profile_dir:
-        try:
-            jax.profiler.start_trace(cfg.profile_dir)
-            profiling = True
-        except Exception as e:  # some PJRT plugins lack profiler support
-            log.warning("profiler unavailable: %s", e)
+        # a requested trace that cannot start is an error, not a warning:
+        # a run that was asked to be measured must not finish unmeasured
+        jax.profiler.start_trace(cfg.profile_dir)
+        profiling = True
 
     # --- the overlapped round pipeline ----------------------------------
     # Every round is dispatched asynchronously; the metric fetch + assembly
@@ -1377,7 +1373,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
             "elastic: worker(s) %s missed the round-%d fence (CRASHED "
             "mid-round, non-cooperative) — rolling back to the round "
             "boundary", crashed, rnd)
-        if sanitize and san_counter_ok and san_warmup is not None:
+        if sanitize and san_warmup is not None:
             # close the steady-state retrace budget before the recovery
             # window (a sanctioned reshard window, like PR 8's): the new
             # mesh's round-program compile belongs to the recovery, but
@@ -1518,7 +1514,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 log.warning("elastic: membership event rejected: %s", r)
         if not change.changed:
             return
-        if sanitize and san_counter_ok and san_warmup is not None:
+        if sanitize and san_warmup is not None:
             # close THIS steady-state segment's zero-retrace budget the
             # moment a change is committed, BEFORE any transition work:
             # checkpoint_fence and build_snapshot trace their own small
@@ -1892,7 +1888,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     # sanitizer provenance (ISSUE 6): recorded like sync_engine — every
     # run artifact states whether it ran sanitized and what the harness
     # observed (all zeros on a clean run; enabled=False when off)
-    if sanitize and san_counter_ok and san_warmup is not None:
+    if sanitize and san_warmup is not None:
         counts = compile_event_counts()
         san["retrace_count"] = counts["traces"] - san_warmup["traces"]
         san["recompile_count"] = (counts["compiles"]
@@ -1907,18 +1903,10 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
             "shape churn in the packed inputs, per-round jit "
             "construction, or value-varying static args)")
     if sanitize:
-        # greppable clean-run provenance (any violation raised above).
-        # The "sanitizer clean" spelling is reserved for full coverage:
-        # when the monitoring surface was unavailable the retrace budget
-        # silently degraded to a no-op, and the line must say so —
-        # verify.sh's smoke greps the full-coverage spelling only.
-        if san_counter_ok:
-            log.info("sanitizer clean: 0 transfer-guard violations, 0 "
-                     "post-warmup retraces, 0 donation failures")
-        else:
-            log.info("sanitizer: 0 transfer-guard violations, 0 "
-                     "donation failures; retrace budget NOT enforced "
-                     "(jax monitoring unavailable)")
+        # greppable clean-run provenance (any violation raised above);
+        # verify.sh's smokes grep this spelling
+        log.info("sanitizer clean: 0 transfer-guard violations, 0 "
+                 "post-warmup retraces, 0 donation failures")
 
     # elastic-membership provenance (ISSUE 8): recorded like sync_engine/
     # sanitize — every run artifact states whether the elastic harness was
@@ -1989,5 +1977,6 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     results["variables"] = engine.rank0_variables(state)
     results["mesh"] = mesh
     results["model"] = model
+    results["engine"] = engine
     results["test"] = test if datasets is None else datasets[2]
     return results

@@ -18,15 +18,10 @@ import logging
 import sys
 
 
-def main(argv=None) -> int:
-    argv = sys.argv[1:] if argv is None else list(argv)
-    if argv and argv[0] == "serve":
-        # `main.py serve ...` — continuous-batching inference off a
-        # sharded checkpoint (ISSUE 7); the --serve_* flag group and
-        # --checkpoint_dir configure it, the model itself comes from the
-        # checkpoint's MANIFEST metadata
-        from .serve.api import serve_main
-        return serve_main(argv[1:])
+def train_main(argv) -> dict:
+    """The training command: train -> rank-0 test evaluation -> the six
+    plots.  Returns ``train_global``'s results (``main`` drops them;
+    ``chip_smoke.py`` checks them)."""
     from .config import config_from_args
     cfg = config_from_args(argv)
     logging.basicConfig(
@@ -52,6 +47,19 @@ def main(argv=None) -> int:
         # actually recorded (a resumed run only records the new ones)
         epochs_run = len(results["global_train_losses"])
         viz.write_all(results, epochs_run, cfg.epochs_local, cfg.out_dir)
+    return results
+
+
+def main(argv=None) -> int:
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if argv and argv[0] == "serve":
+        # `main.py serve ...` — continuous-batching inference off a
+        # sharded checkpoint (ISSUE 7); the --serve_* flag group and
+        # --checkpoint_dir configure it, the model itself comes from the
+        # checkpoint's MANIFEST metadata
+        from .serve.api import serve_main
+        return serve_main(argv[1:])
+    train_main(argv)
     return 0
 
 

@@ -148,6 +148,111 @@ def remat_name_vocab(name: str, num_experts: int = 0) -> tuple[str, ...]:
     return base + ("moe_dispatch",) if num_experts > 0 else base
 
 
+# Named rematerialization policies for the layer-scan engine (ISSUE 3).
+# "everything" REMATERIALIZES everything (saves nothing — jax's
+# ``nothing_saveable``, the historical ``remat=True`` behavior);
+# "dots_saveable" saves matmul/einsum outputs and recomputes only the
+# cheap elementwise chains between them — the pjit/TPUv4 scaling report's
+# default selective-remat recipe.
+#
+# ISSUE 15 adds the NAMED-ACTIVATION tier: ``save_names:<a,b>`` keeps
+# exactly the ``checkpoint_name``-annotated activations in the set on
+# device (``save_only_these_names``), and ``offload_names:<a,b>``
+# additionally moves them to host memory between forward and backward
+# (``save_and_offload_only_these_names`` -> ``pinned_host``).  Both are
+# pure residency policies: the math is the unannotated math, so every
+# policy's fp32 trajectory is BITWISE the baseline's
+# (tests/test_remat_memory.py).
+REMAT_POLICIES = ("none", "dots_saveable", "everything")
+NAMED_REMAT_KINDS = ("save_names", "offload_names")
+
+
+def split_remat_policy(policy: str) -> tuple[str, tuple[str, ...]]:
+    """``--remat_policy`` -> ``(kind, names)``: the three base spellings
+    parse as ``(spelling, ())``; the named tiers as ``("save_names" |
+    "offload_names", (name, ...))`` with duplicates collapsed.  Pure
+    syntax — vocabulary validation against the model family lives in
+    ``Config.parse_remat_policy`` (eager) so a typo'd name fails at
+    argparse time with the family's emitted vocabulary in the message."""
+    if ":" not in policy:
+        if policy not in REMAT_POLICIES:
+            raise ValueError(
+                f"remat policy must be one of {REMAT_POLICIES} or "
+                f"'save_names:<a,b>' / 'offload_names:<a,b>', got "
+                f"{policy!r}")
+        return policy, ()
+    kind, _, names_csv = policy.partition(":")
+    if kind not in NAMED_REMAT_KINDS:
+        raise ValueError(
+            f"named remat policy must start with one of "
+            f"{NAMED_REMAT_KINDS}, got {policy!r}")
+    names = tuple(dict.fromkeys(
+        n.strip() for n in names_csv.split(",") if n.strip()))
+    if not names:
+        raise ValueError(
+            f"--remat_policy {kind}: needs at least one activation name "
+            f"(e.g. {kind}:attn_out), got {policy!r}")
+    return kind, names
+
+
+def host_offload_supported() -> bool:
+    """True when the default backend can place offloaded-remat residuals
+    in host memory: it exposes a ``pinned_host`` memory space.  A
+    backend without one demotes offload — see ``checkpoint_policy``."""
+    import jax
+    return "pinned_host" in {
+        m.kind for m in jax.devices()[0].addressable_memories()}
+
+
+_OFFLOAD_DEMOTIONS_LOGGED: set[tuple[str, ...]] = set()
+
+
+def checkpoint_policy(name):
+    """Resolve a named ``--remat_policy`` to a ``jax.checkpoint`` policy
+    callable.  ``name`` is one of ``REMAT_POLICIES`` minus "none" —
+    callers gate the "none" (no remat at all) case themselves — or a
+    named-activation spelling ``save_names:<a,b>`` /
+    ``offload_names:<a,b>`` (ISSUE 15).
+
+    ``offload_names`` demotion: on a backend without a ``pinned_host``
+    memory space (nowhere distinct to offload TO) the offload set
+    demotes to the SAME-set ``save_names`` with a logged reason.
+    Bitwise-safe by the remat contract: both policies save the
+    identical values, only their residency differs, and residency never
+    changes math."""
+    import jax
+    policies = jax.checkpoint_policies
+    if ":" in name:
+        kind, names = split_remat_policy(name)
+        if kind == "offload_names":
+            if host_offload_supported():
+                return policies.save_and_offload_only_these_names(
+                    names_which_can_be_saved=[],
+                    names_which_can_be_offloaded=list(names),
+                    offload_src="device", offload_dst="pinned_host")
+            if names not in _OFFLOAD_DEMOTIONS_LOGGED:
+                _OFFLOAD_DEMOTIONS_LOGGED.add(names)
+                import logging
+                logging.getLogger(__name__).info(
+                    "remat policy offload_names:%s demoted to "
+                    "save_names:%s — this backend (%s) has no "
+                    "'pinned_host' memory space to offload to, so the "
+                    "same-set device-saved policy is the "
+                    "residency-equivalent; bitwise-identical math "
+                    "either way",
+                    ",".join(names), ",".join(names),
+                    jax.default_backend())
+        return policies.save_only_these_names(*names)
+    if name not in REMAT_POLICIES or name == "none":
+        raise ValueError(
+            f"remat policy must be one of {REMAT_POLICIES[1:]} or a "
+            f"named-activation spelling ('save_names:<a,b>' / "
+            f"'offload_names:<a,b>'), got {name!r}")
+    if name == "dots_saveable":
+        return policies.dots_saveable
+    return policies.nothing_saveable
+
+
 MODEL_INPUT_SPECS = {
     # name -> (example input shape without batch, num_classes or vocab)
     "enhanced_cnn": ((32, 32, 3), 10),

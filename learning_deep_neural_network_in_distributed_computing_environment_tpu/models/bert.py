@@ -26,8 +26,8 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from ..compat import checkpoint_name
 from ..parallel.tp import copy_to_tp_region, reduce_from_tp_region
 
 _init = nn.initializers.normal(stddev=0.02)
@@ -242,13 +242,12 @@ def apply_scanned_stack(scan_layer_cls, x, *, num_layers: int, pp_size: int,
         # between them (the pjit/TPUv4 selective-remat default);
         # "save_names:<set>" / "offload_names:<set>" (ISSUE 15) keep
         # exactly the checkpoint_name-annotated activations in the set
-        # on device / offloaded to pinned host memory (compat.py
-        # demotes offload to same-set save on backends without a host
-        # memory space)
-        from ..compat import checkpoint_policy
-        policy = checkpoint_policy(remat_policy)
-        remat_kw = {} if policy is None else {"policy": policy}
-        cls = nn.remat(scan_layer_cls, prevent_cse=False, **remat_kw)
+        # on device / offloaded to pinned host memory
+        # (models.checkpoint_policy demotes offload to same-set save on
+        # backends without a host memory space)
+        from . import checkpoint_policy
+        cls = nn.remat(scan_layer_cls, prevent_cse=False,
+                       policy=checkpoint_policy(remat_policy))
     scanned = nn.scan(
         cls, variable_axes={"params": 0, "aux": 0},
         split_rngs={"params": True}, in_axes=nn.broadcast,

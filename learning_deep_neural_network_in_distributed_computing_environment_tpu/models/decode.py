@@ -43,6 +43,7 @@ forward whenever the forward's capacity dropped nothing.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -166,12 +167,16 @@ def paged_attend(q, k_new, v_new, *, positions, num_valid, page_table,
 # Per-family block decode (one scanned layer)
 # ----------------------------------------------------------------------
 
-def _dense_general(x, kernel, bias=None):
+def _dense_general(x, kernel, bias=None, *, dtype):
     """flax DenseGeneral over the trailing feature dim: contract x's last
-    axis with kernel dim 0, appending the kernel's remaining dims."""
-    y = lax.dot_general(x, kernel,
+    axis with kernel dim 0, appending the kernel's remaining dims.
+    Input, kernel and bias are cast to the compute ``dtype`` first, as
+    the flax modules' ``dtype=`` does (``promote_dtype``): the kernels
+    are STORED float32, and multiplying a bfloat16 residual by them
+    would promote the scan carry to float32."""
+    y = lax.dot_general(x.astype(dtype), kernel.astype(dtype),
                         (((x.ndim - 1,), (0,)), ((), ())))
-    return y if bias is None else y + bias
+    return y if bias is None else y + bias.astype(dtype)
 
 
 def _moe_ffn(mp, x, dtype):
@@ -198,13 +203,13 @@ def _attn_proj(lp, x, spec: DecodeSpec, positions):
     """q/k/v projections of one block's attention at ``positions``
     (RoPE-rotated for llama so cached keys carry their encoding)."""
     ap = lp["attn"]
+    dense = functools.partial(_dense_general, dtype=spec.dtype)
     if "qkv" in ap:
-        qkv = _dense_general(x, ap["qkv"]["kernel"],
-                             ap["qkv"].get("bias"))
+        qkv = dense(x, ap["qkv"]["kernel"], ap["qkv"].get("bias"))
         q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
     else:  # grouped-query attention: separate q / kv projections
-        q = _dense_general(x, ap["q"]["kernel"])
-        kv = _dense_general(x, ap["kv"]["kernel"])
+        q = dense(x, ap["q"]["kernel"])
+        kv = dense(x, ap["kv"]["kernel"])
         k, v = kv[..., 0, :, :], kv[..., 1, :, :]
     if spec.family == "llama":
         # rope() takes [L]-shaped positions; rows differ per slot, so
@@ -219,6 +224,7 @@ def _block(spec: DecodeSpec, lp, x, positions, num_valid, page_table,
            kc, vc):
     """One decoder block against the paged cache; ``lp`` is this layer's
     slice of the stacked params, ``kc/vc`` its [P, ps, KV, D] pool."""
+    dense = functools.partial(_dense_general, dtype=spec.dtype)
     if spec.family == "gpt":
         h = _layernorm(x, lp["ln1"]["scale"], lp["ln1"]["bias"])
     else:
@@ -227,9 +233,8 @@ def _block(spec: DecodeSpec, lp, x, positions, num_valid, page_table,
     out, kc, vc = paged_attend(q, k, v, positions=positions,
                                num_valid=num_valid, page_table=page_table,
                                k_pages=kc, v_pages=vc)
-    a = _dense_general(out.reshape(*out.shape[:2], -1),
-                       lp["attn"]["out"]["kernel"].reshape(
-                           -1, spec.hidden))
+    a = dense(out.reshape(*out.shape[:2], -1),
+              lp["attn"]["out"]["kernel"].reshape(-1, spec.hidden))
     if "out_bias" in lp["attn"]:
         a = a + lp["attn"]["out_bias"].astype(a.dtype)
     x = x + a
@@ -238,20 +243,18 @@ def _block(spec: DecodeSpec, lp, x, positions, num_valid, page_table,
         if spec.num_experts:
             f = _moe_ffn(lp["moe"], f, spec.dtype)
         else:
-            f = _dense_general(f, lp["ffn_in"]["kernel"],
-                               lp["ffn_in"]["bias"])
+            f = dense(f, lp["ffn_in"]["kernel"], lp["ffn_in"]["bias"])
             f = jax.nn.gelu(f, approximate=True)
-            f = _dense_general(f, lp["ffn_out"]["kernel"])
+            f = dense(f, lp["ffn_out"]["kernel"])
             f = f + lp["ffn_bias"].astype(f.dtype)
     else:
         f = _rmsnorm(x, lp["rms2"]["scale"])
         if spec.num_experts:
             f = _moe_ffn(lp["moe"], f, spec.dtype)
         else:
-            gate = _dense_general(f, lp["ffn_in"]["kernel"])
-            up = _dense_general(f, lp["ffn_up"]["kernel"])
-            f = _dense_general(jax.nn.silu(gate) * up,
-                               lp["ffn_out"]["kernel"])
+            gate = dense(f, lp["ffn_in"]["kernel"])
+            up = dense(f, lp["ffn_up"]["kernel"])
+            f = dense(jax.nn.silu(gate) * up, lp["ffn_out"]["kernel"])
     return x + f, kc, vc
 
 
@@ -295,7 +298,8 @@ def forward_paged(spec: DecodeSpec, params, tokens, lengths, num_valid,
         logits = jnp.einsum("bth,vh->btv", x, emb.astype(spec.dtype))
     else:
         x = _rmsnorm(x, params["rms_f"]["scale"])
-        logits = _dense_general(x, params["lm_head"]["kernel"])
+        logits = _dense_general(x, params["lm_head"]["kernel"],
+                                dtype=spec.dtype)
     return logits, k_pages, v_pages
 
 

@@ -26,8 +26,8 @@ from typing import Any, Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from ..compat import checkpoint_name
 from ..parallel.tp import copy_to_tp_region, reduce_from_tp_region
 from .bert import SelfAttention
 

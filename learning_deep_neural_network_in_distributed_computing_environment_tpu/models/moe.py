@@ -33,8 +33,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ..compat import checkpoint_name
+from jax.ad_checkpoint import checkpoint_name
 
 _init = nn.initializers.normal(stddev=0.02)
 

@@ -33,13 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import shape_dtype_struct, typeof
 from .attention import NEG_INF
-
-# JAX-version compat: the TPU compiler-params container was renamed from
-# TPUCompilerParams (<= 0.4.x) to CompilerParams; same kwargs either way
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or pltpu.TPUCompilerParams)
 
 BQ = 1024  # query block (MXU-aligned)
 BK = 1024  # key/value block
@@ -146,17 +140,17 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
     qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     # under shard_map's varying-manual-axes typing the out aval must carry
     # the same mesh-varying set as the inputs
-    vma = getattr(typeof(qt), "vma", None)
+    vma = jax.typeof(qt).vma
     kw = dict(scale=scale, nk=lk // bk, bq=bq, bk=bk, causal=causal)
     kernel = (functools.partial(_flash_kernel, **kw) if with_lse
               else functools.partial(_fwd_kernel_nolse, **kw))
     o_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0),
                           memory_space=pltpu.VMEM)
-    out_shape = [shape_dtype_struct(qt.shape, q.dtype, vma=vma)]
+    out_shape = [jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma)]
     out_specs = [o_spec]
     if with_lse:
-        out_shape.append(shape_dtype_struct((b, h, lq, LANES), jnp.float32,
-                                              vma=vma))
+        out_shape.append(jax.ShapeDtypeStruct(
+            (b, h, lq, LANES), jnp.float32, vma=vma))
         out_specs.append(pl.BlockSpec(
             (1, 1, bq, LANES), lambda b_, h_, i, j: (b_, h_, i, 0),
             memory_space=pltpu.VMEM))
@@ -179,7 +173,7 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom l
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
@@ -287,128 +281,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                      dqp_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                      scale: float, ni: int, rep: int, bq: int, bk: int,
-                      causal: bool):
-    """Single-pass backward: grid (b, kv_head, jk, it), Q innermost (the
-    two-pass dK/dV kernel's layout, it = member * ni + iq for GQA).
-
-    The softmax block p is recomputed ONCE per (i, j) pair and feeds all
-    three gradients — dk/dv accumulate in VMEM scratch across the
-    innermost sweep exactly as in the two-pass kernel, while this cell's
-    dq contribution (ds @ k) is written to a per-j PARTIAL output tile
-    ([b, h, nj, lq, d]) and reduced by one XLA sum outside.  Rationale
-    (r5 trace): the kernels are VPU-bound on the online-softmax
-    transcendentals and the two-pass FA-2 backward pays that recompute
-    twice; fusing halves the dominant cost for nj x dq of f32 partial
-    traffic (nj = L/1024, ~0.3 ms/layer at L=4096 vs ~1.5 ms/layer of
-    VPU time saved)."""
-    j = pl.program_id(2)
-    it = pl.program_id(3)
-    i = it % ni if rep > 1 else it
-
-    @pl.when(it == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    run = (j * bk <= i * bq + bq - 1) if causal else True
-
-    @pl.when(run)
-    def _block():
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :1]                       # [bq, 1]
-        delta = dl_ref[0, 0, :, :1]                      # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        p = jnp.exp(s - lse)                             # recomputed ONCE
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, d]
-        dqp_ref[0, 0, 0, :, :] = jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, d]
-
-    if causal:
-        @pl.when(jnp.logical_not(run))
-        def _zero_partial():
-            # masked cells still own their dq partial tile — write zeros
-            # so the outer reduction never sums garbage
-            dqp_ref[0, 0, 0, :, :] = jnp.zeros_like(
-                dqp_ref[0, 0, 0, :, :])
-
-    @pl.when(it == ni * rep - 1)
-    def _finish():
-        dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _flash_backward_fused(q, k, v, o, lse, g, causal):
-    """One-pass fused backward (see ``_bwd_fused_kernel``).  dq comes
-    back as per-key-block f32 partials summed outside the kernel (the
-    sum must not round per-block contributions to bf16 first)."""
-    b, lq, h, d = q.shape
-    lk, kv = k.shape[1], k.shape[2]
-    rep = h // kv
-    bq, bk = _block_size(lq, BQ), _block_size(lk, BK)
-    ni = lq // bq
-    nj = lk // bk
-    scale = 1.0 / (d ** 0.5)
-    qt, kt, vt, ot, gt = (a.transpose(0, 2, 1, 3) for a in (q, k, v, o, g))
-    delta = jnp.einsum("bhld,bhld->bhl", gt.astype(jnp.float32),
-                       ot.astype(jnp.float32))
-    delta = jnp.broadcast_to(delta[..., None], (b, h, lq, LANES))
-    vma = getattr(typeof(qt), "vma", None)
-    rowT = lambda m: pl.BlockSpec(
-        (1, 1, bq, m),
-        lambda b_, g_, j, it: (b_, g_ * rep + it // ni, it % ni, 0),
-        memory_space=pltpu.VMEM)
-    colT = lambda m: pl.BlockSpec((1, 1, bk, m),
-                                  lambda b_, g_, j, it: (b_, g_, j, 0),
-                                  memory_space=pltpu.VMEM)
-    partT = pl.BlockSpec(
-        (1, 1, 1, bq, d),
-        lambda b_, g_, j, it: (b_, g_ * rep + it // ni, j, it % ni, 0),
-        memory_space=pltpu.VMEM)
-    params = _CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
-    dqp, dkt, dvt = pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, scale=scale, ni=ni, rep=rep,
-                          bq=bq, bk=bk, causal=causal),
-        out_shape=[shape_dtype_struct((b, h, nj, lq, d), jnp.float32,
-                                        vma=vma),
-                   shape_dtype_struct(kt.shape, k.dtype, vma=vma),
-                   shape_dtype_struct(vt.shape, v.dtype, vma=vma)],
-        grid=(b, kv, nj, ni * rep),
-        in_specs=[rowT(d), colT(d), colT(d), rowT(d), rowT(LANES),
-                  rowT(LANES)],
-        out_specs=[partT, colT(d), colT(d)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=params, interpret=_interpret(),
-    )(qt, kt, vt, gt, lse, delta)
-    dqt = dqp.sum(axis=2).astype(q.dtype)
-    return (dqt.transpose(0, 2, 1, 3), dkt.transpose(0, 2, 1, 3),
-            dvt.transpose(0, 2, 1, 3))
-
-
 def _flash_backward(q, k, v, o, lse, g, causal):
     """Blockwise flash backward: O(L) memory, no L x L score materialization
     (the FlashAttention-2 construction: recompute p from q, k and the saved
@@ -425,7 +297,7 @@ def _flash_backward(q, k, v, o, lse, g, causal):
     delta = jnp.einsum("bhld,bhld->bhl", gt.astype(jnp.float32),
                        ot.astype(jnp.float32))
     delta = jnp.broadcast_to(delta[..., None], (b, h, lq, LANES))
-    vma = getattr(typeof(qt), "vma", None)
+    vma = jax.typeof(qt).vma
     row = lambda m: pl.BlockSpec((1, 1, bq, m),
                                  lambda b_, h_, i, j: (b_, h_, i, 0),
                                  memory_space=pltpu.VMEM)
@@ -442,13 +314,13 @@ def _flash_backward(q, k, v, o, lse, g, causal):
     colT = lambda m: pl.BlockSpec((1, 1, bk, m),
                                   lambda b_, g, j, it: (b_, g, j, 0),
                                   memory_space=pltpu.VMEM)
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
     dqt = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, nk=lk // bk,
                           bq=bq, bk=bk, causal=causal),
-        out_shape=shape_dtype_struct(qt.shape, q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma),
         grid=(b, h, ni, lk // bk),
         in_specs=[row(d), col(d), col(d), row(d), row(LANES), row(LANES)],
         out_specs=row(d),
@@ -459,8 +331,8 @@ def _flash_backward(q, k, v, o, lse, g, causal):
     dkt, dvt = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, ni=ni, rep=rep,
                           bq=bq, bk=bk, causal=causal),
-        out_shape=[shape_dtype_struct(kt.shape, k.dtype, vma=vma),
-                   shape_dtype_struct(vt.shape, v.dtype, vma=vma)],
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct(vt.shape, v.dtype, vma=vma)],
         grid=(b, kv, lk // bk, ni * rep),
         in_specs=[rowT(d), colT(d), colT(d), rowT(d), rowT(LANES),
                   rowT(LANES)],
@@ -506,31 +378,7 @@ def _flash_fwd_rule(q, k, v, causal):
     return o, (q, k, v, o, lse)
 
 
-# Backward implementation switch.  The fused single-pass kernel
-# (_flash_backward_fused: one softmax recompute instead of two, dq as
-# per-key-block partials) was the r5 trace's one remaining idea for the
-# VPU-bound kernels — and measured ~40% SLOWER end to end on the v5e
-# (per-process A/B runs with only FLASH_BWD differing: L=2048 2.59 ms
-# fused vs 1.85 ms two-pass; L=8192 9.48 vs 6.72): the per-cell f32
-# partial-tile writes stall the Mosaic pipeline more than the saved exp
-# recompute buys.  The two-pass FA-2 layout stays the default; the
-# fused kernel remains behind FLASH_BWD=fused, correctness-tested, as
-# the recorded dead end.  Read ONCE at import: the choice is baked into
-# jit traces, so flipping the env var mid-process would silently
-# re-measure the cached executable (code-review r5) — A/B in separate
-# processes, as the recorded numbers were.
-import os as _os
-
-_FUSED_BWD = _os.environ.get("FLASH_BWD") == "fused"
-
-
-def _use_fused_bwd() -> bool:
-    return _FUSED_BWD
-
-
 def _flash_bwd_rule(causal, res, g):
-    if _use_fused_bwd():
-        return _flash_backward_fused(*res, g, causal)
     return _flash_backward(*res, g, causal)
 
 
@@ -546,7 +394,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # the Pallas HLO interpreter (CPU test path) cannot lower kernels whose
     # operands are mesh-varying inside shard_map; the unit tests cover the
     # kernel outside shard_map and the real path compiles on TPU
-    in_shard_map = bool(getattr(typeof(q), "vma", None))
+    in_shard_map = bool(jax.typeof(q).vma)
     if mask is not None:
         _log_fallback("arbitrary masks are not tiled (use causal=True for "
                       "autoregressive masking)", q)

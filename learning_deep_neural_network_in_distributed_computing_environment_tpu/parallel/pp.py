@@ -42,8 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size, pcast, typeof
-
 
 def gpipe_step(apply_fn: Callable, xs: jnp.ndarray, axis_name: str,
                num_micro: int, carry, t):
@@ -51,7 +49,7 @@ def gpipe_step(apply_fn: Callable, xs: jnp.ndarray, axis_name: str,
     ``xs`` [M, mb, ...] holds the microbatched pipeline inputs; ``carry``
     is ``(act_in, outs)``: the activation that just arrived from the
     predecessor stage and the finished-microbatch collection buffer."""
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     act_in, outs = carry
     # stage 0 injects microbatch t; later stages consume what arrived
@@ -70,7 +68,7 @@ def gpipe_step(apply_fn: Callable, xs: jnp.ndarray, axis_name: str,
 def gpipe_finalize(outs: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Broadcast the last stage's collected outputs to every stage so the
     replicated head computes one identical loss along ``pipe``."""
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     return lax.psum(jnp.where(s == p - 1, outs, jnp.zeros_like(outs)),
                     axis_name)
@@ -82,7 +80,7 @@ def gpipe_schedule(stage_fn: Callable, xs: jnp.ndarray, axis_name: str,
     activations, identical on every stage.  (Models go through the flax
     ``nn.scan`` path in ``models.bert`` instead — parameters must be
     lifted; this entry point serves parameterless stage fns and tests.)"""
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
 
     def step(carry, t):
         return gpipe_step(stage_fn, xs, axis_name, num_micro, carry, t), None
@@ -97,7 +95,7 @@ def gpipe_carry0(xs: jnp.ndarray, axis_name: str):
     ``axis_name`` — the loop body makes the carry varying (per-stage
     activations), so an invariant init would fail shard_map's scan carry
     type check."""
-    vary = lambda a: pcast(a, (axis_name,), to="varying")
+    vary = lambda a: lax.pcast(a, (axis_name,), to="varying")
     return vary(jnp.zeros_like(xs[0])), vary(jnp.zeros_like(xs))
 
 
@@ -232,7 +230,7 @@ def onef1b_schedule(stage_fn: Callable, loss_fn: Callable, stage_params,
     along ``axis_name``.  Every tick recomputes the bwd slot's stage
     forward from the stored stage INPUT (per-layer remat by
     construction), so the in-flight residuals are O(stages) inputs."""
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     m = num_micro
     has_aux = stage_aux_weight is not None
@@ -243,12 +241,11 @@ def onef1b_schedule(stage_fn: Callable, loss_fn: Callable, stage_params,
     # per-worker 'data' axis, at least), so every fresh zero / seed must
     # carry xs' full varying-axes set PLUS the pipe axis — otherwise the
     # scan carry types (and the vjp seed type) mismatch the body outputs.
-    want_vma = set(getattr(typeof(xs), "vma", ())) | {axis_name}
+    want_vma = set(jax.typeof(xs).vma) | {axis_name}
 
     def _vary_leaf(a):
-        missing = tuple(sorted(
-            want_vma - set(getattr(typeof(a), "vma", ()))))
-        return pcast(a, missing, to="varying") if missing else a
+        missing = tuple(sorted(want_vma - set(jax.typeof(a).vma)))
+        return lax.pcast(a, missing, to="varying") if missing else a
 
     def vary(tree):
         return jax.tree_util.tree_map(_vary_leaf, tree)
@@ -448,11 +445,11 @@ def _zeros_tree(tree):
     keeps both branches type-identical for any sharding."""
     def z(l):
         zz = jnp.zeros(l.shape, l.dtype)
-        want = set(getattr(typeof(l), "vma", None)
+        want = set(getattr(jax.typeof(l), "vma", None)
                    or getattr(l, "vma", None) or ())
         missing = tuple(sorted(
-            want - set(getattr(typeof(zz), "vma", ()) or ())))
-        return pcast(zz, missing, to="varying") if missing else zz
+            want - set(getattr(jax.typeof(zz), "vma", ()) or ())))
+        return lax.pcast(zz, missing, to="varying") if missing else zz
     return jax.tree_util.tree_map(z, tree)
 
 

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size, pcast, typeof
 from ..ops.attention import NEG_INF, causal_mask
 
 
@@ -40,7 +39,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     zig-zag block reordering that halves causal ring latency is a later
     optimization.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, lc, h, d = q.shape
     # K/V may carry fewer heads (grouped-query attention): scores/outputs
     # use grouped einsums, and — the point of GQA here — the K/V blocks
@@ -76,8 +75,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # shard_map carries, e.g. data), so the zero initializers must be cast
     # to q's exact varying-axis set or tracing rejects the carry (found by
     # running: round-1 shipped this unexecuted and it failed on first use).
-    vma = set(getattr(typeof(qf), "vma", ())) | {axis_name}
-    vary = lambda x: pcast(x, tuple(sorted(vma)), to="varying")
+    vma = set(jax.typeof(qf).vma) | {axis_name}
+    vary = lambda x: lax.pcast(x, tuple(sorted(vma)), to="varying")
     o = vary(jnp.zeros((b, h, lc, d), jnp.float32))       # weighted-value accum
     m = vary(jnp.full((b, h, lc), -jnp.inf, jnp.float32))  # running max
     l = vary(jnp.zeros((b, h, lc), jnp.float32))           # running denominator
@@ -132,7 +131,7 @@ def ring_attention_zigzag(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     the zig-zag redistribution and its inverse are internal ppermutes.
     Requires an even per-device chunk length.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, lc, h, d = q.shape
     if lc % 2:
         raise ValueError(f"zig-zag ring needs an even per-device chunk "
@@ -216,9 +215,8 @@ def ring_attention_zigzag(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             lambda s: s,
             state)
 
-    vma = tuple(sorted(set(getattr(typeof(q), "vma", ()))
-                       | {axis_name}))
-    vary = lambda x: pcast(x, vma, to="varying")
+    vma = tuple(sorted(set(jax.typeof(q).vma) | {axis_name}))
+    vary = lambda x: lax.pcast(x, vma, to="varying")
     zero_state = lambda: (
         vary(jnp.full((b, h, half), -jnp.inf, jnp.float32)),
         vary(jnp.zeros((b, h, half), jnp.float32)),
@@ -259,7 +257,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     moves 2x the activation bytes of ring attention but in two large dense
     collectives that XLA overlaps well on ICI.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, lc, h, d = q.shape
     kv = k.shape[2]
     if h % n or kv % n:

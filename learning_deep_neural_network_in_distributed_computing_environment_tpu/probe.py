@@ -24,14 +24,11 @@ TPU-native redesign:
 
 from __future__ import annotations
 
-import logging
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-log = logging.getLogger(__name__)
 
 
 # ----------------------------------------------------------------------
@@ -58,17 +55,18 @@ class TrackedProgram:
     ``multi_shape=True`` (the serve prefill program, one jit specialized
     per prompt bucket): executables are kept per input-shape key.
 
-    Robustness: a multi-process run, or any lower/compile failure, falls
-    back to the plain jit call path for the life of the program (the
-    memory row then reports ``available: False`` instead of killing the
-    run — observability must never take down training).
+    A lower/compile failure propagates: a program the backend refuses
+    (a Mosaic lowering error, an HBM overflow) must stop the run, not
+    be retried through ``jit`` under an observability warning.  Only a
+    multi-process run takes the plain jit call path (its memory row
+    reports ``available: False``).
     """
 
     def __init__(self, name: str, fn, *, multi_shape: bool = False):
         self.name = name
         self._fn = fn
         self._multi = bool(multi_shape)
-        self._fallback = jax.process_count() > 1
+        self._multiprocess = jax.process_count() > 1
         self.compiled = None           # single-shape executable
         self._by_shape: dict = {}      # multi-shape: key -> executable
 
@@ -82,25 +80,17 @@ class TrackedProgram:
         return self._fn.lower(*args, **kwargs).compile()
 
     def __call__(self, *args, **kwargs):
-        if self._fallback:
+        if self._multiprocess:
             return self._fn(*args, **kwargs)
-        try:
-            if self._multi:
-                key = self._shape_key((args, kwargs))
-                comp = self._by_shape.get(key)
-                if comp is None:
-                    comp = self._by_shape[key] = self._compile(args, kwargs)
-            else:
-                comp = self.compiled
-                if comp is None:
-                    comp = self.compiled = self._compile(args, kwargs)
-        except Exception as e:  # noqa: BLE001 — observability never kills
-            log.warning(
-                "memory tracking: AOT compile of program %r unavailable "
-                "(%s) — falling back to the plain jit path (its memory "
-                "row will report available=False)", self.name, e)
-            self._fallback = True
-            return self._fn(*args, **kwargs)
+        if self._multi:
+            key = self._shape_key((args, kwargs))
+            comp = self._by_shape.get(key)
+            if comp is None:
+                comp = self._by_shape[key] = self._compile(args, kwargs)
+        else:
+            comp = self.compiled
+            if comp is None:
+                comp = self.compiled = self._compile(args, kwargs)
         return comp(*args, **kwargs)
 
     def executables(self) -> list:
@@ -111,30 +101,24 @@ class TrackedProgram:
     def memory_rows(self) -> list[dict]:
         """One ``memory_analysis()`` row per compiled executable (the
         multi-shape prefill program has one per bucket)."""
-        return [r for r in (memory_analysis_row(c)
-                            for c in self.executables()) if r is not None]
+        return [memory_analysis_row(c) for c in self.executables()]
 
 
-def memory_analysis_row(compiled) -> dict | None:
+def memory_analysis_row(compiled) -> dict:
     """XLA's compiled-memory stats for one executable, as plain ints:
     ``temp_bytes`` (scratch + saved activations — the quantity the remat
     policy moves), ``argument_bytes`` / ``output_bytes`` (I/O buffers),
     ``alias_bytes`` (donated input bytes reused for outputs — subtracted
     from the true footprint since aliased pairs share one buffer), and
-    ``generated_code_bytes``.  None when the backend cannot analyze
-    (some PJRT plugins raise Unimplemented)."""
-    try:
-        ma = compiled.memory_analysis()
-        return {
-            "temp_bytes": int(ma.temp_size_in_bytes),
-            "argument_bytes": int(ma.argument_size_in_bytes),
-            "output_bytes": int(ma.output_size_in_bytes),
-            "alias_bytes": int(ma.alias_size_in_bytes),
-            "generated_code_bytes": int(ma.generated_code_size_in_bytes),
-        }
-    except Exception as e:  # noqa: BLE001 — backend-dependent surface
-        log.debug("memory_analysis unavailable: %s", e)
-        return None
+    ``generated_code_bytes``."""
+    ma = compiled.memory_analysis()
+    return {
+        "temp_bytes": int(ma.temp_size_in_bytes),
+        "argument_bytes": int(ma.argument_size_in_bytes),
+        "output_bytes": int(ma.output_size_in_bytes),
+        "alias_bytes": int(ma.alias_size_in_bytes),
+        "generated_code_bytes": int(ma.generated_code_size_in_bytes),
+    }
 
 
 def memory_report(programs: dict, *, state_bytes: dict | None = None,
@@ -149,8 +133,8 @@ def memory_report(programs: dict, *, state_bytes: dict | None = None,
       is where a remat policy shows up — saved activations are XLA temp
       allocations, so ``none >= dots_saveable >= save_names:<set> >=
       everything`` is an asserted ordering (bench ``--entry memory``),
-      not a narrative.  A program that fell back to the jit path (or a
-      backend without the analysis) contributes no row and flips
+      not a narrative.  A program with no compiled executable (a
+      multi-process run's jit path) contributes no row and flips
       ``available`` off.
     - **analytic resident model**: ``per_worker_state_bytes`` (the
       ISSUE 9/11 accounting) extended with the stacked/fleet total

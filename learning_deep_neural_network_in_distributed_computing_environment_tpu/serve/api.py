@@ -70,6 +70,9 @@ def run_serve(cfg, requests: Optional[list] = None, *,
     if not cfg.checkpoint_dir:
         raise ValueError("serve needs --checkpoint_dir (the sharded "
                          "checkpoint to load)")
+    if cfg.compile_cache_dir:
+        from ..xla_flags import setup_compile_cache
+        setup_compile_cache()
     path = cfg.checkpoint_dir
     if not os.path.isfile(os.path.join(path, ckpt_lib.MANIFEST)):
         resolved = ckpt_lib.latest_checkpoint(path)
@@ -142,59 +145,57 @@ def run_serve(cfg, requests: Optional[list] = None, *,
     sanitize = cfg.sanitize or (
         os.environ.get("JAX_GRAFT_SANITIZE", "").strip().lower()
         not in ("", "0", "false", "off", "no"))
-    counter_ok = False
     warmup_counts = None
     if sanitize:
+        from ..utils.batching import pick_bucket
         from ..xla_flags import (compile_event_counts,
                                  install_compile_counter)
-        counter_ok = install_compile_counter()
-        if counter_ok:
-            from ..utils.batching import pick_bucket
-            from .scheduler import Request
-            mnt = min(2, cfg.serve_max_new_tokens)
-            if engine.prefill_chunk:
-                # chunked prefill: ONE [1, C] chunk program covers every
-                # prompt length — a single longest-prompt request (>= 2
-                # chunks when possible) compiles it + the decode step
-                r0 = max(requests, key=lambda r: len(r.prompt),
-                         default=None)
-                warm = ([Request(rid=10_000_000, prompt=r0.prompt,
-                                 max_new_tokens=mnt,
-                                 temperature=r0.temperature)]
-                        if r0 is not None else [])
-            else:
-                # warmup: ONE request per distinct prefill bucket
-                # compiles every program the workload uses (+ the shared
-                # decode step) off the measured run — warming all N
-                # requests would scale startup with N for no extra
-                # compile coverage.  With the prefix cache on, a
-                # measured request can HIT pages and prefill only its
-                # tail at a SMALLER bucket than its full length picks —
-                # cover every configured bucket, not just the full-
-                # length ones, so a partial hit can never retrace.
-                per_bucket = {}
-                for r in requests:
-                    per_bucket.setdefault(
-                        pick_bucket(len(r.prompt), engine.prompt_buckets),
-                        r)
-                warm = [Request(rid=10_000_000 + i, prompt=r.prompt,
-                                max_new_tokens=min(2, r.max_new_tokens),
-                                temperature=r.temperature)
-                        for i, r in enumerate(per_bucket.values())]
-                if engine.prefix_cache:
-                    rng = np.random.default_rng(cfg.seed)
-                    warm += [
-                        Request(rid=11_000_000 + i,
-                                prompt=rng.integers(
-                                    0, engine.spec.vocab, b).tolist(),
-                                max_new_tokens=mnt,
-                                temperature=cfg.serve_temperature)
-                        for i, b in enumerate(engine.prompt_buckets)
-                        if b not in per_bucket]
-            if warm:
-                ContinuousBatchingScheduler(
-                    engine, eos_id=cfg.serve_eos_id).run(warm)
-            warmup_counts = compile_event_counts()
+        from .scheduler import Request
+        install_compile_counter()
+        mnt = min(2, cfg.serve_max_new_tokens)
+        if engine.prefill_chunk:
+            # chunked prefill: ONE [1, C] chunk program covers every
+            # prompt length — a single longest-prompt request (>= 2
+            # chunks when possible) compiles it + the decode step
+            r0 = max(requests, key=lambda r: len(r.prompt),
+                     default=None)
+            warm = ([Request(rid=10_000_000, prompt=r0.prompt,
+                             max_new_tokens=mnt,
+                             temperature=r0.temperature)]
+                    if r0 is not None else [])
+        else:
+            # warmup: ONE request per distinct prefill bucket
+            # compiles every program the workload uses (+ the shared
+            # decode step) off the measured run — warming all N
+            # requests would scale startup with N for no extra
+            # compile coverage.  With the prefix cache on, a
+            # measured request can HIT pages and prefill only its
+            # tail at a SMALLER bucket than its full length picks —
+            # cover every configured bucket, not just the full-
+            # length ones, so a partial hit can never retrace.
+            per_bucket = {}
+            for r in requests:
+                per_bucket.setdefault(
+                    pick_bucket(len(r.prompt), engine.prompt_buckets),
+                    r)
+            warm = [Request(rid=10_000_000 + i, prompt=r.prompt,
+                            max_new_tokens=min(2, r.max_new_tokens),
+                            temperature=r.temperature)
+                    for i, r in enumerate(per_bucket.values())]
+            if engine.prefix_cache:
+                rng = np.random.default_rng(cfg.seed)
+                warm += [
+                    Request(rid=11_000_000 + i,
+                            prompt=rng.integers(
+                                0, engine.spec.vocab, b).tolist(),
+                            max_new_tokens=mnt,
+                            temperature=cfg.serve_temperature)
+                    for i, b in enumerate(engine.prompt_buckets)
+                    if b not in per_bucket]
+        if warm:
+            ContinuousBatchingScheduler(
+                engine, eos_id=cfg.serve_eos_id).run(warm)
+        warmup_counts = compile_event_counts()
 
     sched = ContinuousBatchingScheduler(
         engine, eos_id=cfg.serve_eos_id,
@@ -210,9 +211,8 @@ def run_serve(cfg, requests: Optional[list] = None, *,
     telemetry["memory"] = memory_report(engine.memory_programs())
     telemetry["retrace_count"] = 0
     telemetry["recompile_count"] = 0
-    telemetry["sanitized"] = bool(sanitize and counter_ok)
-    if sanitize and counter_ok:
-        from ..xla_flags import compile_event_counts
+    telemetry["sanitized"] = bool(sanitize)
+    if sanitize:
         counts = compile_event_counts()
         telemetry["retrace_count"] = (counts["traces"]
                                       - warmup_counts["traces"])

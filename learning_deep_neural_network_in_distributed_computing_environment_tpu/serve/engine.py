@@ -503,8 +503,8 @@ class ServeEngine:
             out["prefill_chunk"] = self._chunk
             if not self.compiled_buckets:
                 # chunking replaced bucket prefill entirely this run —
-                # an uncompiled bucket program is absence, not an AOT
-                # fallback, so don't let it flip ``available`` off
+                # an uncompiled bucket program is absence, so don't let
+                # it flip ``available`` off
                 del out["prefill"]
         if self.draft is not None:
             # speculative pair: the target's hot program is the fused

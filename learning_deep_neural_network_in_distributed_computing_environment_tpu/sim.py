@@ -422,16 +422,11 @@ class SimEngine(LocalSGDEngine):
                                  jax.jit(comms.deliver_stale,
                                          donate_argnums=(0,)),
                                  "sim_deliver")
-                try:
-                    spec = jax.tree_util.tree_map(
-                        lambda a: jax.ShapeDtypeStruct(
-                            a.shape, a.dtype, sharding=a.sharding),
-                        state.params)
-                    tp.compiled = tp._fn.lower(spec, spec).compile()
-                except Exception as e:  # noqa: BLE001 — TrackedProgram
-                    # falls back to plain jit on first call
-                    log.warning("sim deliver pre-compile unavailable: "
-                                "%s", e)
+                spec = jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding),
+                    state.params)
+                tp.compiled = tp._fn.lower(spec, spec).compile()
         extra = ()
         if self.scenario_on:
             active, dropped, noise_key = self._draw_scenario()

@@ -58,8 +58,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import comms
 from . import probe as probe_lib
-from .compat import (LEGACY_SHARD_MAP, axis_size, optimization_barrier,
-                     pcast, shard_map, typeof)
 from .config import Config
 from .data.augment import augment_batch
 from .mesh import DATA_AXIS, SLICE_AXIS
@@ -332,18 +330,12 @@ def _zeros_like_varying(tree: PyTree, dtype=None, extra_axes=()) -> PyTree:
     carry widens to fp32).  ``extra_axes`` marks the zeros varying over
     ADDITIONAL axes beyond the source leaf's — the grad-accumulation
     carry holds PRE-reduction gradients, which vary over the
-    batch-partial (seq/fsdp) axes that the params are invariant along.
-    Legacy shard_map (no vma typing) ignores both refinements: its
-    check_rep rewrite reconciles carry types itself."""
+    batch-partial (seq/fsdp) axes that the params are invariant along."""
     def z(x):
         zz = jnp.zeros(x.shape, dtype or x.dtype)
-        t = typeof(x)
-        if not hasattr(t, "vma"):
-            return zz
-        want = set(t.vma) | set(extra_axes)
-        have = set(getattr(typeof(zz), "vma", ()))
-        missing = tuple(sorted(want - have))
-        return pcast(zz, missing, to="varying") if missing else zz
+        want = set(jax.typeof(x).vma) | set(extra_axes)
+        missing = tuple(sorted(want - set(jax.typeof(zz).vma)))
+        return lax.pcast(zz, missing, to="varying") if missing else zz
     return jax.tree_util.tree_map(z, tree)
 
 
@@ -483,30 +475,11 @@ class LocalSGDEngine:
                         else None)
         self.param_specs = None      # set by init_state
         self._sspec = None           # full TrainState spec tree (TP only)
-        # inner (non-worker) mesh axes of size > 1 — the axes legacy
-        # shard_map's replication certifier may need help with (the
-        # slice axis is a worker-grid axis, not a model axis: values
-        # vary over it, nothing is replication-certified along it)
+        # inner (non-worker) mesh axes of size > 1 (the slice axis is a
+        # worker-grid axis, not a model axis)
         self._inner_axes = tuple(
             a for a in mesh.axis_names
             if a not in (DATA_AXIS, SLICE_AXIS) and int(mesh.shape[a]) > 1)
-        # Legacy-JAX check_rep choice per engine config.  TP/EP/PP need
-        # the check_rep=True rewrite (it auto-inserts the gradient psums
-        # for replicated params).  Pure SP (optionally x FSDP) does every
-        # cross-device reduction MANUALLY, and legacy check_rep=True has
-        # a scan-transpose bug under the ring-attention backward
-        # ("mismatched replication types"), so those configs run
-        # check_rep=False — gradient-exact, verified against dense.
-        # None = modern JAX, pass nothing.
-        if not LEGACY_SHARD_MAP:
-            self._check_rep = None
-        else:
-            from .mesh import EXPERT_AXIS, MODEL_AXIS
-            needs_rewrite = (int(mesh.shape.get(MODEL_AXIS, 1)) > 1
-                             or int(mesh.shape.get(EXPERT_AXIS, 1)) > 1
-                             or int(mesh.shape.get(PIPE_AXIS, 1)) > 1)
-            self._check_rep = not (self.seq_axis is not None
-                                   and not needs_rewrite)
         # Microbatch gradient accumulation (ISSUE 3): K > 1 scans the
         # step's batch in K slices with an fp32 gradient carry — bounded
         # activation memory, unchanged effective batch/optimizer/sync
@@ -716,11 +689,10 @@ class LocalSGDEngine:
         Delegates to ``Config.resolve_sync_mode`` (per-topology: the
         bucketed reduce-scatter engine for allreduce, the bucketed
         ppermute gossip engine for ring/double-ring, the legacy per-leaf
-        dense path otherwise).  Inner (TP/PP/EP) mesh axes no longer
-        force the dense path on legacy JAX: psum_scatter / all_to_all /
-        all_gather / ppermute over 'data' are bit-identical to the dense
-        twin under legacy check_rep with the engine's replication
-        re-certification (tests/test_sync.py::TestShardedSyncInnerAxes).
+        dense path otherwise).  Inner (TP/PP/EP) mesh axes do not
+        force the dense path: psum_scatter / all_to_all / all_gather /
+        ppermute over 'data' are bit-identical to the dense twin
+        (tests/test_sync.py::TestShardedSyncInnerAxes).
         """
         return self.cfg.resolve_sync_mode(jax.default_backend())
 
@@ -1148,7 +1120,7 @@ class LocalSGDEngine:
                 # host-numpy source (elastic/checkpoint restage, not the
                 # init_state device path): materialize an XLA-owned
                 # buffer before the round program can DONATE it — on
-                # jax 0.4.x XLA:CPU the put can zero-copy alias
+                # XLA:CPU the put can zero-copy alias
                 # numpy-owned malloc memory (checkpoint._reshard_leaf
                 # documents the resulting heap corruption)
                 out = jax.block_until_ready(out).copy()
@@ -1333,39 +1305,6 @@ class LocalSGDEngine:
     # ------------------------------------------------------------------
     # The round program
     # ------------------------------------------------------------------
-    def _certify_replication(self, tree, specs):
-        """Re-certify out-spec-claimed replication for legacy shard_map.
-
-        Legacy JAX's ``check_rep`` machinery cannot always INFER the
-        replication an out_spec claims (custom-vjp calls in the round
-        program make its tracking conservative), which rejects otherwise
-        correct programs at trace time.  An explicit all-reduce over each
-        leaf's claimed-replicated inner axes is the identity on the
-        already-replicated values (pmean for floats, pmax for
-        integer/uint leaves — no division) and re-establishes the
-        certificate.  Modern JAX proves replication structurally through
-        vma types; this is a no-op there and on data-only meshes."""
-        if (not LEGACY_SHARD_MAP or not self._inner_axes
-                or self._check_rep is False):  # False = nothing to certify
-            return tree
-
-        def cert(spec, subtree):
-            used = {a for part in spec if part is not None
-                    for a in (part if isinstance(part, tuple) else (part,))}
-            missing = tuple(a for a in self._inner_axes if a not in used)
-            if not missing:
-                return subtree
-            red = lambda x: (lax.pmean(x, missing)
-                             if jnp.issubdtype(x.dtype, jnp.inexact)
-                             else lax.pmax(x, missing))
-            return jax.tree_util.tree_map(red, subtree)
-
-        from jax.sharding import PartitionSpec as _P
-        if isinstance(specs, _P):
-            return cert(specs, tree)
-        return jax.tree_util.tree_map(cert, specs, tree,
-                                      is_leaf=lambda z: isinstance(z, _P))
-
     def _grad_global_norm(self, grads):
         """Global L2 norm of a gradient pytree whose leaves may be
         physically sharded over inner mesh axes (TP/PP/EP param specs):
@@ -1462,7 +1401,7 @@ class LocalSGDEngine:
             # different per-device orders deadlock the unpinned XLA:CPU
             # rendezvous (the same race the standard path barriers at
             # its metrics psum; free on TPU)
-            emb = optimization_barrier((emb, denom))[0]
+            emb = lax.optimization_barrier((emb, denom))[0]
         xs = emb.reshape(mnum, b // mnum, *emb.shape[1:])
         denom = jnp.maximum(denom, 1.0)  # data-derived: known pre-schedule
         stage_params = params["layers"]
@@ -1554,7 +1493,7 @@ class LocalSGDEngine:
             # psum'd over batch-partial axes exactly as below, so the
             # wrapper's running sums match the full-batch step's values
             if part_axes:
-                w = optimization_barrier((w, ce))[0]
+                w = lax.optimization_barrier((w, ce))[0]
             loss = (ce * w).sum() / denom
             total = w.sum()
             if part_axes:
@@ -1571,7 +1510,7 @@ class LocalSGDEngine:
             # SP x PP stress runs; 40 s timeout then SIGABRT).  Routing
             # ``w`` through a barrier with ``ce`` (which depends on the
             # model output) serializes them; free on TPU.
-            w = optimization_barrier((w, ce))[0]
+            w = lax.optimization_barrier((w, ce))[0]
             # the batch is partial on this device: under seq parallelism it
             # holds one chunk of every sequence, under FSDP a slice of the
             # worker's batch (composable — psum over both).  The loss is
@@ -1607,7 +1546,7 @@ class LocalSGDEngine:
                 # FSDP x MoE, MoE x SP)
                 denom_aux = 1.0
                 for ax in part_aux:
-                    denom_aux = denom_aux * axis_size(ax)
+                    denom_aux = denom_aux * lax.axis_size(ax)
                 a = a / denom_aux
             # aux_div: the accumulation wrapper averages the K per-slice
             # aux losses (per-slice routing/capacity — the same declared
@@ -1647,7 +1586,7 @@ class LocalSGDEngine:
             # ORDER this mask-only psum before the model collectives of
             # every slice (same XLA:CPU rendezvous hazard the standard
             # path barriers at its metrics psum; free on TPU)
-            xs = optimization_barrier((xs, denom))[0]
+            xs = lax.optimization_barrier((xs, denom))[0]
         denom = jnp.maximum(denom, 1.0)
 
         def micro(g, inp):
@@ -1918,11 +1857,7 @@ class LocalSGDEngine:
             outs = per_worker(
                 squeeze(state), *map(lambda a: a[0], (x, y, m, xv, yv, mv)),
                 poison=poi)
-            new_state = self._certify_replication(outs[0], sspec)
-            metrics = self._certify_replication(outs[-1], self._spec)
-            mid = tuple(self._certify_replication(o, pspec)
-                        for o in outs[1:-1])
-            return tuple(map(expand, (new_state, *mid, metrics)))
+            return tuple(map(expand, outs))
 
         sspec = self._sspec if self._sspec is not None else self._spec
         pspec = self._sspec.params if self._sspec is not None else self._spec
@@ -1932,17 +1867,10 @@ class LocalSGDEngine:
             in_specs = in_specs + (self._spec,)
         out_specs = ((sspec, pspec, self._spec) if emit_grads
                      else (sspec, self._spec))
-        fn = shard_map(
+        fn = jax.shard_map(
             stacked, mesh=self.mesh,
-            in_specs=in_specs, out_specs=out_specs,
-            **self._sm_kwargs())
+            in_specs=in_specs, out_specs=out_specs)
         return jax.jit(fn, donate_argnums=(0,))
-
-    def _sm_kwargs(self) -> dict:
-        """Extra shard_map kwargs: the legacy check_rep choice (see
-        __init__); nothing on modern JAX."""
-        return {} if self._check_rep is None else \
-            {"check_rep": self._check_rep}
 
     def _pack_specs(self, shapes_key=None):
         """(x, y, m) PartitionSpecs for one pack.  Token tasks under
@@ -2185,15 +2113,11 @@ class LocalSGDEngine:
                              jax.jit(comms.deliver_stale,
                                      donate_argnums=(0,)),
                              "deliver")
-            try:
-                spec = jax.tree_util.tree_map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                   sharding=a.sharding),
-                    new_state.params)
-                tp.compiled = tp._fn.lower(spec, spec).compile()
-            except Exception as e:  # noqa: BLE001 — TrackedProgram
-                # falls back to plain jit on first call
-                log.warning("stale deliver pre-compile unavailable: %s", e)
+            spec = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=a.sharding),
+                new_state.params)
+            tp.compiled = tp._fn.lower(spec, spec).compile()
         args = [new_state.params]
         if self.sync_ef:
             # the EF residual chains sync-to-sync engine-side: sync R
@@ -2366,13 +2290,11 @@ class LocalSGDEngine:
             ex = lambda t: jax.tree_util.tree_map(lambda a: a[None], t)
             unstacked = [a if s == P() else sq(a)
                          for a, s in zip(args, in_specs)]
-            out = per_worker(*unstacked)
-            out = self._certify_replication(out, out_specs or self._spec)
-            return ex(out)
+            return ex(per_worker(*unstacked))
 
-        fn = shard_map(stacked, mesh=self.mesh, in_specs=tuple(in_specs),
-                       out_specs=out_specs or self._spec,
-                       **self._sm_kwargs())
+        fn = jax.shard_map(stacked, mesh=self.mesh,
+                           in_specs=tuple(in_specs),
+                           out_specs=out_specs or self._spec)
         if donate is True:
             donate = (0,)
         return jax.jit(fn, donate_argnums=donate or ())
@@ -2609,8 +2531,7 @@ class LocalSGDEngine:
             # (convert_element_type on the scalar) that the sanitizer's
             # guard rejects in the round loop — a 0-d ndarray takes the
             # explicit path on both branches.  Multi-host keeps the
-            # uncommitted asarray (device_put to a cross-process
-            # sharding is not portable on legacy jax).
+            # uncommitted asarray.
             lr_np = np.asarray(
                 steplr(cfg.lr, cfg.lr_gamma, cfg.lr_step_size, epoch0 + e),
                 np.float32)

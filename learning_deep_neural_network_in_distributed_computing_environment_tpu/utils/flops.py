@@ -14,64 +14,59 @@ from typing import Optional
 
 import jax
 
-# Peak dense bf16 FLOP/s per chip by device kind (public spec sheets).
-# Matched by substring, most specific first.
-_PEAK_BF16 = (
-    ("v6", 918e12),        # Trillium / v6e
-    ("v5p", 459e12),
-    ("v5 lite", 197e12),   # v5e reports as "TPU v5 lite"
-    ("v5e", 197e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+# Per-chip peaks keyed by the EXACT ``jax.Device.device_kind`` string:
+# (dense bf16 FLOP/s, HBM bytes/s), from the public Cloud TPU spec
+# sheets.  Kinds are the strings jax itself matches on
+# (jax/_src/pallas/mosaic/tpu_info.py).  "TPU v5 lite" is what the v5e
+# this repo runs on reports (chip_smoke.py prints it); the other rows
+# are spec-sheet values no run here has met.  A kind that is not in the
+# table is an error where a peak is asked for — a substring guess once
+# let "v5" fall through to the v5p number, and a silent ``None`` drops
+# MFU from the artifact without anyone noticing.
+CHIP_PEAKS = {
+    "TPU v2": (45e12, 700e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),    # v5e
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),        # v5p
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1638e9),   # v6e / Trillium
+    "TPU v6e": (918e12, 1638e9),
+}
 
 
-# HBM bandwidth per chip, bytes/s (public spec sheets), same matching rule.
-_HBM_BW = (
-    ("v6", 1638e9),        # Trillium / v6e
-    ("v5p", 2765e9),
-    ("v5 lite", 819e9),
-    ("v5e", 819e9),
-    ("v5", 2765e9),
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-)
+def _chip_peaks(device: Optional[jax.Device]) -> Optional[tuple]:
+    """(bf16 FLOP/s, HBM bytes/s) of one chip; None off-TPU (a CPU has
+    no chip peak to divide by); raises for a TPU kind not in the table."""
+    d = device or jax.devices()[0]
+    if d.platform != "tpu":
+        return None
+    if d.device_kind not in CHIP_PEAKS:
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth recorded for TPU device_kind "
+            f"{d.device_kind!r}; add its spec-sheet row to "
+            f"utils.flops.CHIP_PEAKS (known: {sorted(CHIP_PEAKS)})")
+    return CHIP_PEAKS[d.device_kind]
 
 
 def hbm_bytes_per_sec(device: Optional[jax.Device] = None) -> Optional[float]:
-    """HBM bandwidth of one chip in bytes/s, or None when unknown."""
-    d = device or jax.devices()[0]
-    if d.platform != "tpu":
-        return None
-    kind = d.device_kind.lower()
-    for key, bw in _HBM_BW:
-        if key in kind:
-            return bw
-    return None
+    """HBM bandwidth of one chip in bytes/s (None off-TPU)."""
+    peaks = _chip_peaks(device)
+    return peaks and peaks[1]
 
 
 def peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
-    """Peak bf16 FLOP/s of one chip, or None when unknown (e.g. CPU)."""
-    d = device or jax.devices()[0]
-    if d.platform != "tpu":
-        return None
-    kind = d.device_kind.lower()
-    for key, peak in _PEAK_BF16:
-        if key in kind:
-            return peak
-    return None
+    """Peak bf16 FLOP/s of one chip (None off-TPU)."""
+    peaks = _chip_peaks(device)
+    return peaks and peaks[0]
 
 
 def compiled_flops(jitted, *args, **kwargs) -> Optional[float]:
     """FLOPs of one invocation of a jitted function, from XLA's cost model
-    of the compiled executable.  None when the backend has no cost model."""
-    try:
-        analysis = jitted.lower(*args, **kwargs).compile().cost_analysis()
-    except Exception:
-        return None
+    of the compiled executable (a lower/compile failure propagates).
+    None when the backend's cost model reports no FLOPs."""
+    analysis = jitted.lower(*args, **kwargs).compile().cost_analysis()
     if isinstance(analysis, (list, tuple)):
         analysis = analysis[0] if analysis else None
     if not analysis:
@@ -83,7 +78,8 @@ def compiled_flops(jitted, *args, **kwargs) -> Optional[float]:
 def mfu(flops_per_step: Optional[float], step_time_s: float,
         device: Optional[jax.Device] = None) -> Optional[float]:
     """Achieved fraction of peak: (FLOPs/step / step_time) / peak.
-    None when either the FLOPs or the chip peak is unknown."""
+    None off-TPU or when the cost model reported no FLOPs; an unknown
+    TPU ``device_kind`` raises (``CHIP_PEAKS``)."""
     peak = peak_flops(device)
     if not flops_per_step or not peak or step_time_s <= 0:
         return None

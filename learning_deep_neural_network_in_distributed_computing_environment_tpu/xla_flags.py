@@ -33,52 +33,46 @@ def ensure_sequential_cpu_collectives() -> bool:
     return True
 
 
-def setup_compile_cache(cache_dir: str,
-                        min_compile_secs: float = 1.0) -> bool:
-    """Enable JAX's persistent compilation cache at ``cache_dir``.
+# --- the persistent compile cache: ONE rule ----------------------------
+# A cache that moves never hits, so the directory is fixed: it is
+# ``$JAX_COMPILATION_CACHE_DIR`` when the environment sets one (JAX
+# reads that variable itself at import; this module then sets nothing),
+# and otherwise ``<checkout>/.jax_cache`` — absolute, derived from this
+# file, never the working directory, a temp name, a pid or a time.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
-    Compiled executables (the round programs, bench entries) are keyed by
-    HLO + compile options and reused across PROCESSES on the same host —
-    bench rehearsals pre-warm driver runs, repeated test/CLI invocations
-    stop paying the 20-60 s round-program compiles.  Safe no-op when the
-    runtime lacks the config knobs or the backend doesn't support
-    persistent caching (the cache is an optimization, never a
-    correctness dependency).  Imports jax lazily so this module stays
-    importable before backend init.  Also arms the hit/miss counter so
-    runs can report cache effectiveness (``compile_cache_counts``).
-    """
-    if not cache_dir:
-        return False
+
+def compile_cache_dir() -> str:
+    """Where this process keeps its persistent XLA compilation cache."""
+    return os.environ.get(CACHE_DIR_ENV) or DEFAULT_COMPILE_CACHE_DIR
+
+
+def setup_compile_cache() -> str:
+    """Arm JAX's persistent compilation cache at ``compile_cache_dir()``
+    and the hit/miss counter (``compile_cache_counts``); returns the
+    directory.  The only code site that sets
+    ``jax_compilation_cache_dir``, and it does not when the environment
+    variable is set.  Safe to call mid-process and more than once: jax
+    0.9 initializes the cache object at the first compile that finds a
+    directory configured, and the directory never changes after that.
+    Imports jax lazily so this module stays importable before backend
+    init."""
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_secs)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        reset_cache_latch()
-        install_cache_counter()
-        return True
-    except Exception:  # noqa: BLE001 — optimization only
-        return False
-
-
-def reset_cache_latch() -> None:
-    """Un-latch jax's persistent compilation cache so the NEXT compile
-    re-reads the current config.
-
-    jax latches the cache at the FIRST compile: the cache object (present
-    or absent) is initialized once and the config dir is never consulted
-    again — so arming the cache mid-process (library callers, tests, the
-    bench CLI after warmup compiles), re-pointing it at a different
-    directory, or disabling it for a timing section are all silent no-ops
-    without this.  Safe no-op when the internals drift across versions."""
-    try:
-        from jax._src import compilation_cache as _cc
-        if getattr(_cc, "_cache_initialized", False) \
-                or getattr(_cc, "_cache_checked", False):
-            _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — optimization only
-        pass
+    path = compile_cache_dir()
+    if os.environ.get(CACHE_DIR_ENV):
+        if jax.config.jax_compilation_cache_dir != path:
+            raise RuntimeError(
+                f"{CACHE_DIR_ENV}={path!r} was set after jax was "
+                "imported, so jax never read it (its cache directory is "
+                f"{jax.config.jax_compilation_cache_dir!r}); export it "
+                "before the process starts")
+    else:
+        jax.config.update("jax_compilation_cache_dir", path)
+    install_cache_counter()
+    return path
 
 
 # --- persistent-cache hit/miss telemetry (ROADMAP open item) ---------------
@@ -92,27 +86,22 @@ _cache_counts = {"hits": 0, "misses": 0}
 _cache_counter_installed = False
 
 
-def install_cache_counter() -> bool:
+def install_cache_counter() -> None:
     """Register a jax monitoring listener counting persistent-cache hits
-    and misses.  Idempotent; returns False when the runtime lacks the
-    monitoring surface (counts then stay zero — telemetry only)."""
+    and misses.  Idempotent."""
     global _cache_counter_installed
     if _cache_counter_installed:
-        return True
-    try:
-        from jax._src import monitoring
+        return
+    from jax._src import monitoring
 
-        def _listen(event, **kwargs):
-            if event == _CACHE_HIT_EVENT:
-                _cache_counts["hits"] += 1
-            elif event == _CACHE_MISS_EVENT:
-                _cache_counts["misses"] += 1
+    def _listen(event, **kwargs):
+        if event == _CACHE_HIT_EVENT:
+            _cache_counts["hits"] += 1
+        elif event == _CACHE_MISS_EVENT:
+            _cache_counts["misses"] += 1
 
-        monitoring.register_event_listener(_listen)
-        _cache_counter_installed = True
-        return True
-    except Exception:  # noqa: BLE001 — telemetry only
-        return False
+    monitoring.register_event_listener(_listen)
+    _cache_counter_installed = True
 
 
 def compile_cache_counts() -> dict:
@@ -133,28 +122,22 @@ _compile_event_counts = {"traces": 0, "compiles": 0}
 _compile_counter_installed = False
 
 
-def install_compile_counter() -> bool:
+def install_compile_counter() -> None:
     """Register a listener counting jaxpr traces and backend compiles.
-    Idempotent; returns False when the runtime lacks the monitoring
-    surface (counts then stay zero and the sanitizer's retrace budget
-    degrades to a no-op rather than a false alarm)."""
+    Idempotent."""
     global _compile_counter_installed
     if _compile_counter_installed:
-        return True
-    try:
-        from jax._src import monitoring
+        return
+    from jax._src import monitoring
 
-        def _listen(event, duration, **kwargs):
-            if event == _TRACE_EVENT:
-                _compile_event_counts["traces"] += 1
-            elif event == _BACKEND_COMPILE_EVENT:
-                _compile_event_counts["compiles"] += 1
+    def _listen(event, duration, **kwargs):
+        if event == _TRACE_EVENT:
+            _compile_event_counts["traces"] += 1
+        elif event == _BACKEND_COMPILE_EVENT:
+            _compile_event_counts["compiles"] += 1
 
-        monitoring.register_event_duration_secs_listener(_listen)
-        _compile_counter_installed = True
-        return True
-    except Exception:  # noqa: BLE001 — telemetry only
-        return False
+    monitoring.register_event_duration_secs_listener(_listen)
+    _compile_counter_installed = True
 
 
 def compile_event_counts() -> dict:

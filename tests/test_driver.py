@@ -144,7 +144,7 @@ class TestCompileCacheTelemetry:
             compile_cache_counts,
             install_cache_counter,
         )
-        assert install_cache_counter()
+        install_cache_counter()
         from jax._src import monitoring
         before = compile_cache_counts()
         monitoring.record_event("/jax/compilation_cache/cache_hits")

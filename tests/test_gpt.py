@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _qkv(l=128, h=4, d=16, b=2, seed=0):
@@ -51,7 +50,7 @@ class TestCausalAttention:
             attend, dot_product_attention)
         q, k, v = _qkv()
         mesh = build_mesh({"seq": 4}, devices[:4])
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda q, k, v: attend(q, k, v, impl=impl, axis_name="seq",
                                    causal=True),
             mesh=mesh, in_specs=(P(None, "seq"),) * 3,

@@ -186,34 +186,6 @@ class TestFlashAttention:
         for a, b in zip(g, gref):
             np.testing.assert_allclose(a, b, atol=1e-4)
 
-    def test_fused_bwd_matches_twopass(self, monkeypatch):
-        """The r5 single-pass fused backward (one softmax recompute, dq
-        as per-key-block partials) == the two-pass FA-2 backward, over
-        {bidirectional, causal} x {MHA, grouped-query}.  Block sizes are
-        shrunk to 128 so L=512 yields a 4x4 block grid — exercising the
-        fused kernel's novel paths (causal masked-tile zeroing, multi-
-        block dq-partial reduction, cross-block dk/dv accumulation),
-        which a single-block grid never enters (code-review r5)."""
-        from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
-        monkeypatch.setattr(pallas_ops, "BQ", 128)
-        monkeypatch.setattr(pallas_ops, "BK", 128)
-        rng = np.random.default_rng(4)
-        for causal in (False, True):
-            for kvh in (2, 1):
-                q, k, v = self._qkv(l=512, seed=4)
-                k, v = k[:, :, :kvh], v[:, :, :kvh]
-                o, lse = pallas_ops._flash_forward(q, k, v, causal,
-                                                   with_lse=True)
-                g = jnp.asarray(rng.normal(size=o.shape), o.dtype)
-                two = pallas_ops._flash_backward(q, k, v, o, lse, g,
-                                                 causal)
-                fused = pallas_ops._flash_backward_fused(q, k, v, o, lse,
-                                                         g, causal)
-                for a, b in zip(two, fused):
-                    np.testing.assert_allclose(
-                        np.asarray(a), np.asarray(b), atol=1e-4,
-                        err_msg=f"causal={causal} kvh={kvh}")
-
     def test_unaligned_shapes_fall_back_to_dense(self):
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops.pallas_ops import flash_attention
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops.attention import dot_product_attention

@@ -15,9 +15,6 @@ Three surfaces:
    retains executables without double-compiling, and the uniform
    ``results["memory"]`` row is emitted on every run with exact
    resident-state accounting.
-
-Honors ``JAX_GRAFT_TEST_COMPILE_CACHE`` (conftest arms it; nothing here
-disables the session cache).
 """
 
 from __future__ import annotations
@@ -33,7 +30,7 @@ import optax
 import pytest
 
 from learning_deep_neural_network_in_distributed_computing_environment_tpu import (
-    compat,
+    models as models_lib,
     probe,
     train as train_lib,
 )
@@ -114,15 +111,15 @@ class TestNamedActivations:
 
 class TestPolicyResolution:
     def test_split_spellings(self):
-        assert compat.split_remat_policy("none") == ("none", ())
-        assert compat.split_remat_policy("save_names:a,b,a") == (
+        assert models_lib.split_remat_policy("none") == ("none", ())
+        assert models_lib.split_remat_policy("save_names:a,b,a") == (
             "save_names", ("a", "b"))
         with pytest.raises(ValueError, match="at least one"):
-            compat.split_remat_policy("offload_names:")
+            models_lib.split_remat_policy("offload_names:")
         with pytest.raises(ValueError, match="must start with"):
-            compat.split_remat_policy("keep_names:a")
+            models_lib.split_remat_policy("keep_names:a")
         with pytest.raises(ValueError, match="must be one of"):
-            compat.split_remat_policy("sometimes")
+            models_lib.split_remat_policy("sometimes")
 
     def test_config_validates_names_eagerly(self):
         # valid spellings construct
@@ -152,16 +149,16 @@ class TestPolicyResolution:
             train_global(cfg, progress=False)
 
     def test_save_names_policy_resolves(self):
-        pol = compat.checkpoint_policy("save_names:attn_out,mlp_out")
+        pol = models_lib.checkpoint_policy("save_names:attn_out,mlp_out")
         assert callable(pol)
 
     def test_offload_demotes_with_logged_reason(self, caplog):
-        if compat.host_offload_supported():
+        if models_lib.host_offload_supported():
             pytest.skip("backend has pinned_host — no demotion here")
         names = ("block_out", "mlp_out")   # unique set => fresh log
-        compat._OFFLOAD_DEMOTIONS_LOGGED.discard(names)
+        models_lib._OFFLOAD_DEMOTIONS_LOGGED.discard(names)
         with caplog.at_level(logging.INFO):
-            pol = compat.checkpoint_policy("offload_names:block_out,mlp_out")
+            pol = models_lib.checkpoint_policy("offload_names:block_out,mlp_out")
         assert callable(pol)
         assert any("demoted to save_names" in r.message
                    and "pinned_host" in r.message
@@ -169,9 +166,9 @@ class TestPolicyResolution:
 
     def test_base_spellings_unchanged(self):
         for name in ("dots_saveable", "everything"):
-            compat.checkpoint_policy(name)
+            models_lib.checkpoint_policy(name)
         with pytest.raises(ValueError):
-            compat.checkpoint_policy("none")
+            models_lib.checkpoint_policy("none")
 
 
 def _make_step(policy, depth=2):
@@ -271,7 +268,7 @@ class TestMemoryAnalysisOrdering:
 
     def test_offload_arm_matches_save_arm_bytes(self):
         # demoted offload is the SAME executable residency-wise
-        if compat.host_offload_supported():
+        if models_lib.host_offload_supported():
             pytest.skip("backend has pinned_host — bytes may differ")
         vals = []
         for policy in ("save_names:attn_out", "offload_names:attn_out"):
@@ -312,18 +309,22 @@ class TestTrackedProgram:
         assert len(tp.executables()) == 2
         assert len(tp.memory_rows()) == 2
 
-    def test_fallback_never_kills_the_call(self):
-        tp = probe.TrackedProgram("p", lambda a: a + 1)  # no .lower
-        assert tp(1) == 2
+    def test_compile_failure_propagates(self):
+        """A program the backend refuses must stop the run — no retry
+        through plain jit behind an observability warning."""
+        def refuses(a):
+            raise RuntimeError("compiler refused")
+        tp = probe.TrackedProgram("p", jax.jit(refuses))
+        with pytest.raises(RuntimeError, match="compiler refused"):
+            tp(jnp.ones(3))
         assert tp.memory_rows() == []
 
     def test_memory_report_schema(self):
         tp = probe.TrackedProgram("round", jax.jit(lambda a: a + 1))
         tp(jnp.ones(3))
-        bad = probe.TrackedProgram("broken", lambda a: a)
-        bad(1)
+        uncompiled = probe.TrackedProgram("broken", jax.jit(lambda a: a))
         rep = probe.memory_report(
-            {"round": tp, "broken": bad},
+            {"round": tp, "broken": uncompiled},
             state_bytes={"params": 100, "opt_state": 200,
                          "params_gathered_peak": 800},
             n_workers=8)

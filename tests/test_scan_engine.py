@@ -84,53 +84,25 @@ class TestTraceCount:
         assert self._count_block_traces(
             scan=False, depth=2 * DEPTH) == 2 * DEPTH
 
-    def test_one_cached_executable_for_the_stack(self, tmp_path):
-        """ONE jit entry for the whole scanned stack, via the PR 2
-        persistent-cache counter: compiling the scanned train forward
-        registers exactly one cache miss (one executable), and an
-        identical fresh jit is served as one hit."""
+    def test_one_executable_for_the_stack(self):
+        """ONE backend compile for the whole scanned stack at any depth,
+        via the compile-event counter the sanitizer's retrace budget
+        reads (the persistent-cache hit path is proven on the chip by two
+        consecutive ``chip_smoke.py`` runs)."""
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.xla_flags import (
-            compile_cache_counts,
-            setup_compile_cache,
+            compile_event_counts,
+            install_compile_counter,
         )
-        if not setup_compile_cache(str(tmp_path), min_compile_secs=0.0):
-            pytest.skip("persistent compile cache unavailable")
-        try:
-            m = build(True, depth=8)
-            x = tokens()
-            params = jax.jit(
-                lambda k: m.init(k, x, train=False))(jax.random.key(0))
-            before = compile_cache_counts()
-            jax.jit(lambda p: m.apply(p, x, train=True)).lower(
-                params).compile()
-            mid = compile_cache_counts()
-            assert mid["misses"] - before["misses"] == 1
-            # a DISTINCT function object with the identical HLO: jax's
-            # in-memory executable dedupe cannot serve it, so the compile
-            # goes to the persistent cache and must HIT
-            jax.jit(lambda p: m.apply(p, x, train=True)).lower(
-                params).compile()
-            after = compile_cache_counts()
-            assert after["hits"] - mid["hits"] == 1
-            assert after["misses"] == mid["misses"]
-        finally:
-            # un-latch the tmp cache (jax initializes the cache object
-            # once — clearing the config dir alone would leave every
-            # later compile in this process hitting the tmp cache:
-            # phantom hit/miss deltas in the driver-telemetry tests
-            # downstream), then RESTORE the session cache if the suite
-            # opted into one via JAX_GRAFT_TEST_COMPILE_CACHE
-            import os
-
-            from learning_deep_neural_network_in_distributed_computing_environment_tpu.xla_flags import (
-                reset_cache_latch,
-            )
-            session_dir = os.environ.get("JAX_GRAFT_TEST_COMPILE_CACHE", "")
-            if session_dir:
-                setup_compile_cache(session_dir, min_compile_secs=0.5)
-            else:
-                jax.config.update("jax_compilation_cache_dir", None)
-                reset_cache_latch()
+        install_compile_counter()
+        m = build(True, depth=8)
+        x = tokens()
+        params = jax.jit(
+            lambda k: m.init(k, x, train=False))(jax.random.key(0))
+        before = compile_event_counts()
+        jax.jit(lambda p: m.apply(p, x, train=True)).lower(
+            params).compile()
+        assert compile_event_counts()["compiles"] \
+            - before["compiles"] == 1
 
 
 class TestScanVsUnrolled:
@@ -202,8 +174,7 @@ class TestRematPolicies:
 # The two grad-accum equivalence cases and the auto-scan driver-surface
 # case below are the tier-1 suite's heaviest engine-compile cases (~30 s,
 # ~18 s and ~11 s of fresh K-variant round-program compiles on the CI
-# host — ISSUE 11 satellite measurement); they ride the slow tier, whose
-# runs also reuse the JAX_GRAFT_TEST_COMPILE_CACHE verify.sh now arms.
+# host — ISSUE 11 satellite measurement); they ride the slow tier.
 class TestGradAccum:
     """--grad_accum K: scan K microbatches with an fp32 grad carry.
     K in {2, 4} matches the full-batch round within fp32 summation

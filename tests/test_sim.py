@@ -31,9 +31,6 @@ from learning_deep_neural_network_in_distributed_computing_environment_tpu impor
     comms,
     mesh as mesh_lib,
 )
-from learning_deep_neural_network_in_distributed_computing_environment_tpu.compat import (
-    shard_map,
-)
 from learning_deep_neural_network_in_distributed_computing_environment_tpu.config import Config
 from learning_deep_neural_network_in_distributed_computing_environment_tpu.driver import train_global
 from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import get_model
@@ -111,8 +108,8 @@ class TestAggregateSim:
                                       local_weight=0.3)
             return jax.tree_util.tree_map(lambda a: a[None], out)
         specs = (P("data"),) * (2 if poison is not None else 1)
-        f = jax.jit(shard_map(pw, mesh=mesh8, in_specs=specs,
-                              out_specs=P("data")))
+        f = jax.jit(jax.shard_map(pw, mesh=mesh8, in_specs=specs,
+                                  out_specs=P("data")))
         return f(tree, poison) if poison is not None else f(tree)
 
     @pytest.mark.parametrize("topo", TOPOS)
@@ -148,8 +145,8 @@ class TestAggregateSim:
             return (lax.psum(a[0], "data")[None],
                     lax.ppermute(a[0], "data",
                                  comms.ring_neighbors(N, 2))[None])
-        f = jax.jit(shard_map(pw, mesh=mesh8, in_specs=P("data"),
-                              out_specs=(P("data"), P("data"))))
+        f = jax.jit(jax.shard_map(pw, mesh=mesh8, in_specs=P("data"),
+                                  out_specs=(P("data"), P("data"))))
         ps, perm = f(x)
         fold = jax.jit(comms.sim_fold)(x)
         np.testing.assert_array_equal(np.asarray(ps)[0], np.asarray(fold))

@@ -302,14 +302,8 @@ class TestDriverTelemetry:
         assert pw["params"] > 0 and pw["opt_state"] > 0
         assert pw["params"] * 8 == pw["params_gathered_peak"]
         assert pw["ef_residual"] == 0 and pw["round_opt"] == 0
-        assert res["compile_cache"]["enabled"] is False
-        import os
-        if not os.environ.get("JAX_GRAFT_TEST_COMPILE_CACHE"):
-            # process-global counters: with the opt-in session cache
-            # armed (conftest), this run's compiles legitimately fire
-            # hit/miss events even though the CONFIG flag is off
-            assert res["compile_cache"] == {"enabled": False, "hits": 0,
-                                            "misses": 0}
+        assert res["compile_cache"] == {"enabled": False, "hits": 0,
+                                        "misses": 0}
 
     def test_streamed_rounds_measure_sync_wall(self, mesh8):
         res = train_global(
@@ -455,18 +449,14 @@ class TestInt8Compressed:
 
 
 class TestShardedSyncInnerAxes:
-    """The legacy check_rep verification that lifted the auto-mode dense
-    fallback (ISSUE 3 satellite / ROADMAP open item): psum_scatter /
-    all_to_all / all_gather over 'data' inside a mesh with inner TP/PP/EP
-    axes are bit-identical to the dense twin under check_rep=True with
-    the engine-style replication re-certification on the outputs."""
+    """psum_scatter / all_to_all / all_gather over 'data' inside a mesh
+    with inner TP/PP/EP axes are bit-identical to the dense twin under
+    shard_map's varying-axes check (``check_vma=True``) — what lets the
+    auto mode pick the bucketed engine on meshes with inner axes."""
 
     def _run(self, mesh_axes, spec_sharded, how="equal", wire=None):
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from learning_deep_neural_network_in_distributed_computing_environment_tpu.compat import (
-            shard_map,
-        )
         mesh = mesh_lib.build_mesh(mesh_axes)
         n = mesh_axes["data"]
         rng = np.random.default_rng(0)
@@ -477,9 +467,10 @@ class TestShardedSyncInnerAxes:
         inner = tuple(a for a in mesh_axes if a != "data")
 
         def cert(t):
-            # the engine's _certify_replication for the repl leaf: an
-            # identity pmean re-establishes the out-spec's replication
-            # certificate legacy check_rep cannot infer
+            # the bucketed engine packs the replicated leaf beside the
+            # inner-axis-sharded one, so its output is TYPED varying
+            # over the inner axes; an identity pmean (the values are
+            # equal) restores the invariance the out_spec claims
             return {"sharded": t["sharded"],
                     "repl": lax.pmean(t["repl"], inner)}
 
@@ -493,8 +484,8 @@ class TestShardedSyncInnerAxes:
             ex = lambda tt: jax.tree_util.tree_map(lambda a: a[None], tt)
             return ex(cert(out)), ex(cert(dense))
 
-        f = shard_map(body, mesh=mesh, in_specs=(specs,),
-                      out_specs=(specs, specs), check_rep=True)
+        f = jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                          out_specs=(specs, specs), check_vma=True)
         out, dense = jax.jit(f)(tree)
         return out, dense
 

@@ -97,6 +97,9 @@ _CHAIN_BREAKERS = {
     "enumerate", "zip", "tuple", "list", "dict", "set", "sorted", "repr",
     "str", "id", "tree_structure", "tree_flatten", "tree_leaves",
     "tree_unflatten", "tree_map", "ShapeDtypeStruct", "dtype", "format",
+    # static under tracing too: lax.axis_size is a Python int (the bound
+    # mesh axis's size), jax.typeof an aval (shape/dtype/vma metadata)
+    "axis_size", "typeof",
 }
 # Attribute reads that yield static metadata, not traced values.
 _STATIC_ATTRS = {"shape", "dtype", "ndim", "size", "sharding",
