@@ -58,6 +58,8 @@ import jax
 import numpy as np
 from flax import serialization
 
+from .spans import span
+
 log = logging.getLogger(__name__)
 
 _LEGACY_RE = re.compile(r"ckpt_(\d+)\.msgpack$")
@@ -233,16 +235,17 @@ class CheckpointEngine:
         mode returns here; the serialize/checksum/fsync/manifest wall
         rides the background thread.  EVERY process must call this (the
         multi-host commit barrier is collective)."""
-        t0 = time.perf_counter()
-        self._finalize()
-        state = _strip_buddy(state)
-        jax.block_until_ready(state)   # the donated-buffer snapshot fence
-        pieces, meta = snapshot_addressable(state)
-        snapshot_ms = round((time.perf_counter() - t0) * 1e3, 3)
+        row = {} if timing is None else timing
+        # the save that closes round r is epoch r + 1
+        with span("round.ckpt_snapshot", row, "ckpt_snapshot_ms",
+                  round=int(global_epoch) - 1):
+            self._finalize()
+            state = _strip_buddy(state)
+            jax.block_until_ready(state)   # the donated-buffer snapshot fence
+            pieces, meta = snapshot_addressable(state)
+        snapshot_ms = row["ckpt_snapshot_ms"]
         payload = sum(int(a.nbytes) for pl in pieces.values()
                       for _i, a in pl)
-        if timing is not None:
-            timing["ckpt_snapshot_ms"] = snapshot_ms
         self.stats["saves"] += 1
         self.stats["payload_bytes_per_save"] = payload
         self.stats["snapshot_ms_total"] = round(
@@ -309,39 +312,42 @@ class CheckpointEngine:
     def _write_shard(self, pieces, meta, epoch: int, timing) -> dict:
         """Serialize + checksum + fsync this process's shard file.
         Returns {"bytes", "crc32", "payload_bytes"}.  Single-process runs
-        the commit inline (no barrier needed)."""
-        t0 = time.perf_counter()
-        p = jax.process_index()
-        d = os.path.join(self.dir, f"ckpt_{epoch}")
-        os.makedirs(d, exist_ok=True)
-        raw = serialization.msgpack_serialize(
-            {"format": FORMAT, "process": p, "leaves": pieces})
-        path = os.path.join(d, f"shard_{p}.msgpack")
-        tmp = f"{path}.tmp.{p}"
-        with open(tmp, "wb") as f:
-            f.write(raw)
-            _maybe_crash("mid_shard")
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, path)
-        local = {"bytes": len(raw), "crc32": zlib.crc32(raw),
-                 "payload_bytes": sum(int(a.nbytes)
-                                      for pl in pieces.values()
-                                      for _i, a in pl)}
-        _maybe_crash("before_manifest")
-        if jax.process_count() == 1:
-            self._commit(epoch, local, meta, timing, t_start=t0)
-        else:
-            # multi-host: the commit wall lands separately (deferred to
-            # the main thread, _commit with t_start=None adds it); record
-            # the serialize+fsync wall here so write_ms_total covers the
-            # whole background cost on every backend
-            write_ms = round((time.perf_counter() - t0) * 1e3, 3)
-            self.stats["write_ms_total"] = round(
-                self.stats["write_ms_total"] + write_ms, 3)
-            if timing is not None:
-                timing["ckpt_write_ms"] = write_ms
-        return local
+        the commit inline (no barrier needed).  The ``round.ckpt_write``
+        span is the annotation alone: ``ckpt_write_ms`` is summed below
+        and in ``_commit``, across two threads on multi-host."""
+        with span("round.ckpt_write", round=epoch - 1):
+            t0 = time.perf_counter()
+            p = jax.process_index()
+            d = os.path.join(self.dir, f"ckpt_{epoch}")
+            os.makedirs(d, exist_ok=True)
+            raw = serialization.msgpack_serialize(
+                {"format": FORMAT, "process": p, "leaves": pieces})
+            path = os.path.join(d, f"shard_{p}.msgpack")
+            tmp = f"{path}.tmp.{p}"
+            with open(tmp, "wb") as f:
+                f.write(raw)
+                _maybe_crash("mid_shard")
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(tmp, path)
+            local = {"bytes": len(raw), "crc32": zlib.crc32(raw),
+                     "payload_bytes": sum(int(a.nbytes)
+                                          for pl in pieces.values()
+                                          for _i, a in pl)}
+            _maybe_crash("before_manifest")
+            if jax.process_count() == 1:
+                self._commit(epoch, local, meta, timing, t_start=t0)
+            else:
+                # multi-host: the commit wall lands separately (deferred to
+                # the main thread, _commit with t_start=None adds it); record
+                # the serialize+fsync wall here so write_ms_total covers the
+                # whole background cost on every backend
+                write_ms = round((time.perf_counter() - t0) * 1e3, 3)
+                self.stats["write_ms_total"] = round(
+                    self.stats["write_ms_total"] + write_ms, 3)
+                if timing is not None:
+                    timing["ckpt_write_ms"] = write_ms
+            return local
 
     def _commit(self, epoch: int, local: dict, meta, timing,
                 t_start: float | None = None) -> None:

@@ -55,6 +55,7 @@ from .mesh import (DATA_AXIS, EXPERT_AXIS, MODEL_AXIS, PIPE_AXIS,
                    SLICE_AXIS, build_mesh, initialize_distributed,
                    max_data_axis_size, resize_data_axis, world_size)
 from .models import get_model, is_attention_model, is_token_model
+from .spans import span
 from .train import LocalSGDEngine, rank0_variables
 
 log = logging.getLogger(__name__)
@@ -437,6 +438,10 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                           "recovery_source": [], "recovery_ms": [],
                           "quarantined_rounds": 0}
 
+    # stamps between set-up's phases, which follow one another from here
+    # to the probe's end (results["setup_timings"])
+    t_setup = [time.perf_counter()]
+
     # --- data ---------------------------------------------------------
     if datasets is None:
         full_train, test = load_dataset(
@@ -447,6 +452,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         trainset, valset, test = datasets
     num_classes = trainset.num_classes
     batch = cfg.batch_size
+    t_setup.append(time.perf_counter())     # data_s
 
     # --- model + engine -------------------------------------------------
     train_model = None
@@ -788,6 +794,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         if elastic_snapshot.params_template is not None:
             engine.params_template = elastic_snapshot.params_template
         state = engine.stage_state(elastic_snapshot.host_state)
+    t_setup.append(time.perf_counter())     # engine_s
 
     # --- checkpoint engine + resume (beyond-reference; off when no dir) --
     # Opening the engine sweeps stale mid-write leftovers (.tmp files,
@@ -856,6 +863,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 num_slices=engine.n_slices)
             state = engine.refresh_buddy(state)
             log.info("resumed from %s at global epoch %d", latest, start_epoch)
+    t_setup.append(time.perf_counter())     # restore_s
 
     # --- probe -> ratios -> initial partition ---------------------------
     if elastic_snapshot is None:
@@ -896,6 +904,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         rng.bit_generator.state = copy.deepcopy(elastic_snapshot.rng_state)
         log.info("continuing from membership snapshot: round %d, "
                  "workers %s", start_epoch, worker_ids)
+    t_setup.append(time.perf_counter())     # probe_s
 
     # --- reference metric structures (trainer.py:13-25) -----------------
     results: dict[str, Any] = {
@@ -1002,7 +1011,13 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     # produce bit-identical results.
     results["step_caps"] = []
     results["shard_sizes"] = []      # per-round per-worker train-shard sizes
-    results["round_timings"] = []    # per-round stage/compute/fetch/assemble
+    # one row a round: the spans' durations and the two absolute stamps
+    # (docs/ARCHITECTURE.md, "Spans of a round")
+    results["round_timings"] = []
+    setup_timings = results["setup_timings"] = {
+        key: round(t1 - t0, 3) for key, t0, t1 in zip(
+            ("data_s", "engine_s", "restore_s", "probe_s"),
+            t_setup, t_setup[1:])}
     epoch_iter = range(start_epoch, cfg.epochs_global)
     pbar = None
     if progress and jax.process_index() == 0:
@@ -1060,21 +1075,26 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                      and not sanitize and schedule is None
                      and cfg.sync_staleness == 0)
 
-    def build_inputs(tparts, vparts, caps):
+    def build_inputs(tparts, vparts, caps, row, ids):
         if streaming:
             return (chunk_feed(trainset, tparts, caps),
                     chunk_feed(valset, vparts))
         # pack AND stage onto device at prep time: in the overlapped
         # pipeline this runs while the previous round computes, so the
-        # host->device transfer rides under device time too
-        return engine.stage_pack(pack_all(trainset, tparts, "train", caps),
-                                 pack_all(valset, vparts, "val"))
+        # host->device transfer rides under device time too (h2d_ms is
+        # the host's time in device_put, not the copy's)
+        with span("round.prep.pack", row, "pack_ms", **ids):
+            packs = (pack_all(trainset, tparts, "train", caps),
+                     pack_all(valset, vparts, "val"))
+        with span("round.prep.h2d", row, "h2d_ms", **ids):
+            return engine.stage_pack(*packs)
 
-    def make_prep(tparts, vparts):
+    def make_prep(tparts, vparts, row=None, **ids):
         """Caps + packed/staged inputs for the round about to run, from
         the CURRENT sec_per_batch estimate (straggler protocol: per-worker
         step cap from the probe-seeded, measured-wall-updated EMA and the
-        time_limit grace budget)."""
+        time_limit grace budget).  ``row`` takes the pack and
+        host->device spans."""
         caps = [budget_from_time_limit(
             int(np.ceil(len(p) / batch)), float(sec_per_batch[i]),
             cfg.time_limit) for i, p in enumerate(tparts)]
@@ -1083,7 +1103,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
             for i, p in enumerate(tparts)], np.float64)
         return dict(caps=caps, steps_run=steps_run,
                     sizes=[len(p) for p in tparts],
-                    inputs=build_inputs(tparts, vparts, caps))
+                    inputs=build_inputs(tparts, vparts, caps, row, ids))
 
     walls_by_round: dict[int, tuple[np.ndarray, np.ndarray]] = {}
     next_wall_box = [start_epoch]  # next round whose wall the EMA consumes
@@ -1098,8 +1118,10 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
             sec_per_batch = 0.5 * sec_per_batch + 0.5 * measured_spb
             next_wall_box[0] += 1
 
-    def prepare_next(cur_epoch: int, cur_steps_run: np.ndarray):
-        """Re-partition + pack round ``cur_epoch + 1``.
+    def prepare_next(cur_epoch: int, cur_steps_run: np.ndarray, row: dict):
+        """Re-partition + pack round ``cur_epoch + 1``, as span
+        ``round.prep`` (``prep_ms``) of round ``cur_epoch``'s row: the
+        round this work runs beside.
 
         Runs while round ``cur_epoch`` may still be computing, so the
         straggler feedback (trainer.py:112-119, 179-188 semantics)
@@ -1109,6 +1131,12 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         (host-known at dispatch time): at equilibrium the products
         equalize, i.e. shard sizes settle inversely proportional to
         measured speed, one round later than the fully-serial reference."""
+        with span("round.prep", row, "prep_ms", round=cur_epoch):
+            with span("round.prep.partition", round=cur_epoch):
+                _repartition(cur_epoch, cur_steps_run)
+            return make_prep(train_parts, val_parts, row, round=cur_epoch)
+
+    def _repartition(cur_epoch: int, cur_steps_run: np.ndarray):
         nonlocal train_parts, val_parts
         consume_walls(upto=cur_epoch)
         round_durations = sec_per_batch * np.maximum(cur_steps_run, 1.0)
@@ -1133,7 +1161,6 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 skew_repartition(valset.labels, p, fixed_classes[i],
                                  cfg.fixed_ratio, rng)
                 for i, p in enumerate(val_parts)]
-        return make_prep(train_parts, val_parts)
 
     def report_progress(mx, global_epoch: int, wall: float, wids):
         if not (progress and jax.process_index() == 0):
@@ -1176,12 +1203,11 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         ``wids`` is the round's OWN membership roster, captured at
         dispatch: a membership change at the next boundary must not
         re-map this round's rows."""
-        t0 = time.perf_counter()
-        mx = engine.finish_metrics(handle)
-        timing["fetch_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
-        t0 = time.perf_counter()
-        _assemble_round_metrics(results, mx, wids)
-        timing["assemble_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
+        with span("round.fetch", timing, "fetch_ms", round=global_epoch):
+            mx = engine.finish_metrics(handle)
+        with span("round.assemble", timing, "assemble_ms",
+                  round=global_epoch):
+            _assemble_round_metrics(results, mx, wids)
         report_progress(mx, global_epoch, time.perf_counter() - t_dispatch,
                         wids)
         return mx
@@ -1191,11 +1217,14 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 if overlap else None)
     pending: list = []
     # no pack/stage when no rounds will run (e.g. resuming a finished run)
-    prep = (make_prep(train_parts, val_parts)
-            if start_epoch < cfg.epochs_global else None)
+    prep = None
+    setup_timings["first_prep_s"] = 0.0
+    if start_epoch < cfg.epochs_global:
+        with span("setup.first_prep", setup_timings, "first_prep_s"):
+            prep = make_prep(train_parts, val_parts)
     t_ready = None
     # deep pipeline only: the round whose completion barrier was deferred
-    inflight: list = []              # [(epoch, marker, t_disp, timing, steps)]
+    inflight: list = []            # [(epoch, markers, t_disp, timing, steps)]
     # completion time of the previously settled round: with two rounds in
     # flight, a round's device time runs from max(its dispatch, the
     # previous round's completion) — measuring from dispatch alone would
@@ -1275,9 +1304,10 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         serial flow (walls through round r-1 when preparing round r+1)."""
         if not inflight:
             return
-        ep, marker, t_disp_, timing_, steps_ = inflight.pop()
-        jax.block_until_ready(marker)
-        t_done = time.perf_counter()
+        ep, markers, t_disp_, timing_, steps_ = inflight.pop()
+        with span("round.wait", timing_, "wait_ms", round=ep):
+            jax.block_until_ready(markers)
+        t_done = timing_["t_ready_s"] = time.perf_counter()
         start = t_disp_ if t_done_prev[0] is None \
             else max(t_disp_, t_done_prev[0])
         t_done_prev[0] = t_done
@@ -1613,7 +1643,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 timing: dict[str, Any] = {"ckpt_snapshot_ms": 0.0,
                                           "ckpt_write_ms": 0.0}
                 results["round_timings"].append(timing)
-                t_disp = time.perf_counter()
+                t_disp = timing["t_dispatch_s"] = time.perf_counter()
                 if t_ready is not None:
                     # host time the device sat idle between the previous
                     # round finishing and this round's dispatch — the
@@ -1621,42 +1651,55 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                     # round_gap entry)
                     results["round_timings"][-2]["gap_ms"] = round(
                         (t_disp - t_ready) * 1e3, 3)
-                poison = None
-                if nan_armed:
-                    # stage this round's per-worker poison flags (nan@R
-                    # faults) — an EXPLICIT put, transfer-guard-safe
-                    targets = schedule.nan_targets(global_epoch,
-                                                   worker_ids)
-                    poison = engine.stage_poison(np.array(
-                        [wid in targets for wid in worker_ids],
-                        np.bool_))
-                # sanitizer donation probe: the packed round program
-                # donates its whole TrainState input — hold the
-                # pre-dispatch buffer refs so the post-wait check can
-                # assert XLA actually deleted them (the streamed path
-                # donates only the inner chunk carry, with lr_epoch
-                # deliberately read eagerly, so it is exempt; the buddy
-                # rows are NOT a program input — round_start drops them
-                # and the sync program writes the fresh copy — so they
-                # are excluded from the donation contract)
-                donated_leaves = (
-                    [l for l in jax.tree_util.tree_leaves(
-                        state.replace(buddy=None))
-                     if isinstance(l, jax.Array)]
-                    if sanitize and not streaming else None)
-                with _round_guard(san):
-                    if streaming:
-                        state, handle = engine.round_streamed_start(
-                            state, *prep["inputs"], poison=poison)
-                    else:
-                        state, handle = engine.round_start(
-                            state, *prep["inputs"], poison=poison)
-                timing["stage_ms"] = round(
-                    (time.perf_counter() - t_disp) * 1e3, 3)
+                # stage_ms is the DISPATCH span: the packs were staged in
+                # prepare_next, so it holds the poison flags' put, the
+                # donation probe and engine.round_start, which on round 0
+                # builds the programs (build_ms below)
+                with span("round.dispatch", timing, "stage_ms",
+                          round=global_epoch):
+                    poison = None
+                    if nan_armed:
+                        # stage this round's per-worker poison flags
+                        # (nan@R faults) — an EXPLICIT put,
+                        # transfer-guard-safe
+                        targets = schedule.nan_targets(global_epoch,
+                                                       worker_ids)
+                        poison = engine.stage_poison(np.array(
+                            [wid in targets for wid in worker_ids],
+                            np.bool_))
+                    # sanitizer donation probe: the packed round program
+                    # donates its whole TrainState input — hold the
+                    # pre-dispatch buffer refs so the post-wait check can
+                    # assert XLA actually deleted them (the streamed path
+                    # donates only the inner chunk carry, with lr_epoch
+                    # deliberately read eagerly, so it is exempt; the
+                    # buddy rows are NOT a program input — round_start
+                    # drops them and the sync program writes the fresh
+                    # copy — so they are excluded from the donation
+                    # contract)
+                    donated_leaves = (
+                        [l for l in jax.tree_util.tree_leaves(
+                            state.replace(buddy=None))
+                         if isinstance(l, jax.Array)]
+                        if sanitize and not streaming else None)
+                    with _round_guard(san):
+                        if streaming:
+                            state, handle = engine.round_streamed_start(
+                                state, *prep["inputs"], poison=poison)
+                        else:
+                            state, handle = engine.round_start(
+                                state, *prep["inputs"], poison=poison)
+                # which dispatch built a program (trace + lower +
+                # compile or cache load): round 0's as a rule, and any
+                # later one is "this round recompiled"
+                built = engine.take_builds()
+                timing["build_ms"] = round(
+                    sum((ms for _, ms in built), 0.0), 3)
+                timing["programs_built"] = [name for name, _ in built]
                 if engine.last_sync_stats:
                     # static per-round sync telemetry (bytes on the wire,
-                    # mode); the measured collective wall joins after
-                    # round_wait when a standalone sync program ran
+                    # mode); the measured sync_ms joins after round_wait
+                    # when a standalone sync program ran
                     timing.update(engine.last_sync_stats)
                 cur_steps_run = prep["steps_run"]
                 if overlap:
@@ -1675,22 +1718,25 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 finish_inflight()
                 if defer:
                     # two rounds in flight: leave THIS round computing
-                    inflight.append((global_epoch,
-                                     engine.round_done_marker(handle),
+                    markers = engine.round_markers(handle)
+                    if markers[1] is not None:
+                        # the host comes to this round's standalone sync
+                        # late and never waits on it: no sync_ms, rather
+                        # than a 0.0 that reads as a measured wall
+                        timing.pop("sync_ms", None)
+                    inflight.append((global_epoch, markers,
                                      t_disp, timing, cur_steps_run))
                     t_ready = None  # device not idle between rounds here
                 if overlap and not last_round:
-                    t0 = time.perf_counter()
-                    prep = prepare_next(global_epoch, cur_steps_run)
-                    timing["prep_ms"] = round(
-                        (time.perf_counter() - t0) * 1e3, 3)
+                    prep = prepare_next(global_epoch, cur_steps_run, timing)
                 crashed: list[int] = []
                 if not defer:
-                    with _round_guard(san):
-                        state = engine.round_wait(state)
+                    with span("round.wait", timing, "wait_ms",
+                              round=global_epoch), _round_guard(san):
+                        state = engine.round_wait(state, handle)
                     if engine.last_sync_stats:
                         timing.update(engine.last_sync_stats)
-                    t_ready = time.perf_counter()
+                    t_ready = timing["t_ready_s"] = time.perf_counter()
                     # the barrier round right after a deferred one also
                     # started computing only when its predecessor
                     # finished (same double-count hazard finish_inflight
@@ -1752,10 +1798,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                     process_quarantine(global_epoch,
                                        np.asarray(mx["sync_ok"]))
                 if not last_round:
-                    t0 = time.perf_counter()
-                    prep = prepare_next(global_epoch, cur_steps_run)
-                    timing["prep_ms"] = round(
-                        (time.perf_counter() - t0) * 1e3, 3)
+                    prep = prepare_next(global_epoch, cur_steps_run, timing)
 
             if ckpt_engine is not None and jax.process_count() > 1:
                 # bound the multi-host deferred-commit window to ONE
@@ -1818,6 +1861,16 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         pbar.close()
     if profiling:
         jax.profiler.stop_trace()
+    if results["round_timings"]:
+        row0 = results["round_timings"][0]
+        log.info("set-up: data %.3f s, engine %.3f s, restore %.3f s, "
+                 "probe %.3f s, first prep %.3f s; the first round built "
+                 "%s in %.1f ms of a %.1f ms dispatch",
+                 *(setup_timings[k] for k in (
+                     "data_s", "engine_s", "restore_s", "probe_s",
+                     "first_prep_s")),
+                 row0["programs_built"], row0["build_ms"],
+                 row0["stage_ms"])
 
     # persistent-compile-cache effectiveness for THIS run (ROADMAP open
     # item): how many executable lookups the armed cache served vs compiled

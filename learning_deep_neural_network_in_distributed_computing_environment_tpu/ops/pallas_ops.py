@@ -177,6 +177,7 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_fwd",
     )(qt, kt, vt)
     if with_lse:
         return out[0].transpose(0, 2, 1, 3), out[1]
@@ -325,7 +326,7 @@ def _flash_backward(q, k, v, o, lse, g, causal):
         in_specs=[row(d), col(d), col(d), row(d), row(LANES), row(LANES)],
         out_specs=row(d),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=params, interpret=_interpret(),
+        compiler_params=params, interpret=_interpret(), name="flash_dq",
     )(qt, kt, vt, gt, lse, delta)
 
     dkt, dvt = pl.pallas_call(
@@ -339,7 +340,7 @@ def _flash_backward(q, k, v, o, lse, g, causal):
         out_specs=[colT(d), colT(d)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=params, interpret=_interpret(),
+        compiler_params=params, interpret=_interpret(), name="flash_dkv",
     )(qt, kt, vt, gt, lse, delta)
     return (dqt.transpose(0, 2, 1, 3), dkt.transpose(0, 2, 1, 3),
             dvt.transpose(0, 2, 1, 3))

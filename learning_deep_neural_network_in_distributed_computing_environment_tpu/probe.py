@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .spans import span
+
 
 # ----------------------------------------------------------------------
 # Compiled-memory observability (ISSUE 15)
@@ -60,11 +62,22 @@ class TrackedProgram:
     be retried through ``jit`` under an observability warning.  Only a
     multi-process run takes the plain jit call path (its memory row
     reports ``available: False``).
+
+    ``_compile`` is the one site where a program is traced, lowered and
+    compiled (or loaded from the compile cache).  It is a span under the
+    owner's name for it (``build_span``; the round loop's engine says
+    ``round.build``) and appends ``(name, build_ms)`` to ``built`` — the
+    list an engine shares among its programs and the driver drains into
+    the row of the round whose dispatch built them.
     """
 
-    def __init__(self, name: str, fn, *, multi_shape: bool = False):
+    def __init__(self, name: str, fn, *, multi_shape: bool = False,
+                 built: list | None = None,
+                 build_span: str = "program.build"):
         self.name = name
         self._fn = fn
+        self._built = built
+        self._build_span = build_span
         self._multi = bool(multi_shape)
         self._multiprocess = jax.process_count() > 1
         self.compiled = None           # single-shape executable
@@ -77,7 +90,12 @@ class TrackedProgram:
             for l in jax.tree_util.tree_leaves(args))
 
     def _compile(self, args, kwargs):
-        return self._fn.lower(*args, **kwargs).compile()
+        row: dict = {}
+        with span(self._build_span, row, "build_ms", program=self.name):
+            comp = self._fn.lower(*args, **kwargs).compile()
+        if self._built is not None:
+            self._built.append((self.name, row["build_ms"]))
+        return comp
 
     def __call__(self, *args, **kwargs):
         if self._multiprocess:
@@ -232,33 +250,6 @@ def gather_durations(local_duration: float, world_size: int,
                 "attribution would be wrong")
         return np.repeat(per_process, world_size // per_process.size)
     return np.full(world_size, local_duration, np.float64)
-
-
-def attribute_sync_wall(sync_ms: float, ici_bytes: int, dcn_bytes: int,
-                        dcn_cost_factor: float = 1.0
-                        ) -> tuple[float, float]:
-    """Split one measured sync wall across the two interconnect levels
-    (ISSUE 13): ``(ici_ms, dcn_ms)``.
-
-    The round loop measures ONE wall for the whole fused/standalone sync
-    program — the two levels execute inside a single XLA program and
-    cannot be timed separately from the host.  This attribution is a
-    declared MODEL, not a measurement: the wall splits proportionally to
-    each level's wire bytes, with ``dcn_cost_factor`` weighting a DCN
-    byte's relative cost (1.0 on CPU where both "wires" are local
-    memcpys — the honest default the tests pin; a real multi-pod
-    deployment calibrates it from the measured DCN/ICI bandwidth ratio,
-    the ROADMAP real-TPU follow-on).  The per-level walls feed the same
-    telemetry rows (``sync_ms_ici`` / ``sync_ms_dcn``) and, on
-    heterogeneous fleets, the straggler EMA's view of where a slow
-    round's time went.  Flat rounds (zero DCN bytes) attribute the whole
-    wall to the ICI level — the schema is identical on every engine."""
-    total = float(ici_bytes) + float(dcn_bytes) * float(dcn_cost_factor)
-    if total <= 0 or sync_ms <= 0:
-        return (round(float(sync_ms), 3), 0.0)
-    dcn_ms = float(sync_ms) * (float(dcn_bytes) * float(dcn_cost_factor)
-                               / total)
-    return (round(float(sync_ms) - dcn_ms, 3), round(dcn_ms, 3))
 
 
 def joiner_sec_per_batch(survivor_spb: np.ndarray,

@@ -204,10 +204,7 @@ class SimEngine(LocalSGDEngine):
                                 # hide, so the column is always zero
                                 "sync_hidden_ms": 0.0,
                                 "sync_bytes_ici": ici,
-                                "sync_bytes_dcn": dcn,
-                                "sync_ms_ici": 0.0,
-                                "sync_ms_dcn": 0.0}
-        self._sync_probe = None
+                                "sync_bytes_dcn": dcn}
 
     # ------------------------------------------------------------------
     # Scenario draws
@@ -426,7 +423,7 @@ class SimEngine(LocalSGDEngine):
                     lambda a: jax.ShapeDtypeStruct(
                         a.shape, a.dtype, sharding=a.sharding),
                     state.params)
-                tp.compiled = tp._fn.lower(spec, spec).compile()
+                tp.compiled = tp._compile((spec, spec), {})
         extra = ()
         if self.scenario_on:
             active, dropped, noise_key = self._draw_scenario()

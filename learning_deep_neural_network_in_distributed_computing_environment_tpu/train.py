@@ -61,6 +61,7 @@ from . import probe as probe_lib
 from .config import Config
 from .data.augment import augment_batch
 from .mesh import DATA_AXIS, SLICE_AXIS
+from .spans import span
 
 log = logging.getLogger(__name__)
 PyTree = Any
@@ -496,6 +497,8 @@ class LocalSGDEngine:
         # stable label — probe.memory_report walks this registry into
         # the uniform results["memory"] row
         self._programs: dict[str, probe_lib.TrackedProgram] = {}
+        # (label, build_ms) of every program built since ``take_builds``
+        self._built: list[tuple[str, float]] = []
         self._spec = (P((SLICE_AXIS, DATA_AXIS)) if self.slice_axis
                       else P(DATA_AXIS))
         # --- round-sync engine selection (ISSUE 2 / ISSUE 13) ----------
@@ -676,7 +679,6 @@ class LocalSGDEngine:
         self.staleness_serial = bool(
             os.environ.get("JAX_GRAFT_STALENESS_SERIAL"))
         self.last_sync_stats: dict | None = None
-        self._sync_probe = None      # (ready_marker | None, sync_out_ref)
         self._sync_bytes: int | None = None
         self._sync_bytes_split: tuple = (0, 0)   # (ici, dcn) per level
 
@@ -859,7 +861,7 @@ class LocalSGDEngine:
         """Reset ``last_sync_stats`` for the round being dispatched: the
         static per-round wire bytes (from the bucket plan over per-worker
         logical shapes) + mode + a zero ``sync_ms``; ``round_wait``
-        overwrites ``sync_ms`` with the measured collective wall when a
+        overwrites ``sync_ms`` with the measured wait on the sync when a
         standalone sync program ran.  The schema is identical across all
         three topologies and every engine (zero-filled where a
         measurement does not apply), so downstream viz/bench can key on
@@ -929,15 +931,9 @@ class LocalSGDEngine:
                                 # schema on every engine — flat rounds
                                 # report all bytes as the intra-slice
                                 # (ICI) level and zero DCN, hierarchical
-                                # rounds the true split; the ms fields
-                                # are the byte-proportional attribution
-                                # of the measured sync wall
-                                # (probe.attribute_sync_wall)
+                                # rounds the true split
                                 "sync_bytes_ici": ici,
-                                "sync_bytes_dcn": dcn,
-                                "sync_ms_ici": 0.0,
-                                "sync_ms_dcn": 0.0}
-        self._sync_probe = None
+                                "sync_bytes_dcn": dcn}
 
     def _track(self, key, fn, name: str):
         """Install a freshly-built engine program into the round cache
@@ -951,11 +947,20 @@ class LocalSGDEngine:
         label, i = name, 2
         while label in self._programs:
             label, i = f"{name}#{i}", i + 1
-        tp = probe_lib.TrackedProgram(label, fn)
+        tp = probe_lib.TrackedProgram(label, fn, built=self._built,
+                                      build_span="round.build")
         self._programs[label] = tp
         if key is not None:
             self._round_cache[key] = tp
         return tp
+
+    def take_builds(self) -> list[tuple[str, float]]:
+        """``(label, build_ms)`` of every program traced, lowered and
+        compiled (or loaded from the compile cache) since the last call:
+        the driver folds them into the row of the round whose dispatch
+        built them (``build_ms``, ``programs_built``)."""
+        built, self._built[:] = list(self._built), []
+        return built
 
     def memory_programs(self) -> dict:
         """Label -> TrackedProgram registry of every cached engine
@@ -1859,6 +1864,9 @@ class LocalSGDEngine:
                 poison=poi)
             return tuple(map(expand, outs))
 
+        # the compiled module is ``jit_<name>``: what the device trace
+        # calls this program
+        stacked.__name__ = "localsgd_round"
         sspec = self._sspec if self._sspec is not None else self._spec
         pspec = self._sspec.params if self._sspec is not None else self._spec
         emit_grads = self.split_sync and cfg.aggregation_by == "gradients"
@@ -1967,7 +1975,8 @@ class LocalSGDEngine:
         if self.split_sync:
             # the sync program consumes the round's outputs, so its
             # dispatch chains behind the still-running round program; the
-            # probe lets round_wait time the collective wall separately
+            # handle carries both markers so that ``round_wait`` can time
+            # the wait on the sync apart
             if "sync" not in self._round_cache:
                 self._round_cache["sync"] = self._build_sync()
             sync = self._round_cache["sync"]
@@ -2007,32 +2016,41 @@ class LocalSGDEngine:
                 sync_norm = d["out"]
                 fence = sync_norm
             sync_ok = d.get("ok")
-            self._sync_probe = (metrics["train_loss"], fence)
         return new_state, ("packed", metrics, sync_norm, fence, sync_ok)
 
-    def round_wait(self, new_state: TrainState) -> TrainState:
+    def round_markers(self, handle) -> tuple:
+        """``(round marker, sync fence)`` of a dispatched round: two
+        small, never-donated device arrays.  The first materializes when
+        the round program has run, the second (None when the sync ran
+        fused, or under staleness) when the standalone sync program
+        behind it has.  The deep-pipeline driver keeps them with the
+        round it leaves in flight and blocks on them instead of the
+        state, whose buffers the NEXT round's dispatch already donated.
+        A streamed round has no round marker: its per-epoch barrier has
+        materialized everything before the sync."""
+        if handle[0] == "packed":
+            _, metrics, _sync_norm, fence, _ok = handle
+            return metrics["train_loss"], fence
+        return None, handle[-1]
+
+    def round_wait(self, new_state: TrainState, handle) -> TrainState:
         """Block until a dispatched round's state is materialized — the
         barrier that keeps at most one round program in flight.
 
         When a standalone sync program ran (split_sync / streamed rounds),
-        also measures its collective wall into ``last_sync_stats``: block
-        on the round-program marker first, then time the block on the sync
-        output — the difference is the sync program's execution (plus its
-        dispatch overhead)."""
-        probe, self._sync_probe = self._sync_probe, None
-        if probe is not None:
-            marker, out_ref = probe
-            if marker is not None:
-                jax.block_until_ready(marker)
-            t0 = time.perf_counter()
-            jax.block_until_ready(out_ref)
-            if self.last_sync_stats is not None:
-                sync_ms = round((time.perf_counter() - t0) * 1e3, 3)
-                self.last_sync_stats["sync_ms"] = sync_ms
-                ici_ms, dcn_ms = probe_lib.attribute_sync_wall(
-                    sync_ms, *self._sync_bytes_split)
-                self.last_sync_stats["sync_ms_ici"] = ici_ms
-                self.last_sync_stats["sync_ms_dcn"] = dcn_ms
+        also measures the host's wait on it into ``last_sync_stats``:
+        block on the round program's marker first, then time the block on
+        the sync's fence (span ``round.sync``).  The caller is here before
+        the round program ends, so that is the sync program's execution
+        plus its dispatch overhead.  A round the driver leaves in flight
+        is not settled here and gets no ``sync_ms``: the host comes to
+        it late and would time two blocks that return at once."""
+        marker, fence = self.round_markers(handle)
+        if marker is not None:
+            jax.block_until_ready(marker)
+        if fence is not None:
+            with span("round.sync", self.last_sync_stats, "sync_ms"):
+                jax.block_until_ready(fence)
         return jax.block_until_ready(new_state)
 
     # ------------------------------------------------------------------
@@ -2082,12 +2100,8 @@ class LocalSGDEngine:
         hidden_ms = (0.0 if self.staleness_serial
                      else max(0.0, wall_ms - exposed_ms))
         params = self._round_cache["deliver"](state.params, rec["delta"])
-        ici_ms, dcn_ms = probe_lib.attribute_sync_wall(
-            round(wall_ms, 3), *self._sync_bytes_split)
         self._delivered_stats = {"sync_ms": round(wall_ms, 3),
-                                 "sync_hidden_ms": round(hidden_ms, 3),
-                                 "sync_ms_ici": ici_ms,
-                                 "sync_ms_dcn": dcn_ms}
+                                 "sync_hidden_ms": round(hidden_ms, 3)}
         self.stale_log.append({"sync_ms": round(wall_ms, 3),
                                "sync_hidden_ms": round(hidden_ms, 3),
                                "exposed_ms": round(exposed_ms, 3)})
@@ -2117,7 +2131,7 @@ class LocalSGDEngine:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                sharding=a.sharding),
                 new_state.params)
-            tp.compiled = tp._fn.lower(spec, spec).compile()
+            tp.compiled = tp._compile((spec, spec), {})
         args = [new_state.params]
         if self.sync_ef:
             # the EF residual chains sync-to-sync engine-side: sync R
@@ -2206,7 +2220,8 @@ class LocalSGDEngine:
         prog = self._track(None,
                            self._wrap_stacked(per_worker, in_specs,
                                               out_specs=out_specs,
-                                              donate=donate),
+                                              donate=donate,
+                                              name="stale_sync"),
                            "stale_sync")
 
         def run(*args):
@@ -2230,16 +2245,6 @@ class LocalSGDEngine:
         ROADMAP's offloaded-remat item waits on."""
         return jax.block_until_ready(state)
 
-    def round_done_marker(self, handle):
-        """A small, never-donated device array that materializes when the
-        round's device work — including any standalone sync program — has
-        completed.  The deep-pipeline driver blocks on this instead of the
-        state (whose buffers the NEXT round's dispatch already donated)."""
-        if handle[0] != "packed":
-            raise ValueError("round_done_marker applies to packed rounds")
-        _, metrics, _sync_norm, fence, _ok = handle
-        return fence if fence is not None else metrics["train_loss"]
-
     def finish_metrics(self, handle) -> dict:
         """Fetch + assemble a dispatched round's host metrics.
 
@@ -2258,7 +2263,7 @@ class LocalSGDEngine:
                 # standalone sync program
                 mx["sync_ok"] = self._fetch(sync_ok)
             return mx
-        _, per_epoch, agg_grad_norm, sync_ok = handle
+        _, per_epoch, agg_grad_norm, sync_ok, _fence = handle
         mx = self._assemble_streamed(per_epoch, agg_grad_norm)
         if sync_ok is not None:
             mx["sync_ok"] = self._fetch(sync_ok)
@@ -2267,7 +2272,7 @@ class LocalSGDEngine:
     def round(self, state: TrainState, train_pack, val_pack):
         """Serial convenience wrapper: dispatch, block, fetch."""
         new_state, handle = self.round_start(state, train_pack, val_pack)
-        new_state = self.round_wait(new_state)
+        new_state = self.round_wait(new_state, handle)
         return new_state, self.finish_metrics(handle)
 
     # ------------------------------------------------------------------
@@ -2282,8 +2287,10 @@ class LocalSGDEngine:
     # bytes ever return to the host.
 
     def _wrap_stacked(self, per_worker, in_specs, out_specs=None,
-                      donate=False):
-        """shard_map a per-worker fn over the worker-stacked leading axis."""
+                      donate=False, *, name: str):
+        """shard_map a per-worker fn over the worker-stacked leading axis.
+        The compiled module is ``jit_localsgd_<name>``: the program's
+        label, which is what the device trace then calls it."""
 
         def stacked(*args):
             sq = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
@@ -2292,6 +2299,7 @@ class LocalSGDEngine:
                          for a, s in zip(args, in_specs)]
             return ex(per_worker(*unstacked))
 
+        stacked.__name__ = f"localsgd_{name}"
         fn = jax.shard_map(stacked, mesh=self.mesh,
                            in_specs=tuple(in_specs),
                            out_specs=out_specs or self._spec)
@@ -2314,7 +2322,7 @@ class LocalSGDEngine:
         inner = self._inner_specs()
         return self._wrap_stacked(
             per_worker, [inner, P(), xs, ys_, ms],
-            out_specs=(inner, self._spec), donate=True)
+            out_specs=(inner, self._spec), donate=True, name="chunk_train")
 
     def _build_chunk_eval(self, shapes_key):
         _, eval_step = self._make_step_fns(False)
@@ -2333,7 +2341,7 @@ class LocalSGDEngine:
             else self._spec
         return self._wrap_stacked(
             per_worker, [pspec, bspec, xs, ys_, ms],
-            out_specs=self._spec)
+            out_specs=self._spec, name="chunk_eval")
 
     def _build_sync(self):
         """The standalone donated sync program (streamed rounds on every
@@ -2348,9 +2356,9 @@ class LocalSGDEngine:
         resident shards / agg norm), plus ``residual`` / ``tracker`` /
         ``buddy`` (ISSUE 12 ring-successor copies) / ``ok`` (ISSUE 12
         per-worker validity) as armed, and ``fence`` — a tiny
-        never-donated per-worker scalar marker for the sync-wall probe
-        and the deep-pipeline driver (in gradients mode ``out`` IS the
-        fence)."""
+        never-donated per-worker scalar marker that ``round_wait`` times
+        the sync on and the deep-pipeline driver blocks on (in gradients
+        mode ``out`` IS the fence)."""
         cfg = self.cfg
 
         def _fence(tree):
@@ -2439,7 +2447,8 @@ class LocalSGDEngine:
         prog = self._track(None,
                            self._wrap_stacked(per_worker, in_specs,
                                               out_specs=out_specs,
-                                              donate=tuple(donate)),
+                                              donate=tuple(donate),
+                                              name="sync"),
                            "sync")
 
         def run(*args, poison=None):
@@ -2618,9 +2627,9 @@ class LocalSGDEngine:
             sync_ok = d.get("ok")
             fence = agg_grad_norm
         # everything before the sync is already materialized (the
-        # per-epoch barrier above), so the block on the fence times the
-        # sync program's collectives alone
-        self._sync_probe = (None, fence)
+        # per-epoch barrier above), so ``round_wait``'s block on the
+        # fence, which rides the handle, times the sync program's
+        # collectives alone
 
         # the epoch bump runs as a tiny cached program: eager arithmetic
         # with a Python/numpy scalar is an IMPLICIT host->device transfer
@@ -2637,7 +2646,8 @@ class LocalSGDEngine:
             lr_epoch=self._round_cache["bump_epoch"](state.lr_epoch),
             rng=rng, sync_residual=residual, round_opt=round_opt,
             buddy=new_buddy, sync_residual_outer=outer_res)
-        return new_state, ("streamed", per_epoch, agg_grad_norm, sync_ok)
+        return new_state, ("streamed", per_epoch, agg_grad_norm, sync_ok,
+                           fence)
 
     def _assemble_streamed(self, per_epoch, agg_grad_norm) -> dict:
         """Fetch + assemble a streamed round's metrics into the same mx
@@ -2682,5 +2692,5 @@ class LocalSGDEngine:
         (same step bodies, same RNG stream)."""
         new_state, handle = self.round_streamed_start(
             state, train_chunks, val_chunks)
-        new_state = self.round_wait(new_state)
+        new_state = self.round_wait(new_state, handle)
         return new_state, self.finish_metrics(handle)

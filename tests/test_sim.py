@@ -338,8 +338,7 @@ class TestEngineParity:
         # sync_hidden_ms, zero-filled everywhere but staleness runs)
         assert set(stats) == {"sync_bytes", "sync_mode", "sync_ms",
                               "sync_hidden_ms",
-                              "sync_bytes_ici", "sync_bytes_dcn",
-                              "sync_ms_ici", "sync_ms_dcn"}
+                              "sync_bytes_ici", "sync_bytes_dcn"}
         assert stats["sync_hidden_ms"] == 0.0
         assert stats["sync_mode"] == "sim"
         assert stats["sync_bytes"] == comms.sim_wire_bytes(
