@@ -129,6 +129,8 @@ def check_flash_kernels() -> None:
     flash = lambda q, k, v: attend(q, k, v, impl="flash", causal=True)
     dense = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
     for name, (b, l, h, kv, d) in {
+            "mha L=1024 (gpt2s_train_1k, the benchmark's)":
+                (4, 1024, 12, 12, 64),
             "mha L=4096 (gpt2_4k_flash)": (1, 4096, 12, 12, 64),
             "gqa L=2048 16/4 (llama_gqa4)": (1, 2048, 16, 4, 64)}.items():
         keys = jax.random.split(jax.random.key(l), 4)
@@ -157,6 +159,24 @@ def check_flash_kernels() -> None:
     if pallas_ops._FALLBACK_LOGGED:
         raise AssertionError(
             f"flash fell back to dense: {pallas_ops._FALLBACK_LOGGED}")
+    say_flash_tiles()
+
+
+def say_flash_tiles() -> None:
+    """The kernels' tile registry: a causal shape of more than one sub-tile
+    must skip some and mask some, any other shape must visit them all
+    unmasked."""
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import (
+        pallas_ops,
+    )
+    for key, (visited, total, masked) in sorted(
+            pallas_ops.TILE_COUNTS.items()):
+        causal = key[2]
+        say(pallas_ops.tiles_line(key))
+        if ((visited < total) != (causal and total > 1)
+                or (masked > 0) != causal):
+            raise AssertionError(f"wrong for this shape: "
+                                 f"{pallas_ops.tiles_line(key)}")
 
 
 # ----------------------------------------------------------------------
@@ -201,6 +221,7 @@ def check_training(results: dict, n_dev: int, *, flash: bool) -> None:
         if pallas_ops._FALLBACK_LOGGED:
             raise AssertionError(
                 f"flash fell back to dense: {pallas_ops._FALLBACK_LOGGED}")
+        say_flash_tiles()
 
 
 def state_leaves(state) -> list:

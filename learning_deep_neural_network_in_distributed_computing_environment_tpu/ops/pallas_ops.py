@@ -16,6 +16,11 @@ recomputes softmax probabilities per block pair from (q, k, lse) and runs
 two passes — a dQ kernel (K/V innermost) and a dK/dV kernel (Q innermost)
 — so training never materializes an L x L score matrix either.
 
+Causal calls do work only on the causal triangle: grid blocks above the
+diagonal are skipped, and a block the diagonal crosses is walked in
+``TILE``-wide sub-tiles inside the kernel (``_visit``), of which those
+above the diagonal are not visited and only those on it are masked.
+
 Falls back to the dense XLA path when shapes don't satisfy the tiling
 constraints, and runs in interpreter mode on CPU (tests).
 
@@ -37,17 +42,22 @@ from .attention import NEG_INF
 
 BQ = 1024  # query block (MXU-aligned)
 BK = 1024  # key/value block
-# (block sizes swept on v5e: r3 found (512, 1024) beating (256, 512) at
-# every L; r5 extended the sweep to (1024, 1024), which wins again —
-# train-step A/B 1.85 -> 1.47 ms at L=2048 (-20%) and 6.77 -> 6.42 ms
-# at L=8192, lifting gpt2_4k_flash 55.7 -> 58.1% MFU and llama_gqa4
-# 51.5 -> 53.3% end to end.  Mechanism: doubling BQ halves the number
-# of query-block sweeps ni, which halves the K/V HBM re-fetch traffic
-# (K/V blocks stream once per (i, j) cell) and the per-grid-step
-# pipeline overhead; the per-element softmax/exp work is BQ-invariant.
-# The sweep is closed upward: (1024, 2048) measured worse at both
-# L=2048 and L=8192, and (2048, 1024) tied at L=8192 while failing to
-# lower at L=2048 — (1024, 1024) is the v5e optimum for d=64.)
+TILE = 256  # causal sub-tile inside a block that the diagonal crosses
+# (block sizes swept on v5e at L >= 2048 only: r3 found (512, 1024)
+# beating (256, 512); r5 extended the sweep to (1024, 1024), which wins
+# again — train-step A/B 1.85 -> 1.47 ms at L=2048 and 6.77 -> 6.42 ms
+# at L=8192.  Mechanism: doubling BQ halves the K/V HBM re-fetches (K/V
+# blocks stream once per (i, j) cell) and the per-grid-step pipeline
+# overhead.  The sweep is closed upward: (1024, 2048) measured worse
+# and (2048, 1024) fails to lower at L=2048.
+# At L <= 1024 one block covers the sequence, the grid is (b, h, 1, 1)
+# and the grid-level causal skip never fires: the work is bounded INSIDE
+# the block instead.  A block the diagonal crosses is walked in TILE-wide
+# sub-tiles on operands already in VMEM (``_block_groups``): sub-tiles
+# above the diagonal are not visited, those below it run unmasked as one
+# slab, only those on it pay the iota/compare/select.  Same grid, same
+# HBM traffic, same operands; at L > 1024 the diagonal blocks gain the
+# same way and the blocks below them drop their mask.)
 
 
 def _interpret() -> bool:
@@ -55,58 +65,190 @@ def _interpret() -> bool:
 
 
 LANES = 128  # lane padding for per-row (lse/delta) tensors, TPU tile width
+ALL = slice(None)
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                  *, scale: float, nk: int, bq: int, bk: int, causal: bool):
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def tile_plan(bq: int, bk: int, tq: int, tk: int, off: int = 0,
+              causal: bool = True) -> list:
+    """``(n_full, n_vis)`` for each ``tq``-row sub-tile of a ``[bq, bk]``
+    block whose first query sits ``off`` positions after its first key
+    (top-left aligned mask: key c visible to query r iff c <= r + off).
+    Of the row's ``bk // tk`` key sub-tiles the first ``n_full`` lie wholly
+    on or below the diagonal (no mask), the next ``n_vis - n_full`` are
+    crossed by it (masked) and the rest lie above it (not visited)."""
+    nkt = bk // tk
+    if not causal:
+        return [(nkt, nkt)] * (bq // tq)
+    clip = lambda n: max(0, min(nkt, n))
+    return [(clip((off + a * tq + 1) // tk),
+             clip((off + a * tq + tq - 1) // tk + 1))
+            for a in range(bq // tq)]
+
+
+def tile_counts(plan: list) -> tuple:
+    """(visited, masked) sub-tiles of a plan."""
+    return (sum(n_vis for _, n_vis in plan),
+            sum(n_vis - n_full for n_full, n_vis in plan))
+
+
+def _block_groups(bq, bk, tq, tk, off, by_key=False):
+    """The pieces of a block that the causal mask leaves, as static slices.
+
+    A list of ``(rows, [(cols, mask_off), ...])``: per query sub-tile its
+    unmasked key slab then its masked one (``by_key``: per key sub-tile its
+    masked query slab then its unmasked one; the pair then reads
+    ``(cols, [(rows, mask_off), ...])``).  ``mask_off`` is the piece's own
+    ``off`` for ``_scores``, ``None`` where no mask is needed; a sub-tile
+    that sees nothing keeps its entry, with no pieces."""
+    plan = tile_plan(bq, bk, tq, tk, off)
+    groups = []
+    if by_key:
+        for c in range(bk // tk):
+            vis = sum(n_vis <= c for _, n_vis in plan)      # first visited
+            full = sum(n_full <= c for n_full, _ in plan)   # first unmasked
+            pieces = []
+            if full > vis:
+                pieces.append((slice(vis * tq, full * tq),
+                               off + vis * tq - c * tk))
+            if full < len(plan):
+                pieces.append((slice(full * tq, bq), None))
+            groups.append((slice(c * tk, (c + 1) * tk), pieces))
+        return groups
+    for a, (n_full, n_vis) in enumerate(plan):
+        pieces = []
+        if n_full:
+            pieces.append((slice(0, n_full * tk), None))
+        if n_vis > n_full:
+            pieces.append((slice(n_full * tk, n_vis * tk),
+                           off + a * tq - n_full * tk))
+        groups.append((slice(a * tq, (a + 1) * tq), pieces))
+    return groups
+
+
+def _tiles(bq, bk):
+    """The (query, key) sub-tile of a block: TILE where it divides."""
+    return _block_size(bq, TILE), _block_size(bk, TILE)
+
+
+def _crossing_offsets(ni, nk, bq, bk):
+    """Every ``i * bq - j * bk`` of a grid block the diagonal crosses
+    (``{0}`` whenever bq == bk or one block covers the sequence)."""
+    return sorted({i * bq - j * bk for i in range(ni) for j in range(nk)
+                   if -bq < i * bq - j * bk < bk - 1})
+
+
+def _visit(i, j, *, ni, nk, bq, bk, causal, body, by_key=False):
+    """Run ``body(major, pieces)`` over the part of grid block (i, j) that
+    the mask leaves.  Not causal, or wholly below the diagonal: the block
+    in one unmasked piece.  Crossed by the diagonal: sub-tiled, unrolled
+    from the static geometry.  Wholly above it: nothing."""
+    whole = lambda: body(ALL, [(ALL, None)])
+    if not causal:
+        return whole()
+    static = ni * nk == 1       # the one block: i = j = 0, nothing to test
+    off = i * bq - j * bk
+    if not static:
+        pl.when(off >= bk - 1)(whole)
+    for o in _crossing_offsets(ni, nk, bq, bk):
+        def crossed(o=o):
+            for major, pieces in _block_groups(bq, bk, *_tiles(bq, bk), o,
+                                               by_key):
+                body(major, pieces)
+        crossed() if static else pl.when(off == o)(crossed)
+
+
+def _scores(q, k, scale, off):
+    """f32 ``q @ k.T * scale``; with ``off`` (first query position minus
+    first key position of the piece) the future keys read NEG_INF."""
+    s = _dot(q, k, _NT) * scale
+    if off is not None:
+        qpos = off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos <= qpos, s, NEG_INF)
+    return s
+
+
+def _one_block(causal: bool, ni: int, nk: int, rep: int = 1) -> bool:
+    """A causal call whose score square is ONE grid block (L <= 1024, no
+    query group to fold): the geometry is static and nothing is carried
+    from grid step to grid step, so the kernels hold no scratch state and
+    write each sub-tile's result straight to the output block."""
+    return causal and ni == nk == rep == 1
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
+                  scale: float, ni: int, nk: int, bq: int, bk: int,
+                  causal: bool):
     # refs are [1, 1, block, D] tiles of the [B, H, L, D] operands: the TPU
     # lowering needs the (sublane, lane) = last-two dims to be the tiled
-    # (sequence, head_dim) pair, not (head, head_dim)
+    # (sequence, head_dim) pair, not (head, head_dim).  ``state`` is the
+    # (m, l, acc) scratch that carries the online softmax across the K/V
+    # grid dimension; a ``_one_block`` call has none
     i = pl.program_id(2)
     j = pl.program_id(3)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # causal: K block j is entirely in the future of Q block i when its
-    # first key position exceeds the block's last query position — skip it
-    # (j == 0 always computes: every query can attend key 0, so the running
-    # max is real from the first processed block on)
-    run = (j * bk <= i * bq + bq - 1) if causal else True
-
-    @pl.when(run)
-    def _block():
-        q = q_ref[0, 0, :, :]                            # [BQ, D] (bf16 ok)
-        k = k_ref[0, 0, :, :]                            # [BK, D]
-        v = v_ref[0, 0, :, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [BQ, BK] f32
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(j == nk - 1)
-    def _finish():
-        o_ref[0, 0, :, :] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    def _out(rows, m, l, acc):
+        o_ref[0, 0, rows, :] = (acc / l).astype(o_ref.dtype)
         if lse_ref is not None:
             # log-sum-exp residual for the backward kernels, lane-broadcast
             # to the TPU tile width (the jax in-tree kernel's layout)
-            lse = m_ref[...] + jnp.log(l_ref[...])          # [bq, 1]
-            lse_ref[0, 0, :, :] = jnp.broadcast_to(lse, (lse.shape[0], LANES))
+            lse = m + jnp.log(l)                            # [rows, 1]
+            lse_ref[0, 0, rows, :] = jnp.broadcast_to(
+                lse, (lse.shape[0], LANES))
+
+    if state:
+        m_ref, l_ref, acc_ref = state
+
+        @pl.when(j == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def _step(rows, pieces):
+        # one online-softmax update of the query rows over all their key
+        # pieces at once (bf16 operands into the MXU, f32 from there on).
+        # j == 0 always holds key 0, which every query attends, so the
+        # running max is real from the first processed block on
+        if not pieces:
+            return
+        q = q_ref[0, 0, rows, :]
+        kv = [(k_ref[0, 0, cols, :], v_ref[0, 0, cols, :])
+              for cols, _ in pieces]
+        s = [_scores(q, k, scale, off)
+             for (k, _), (_, off) in zip(kv, pieces)]
+        tops = [x.max(axis=-1, keepdims=True) for x in s]
+        if state:
+            m_prev = m_ref[rows, :]
+            tops.insert(0, m_prev)
+        m = functools.reduce(jnp.maximum, tops)
+        p = [jnp.exp(x - m) for x in s]
+        l = functools.reduce(
+            jnp.add, [x.sum(axis=-1, keepdims=True) for x in p])
+        acc = functools.reduce(
+            jnp.add, [_dot(x.astype(v.dtype), v, _NN)
+                      for x, (_, v) in zip(p, kv)])
+        if not state:
+            return _out(rows, m, l, acc)
+        corr = jnp.exp(m_prev - m)
+        l_ref[rows, :] = l_ref[rows, :] * corr + l
+        acc_ref[rows, :] = acc_ref[rows, :] * corr + acc
+        m_ref[rows, :] = m
+
+    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step)
+
+    if state:
+        @pl.when(j == nk - 1)
+        def _finish():
+            _out(ALL, m_ref[...], l_ref[...], acc_ref[...])
 
 
 def _block_size(l: int, cap: int) -> Optional[int]:
@@ -117,10 +259,8 @@ def _block_size(l: int, cap: int) -> Optional[int]:
     return None
 
 
-def _fwd_kernel_nolse(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                      **kw):
-    _flash_kernel(q_ref, k_ref, v_ref, o_ref, None, m_ref, l_ref, acc_ref,
-                  **kw)
+def _fwd_kernel_nolse(q_ref, k_ref, v_ref, o_ref, *state, **kw):
+    _flash_kernel(q_ref, k_ref, v_ref, o_ref, None, *state, **kw)
 
 
 def _flash_forward(q, k, v, causal=False, with_lse=False):
@@ -136,12 +276,14 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
     bq, bk = _block_size(lq, BQ), _block_size(lk, BK)
     scale = 1.0 / (d ** 0.5)
     grid = (b, h, lq // bq, lk // bk)
+    _log_tiles(lq, lk, bq, bk, causal)
     # [B, L, H, D] -> [B, H, L, D]: the kernel tiles over (seq, head_dim)
     qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     # under shard_map's varying-manual-axes typing the out aval must carry
     # the same mesh-varying set as the inputs
     vma = jax.typeof(qt).vma
-    kw = dict(scale=scale, nk=lk // bk, bq=bq, bk=bk, causal=causal)
+    kw = dict(scale=scale, ni=lq // bq, nk=lk // bk, bq=bq, bk=bk,
+              causal=causal)
     kernel = (functools.partial(_flash_kernel, **kw) if with_lse
               else functools.partial(_fwd_kernel_nolse, **kw))
     o_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0),
@@ -168,7 +310,7 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
                          memory_space=pltpu.VMEM),
         ],
         out_specs=out_specs,
-        scratch_shapes=[
+        scratch_shapes=[] if _one_block(causal, *grid[2:]) else [
             pltpu.VMEM((bq, 1), jnp.float32),    # running max m
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom l
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
@@ -184,54 +326,62 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
     return out[0].transpose(0, 2, 1, 3)
 
 
+def _p_ds(q, k, v, do, lse, delta, scale, off):
+    """Softmax probabilities of a piece, recomputed from the saved
+    log-sum-exp, and ds = p * (do v^T - delta) * scale (both f32)."""
+    p = jnp.exp(_scores(q, k, scale, off) - lse)
+    return p, p * (_dot(do, v, _NT) - delta) * scale
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
-                   acc_ref, *, scale: float, nk: int, bq: int, bk: int,
+                   *acc, scale: float, ni: int, nk: int, bq: int, bk: int,
                    causal: bool):
     """dQ pass: grid (b, h, iq, jk), K/V innermost; accumulates
-    dq_i = sum_j ds_ij k_j with ds = p * (do v^T - delta) * scale."""
+    dq_i = sum_j ds_ij k_j with ds = p * (do v^T - delta) * scale, in the
+    f32 scratch ``acc`` (a ``_one_block`` call has none)."""
     i = pl.program_id(2)
     j = pl.program_id(3)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    if acc:
+        acc_ref, = acc
 
-    run = (j * bk <= i * bq + bq - 1) if causal else True
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(run)
-    def _block():
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :1]                       # [bq, 1]
-        delta = dl_ref[0, 0, :, :1]                      # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        p = jnp.exp(s - lse)                             # softmax probs
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta) * scale
-        acc_ref[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, d]
+    def _step(rows, pieces):
+        if not pieces:
+            return
+        q = q_ref[0, 0, rows, :]
+        do = do_ref[0, 0, rows, :]
+        lse = lse_ref[0, 0, rows, :1]                    # [rows, 1]
+        delta = dl_ref[0, 0, rows, :1]
+        parts = []
+        for cols, off in pieces:
+            k = k_ref[0, 0, cols, :]
+            _, ds = _p_ds(q, k, v_ref[0, 0, cols, :], do, lse, delta,
+                          scale, off)
+            parts.append(_dot(ds.astype(k.dtype), k, _NN))   # [rows, d]
+        dq = functools.reduce(jnp.add, parts)
+        if acc:
+            acc_ref[rows, :] += dq
+        else:
+            dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
 
-    @pl.when(j == nk - 1)
-    def _finish():
-        dq_ref[0, 0, :, :] = acc_ref[...].astype(dq_ref.dtype)
+    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step)
+
+    if acc:
+        @pl.when(j == nk - 1)
+        def _finish():
+            dq_ref[0, 0, :, :] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    ni: int, rep: int, bq: int, bk: int, causal: bool):
+                    dk_ref, dv_ref, *acc, scale: float, ni: int, nk: int,
+                    rep: int, bq: int, bk: int, causal: bool):
     """dK/dV pass: grid (b, kv_head, jk, it), Q innermost; accumulates
-    dv_j = sum_i p^T do_i and dk_j = sum_i ds^T q_i.
+    dv_j = sum_i p^T do_i and dk_j = sum_i ds^T q_i in the f32 scratch
+    ``acc`` (a ``_one_block`` call has none).
 
     Grouped-query attention folds the ``rep`` query heads sharing each
     K/V head into the innermost grid dim: it = member * ni + iq (member
@@ -242,44 +392,47 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     it = pl.program_id(3)
     i = it % ni if rep > 1 else it
 
-    @pl.when(it == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+    if acc:
+        dk_acc, dv_acc = acc
 
-    run = (j * bk <= i * bq + bq - 1) if causal else True
+        @pl.when(it == 0)
+        def _init():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(run)
-    def _block():
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :1]
-        delta = dl_ref[0, 0, :, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, d]
+    def _step(cols, pieces):
+        if not pieces:
+            if not acc:     # keys past the last query: nothing reaches them
+                zero = jnp.zeros((cols.stop - cols.start, dk_ref.shape[-1]),
+                                 dk_ref.dtype)
+                dk_ref[0, 0, cols, :] = dv_ref[0, 0, cols, :] = zero
+            return
+        k = k_ref[0, 0, cols, :]
+        v = v_ref[0, 0, cols, :]
+        parts = []
+        for rows, off in pieces:
+            q = q_ref[0, 0, rows, :]
+            do = do_ref[0, 0, rows, :]
+            p, ds = _p_ds(q, k, v, do, lse_ref[0, 0, rows, :1],
+                          dl_ref[0, 0, rows, :1], scale, off)
+            parts.append((_dot(p.astype(do.dtype), do, _TN),     # [cols, d]
+                          _dot(ds.astype(q.dtype), q, _TN)))
+        dv, dk = (functools.reduce(jnp.add, x) for x in zip(*parts))
+        if acc:
+            dv_acc[cols, :] += dv
+            dk_acc[cols, :] += dk
+        else:
+            dk_ref[0, 0, cols, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
 
-    @pl.when(it == ni * rep - 1)
-    def _finish():
-        dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
+    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
+           by_key=True)
+
+    if acc:
+        @pl.when(it == ni * rep - 1)
+        def _finish():
+            dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, o, lse, g, causal):
@@ -290,7 +443,7 @@ def _flash_backward(q, k, v, o, lse, g, causal):
     lk, kv = k.shape[1], k.shape[2]
     rep = h // kv             # queries per K/V head (1 = MHA, >1 = GQA)
     bq, bk = _block_size(lq, BQ), _block_size(lk, BK)
-    ni = lq // bq
+    ni, nk = lq // bq, lk // bk
     scale = 1.0 / (d ** 0.5)
     qt, kt, vt, ot, gt = (a.transpose(0, 2, 1, 3) for a in (q, k, v, o, g))
     # delta_i = rowsum(do * o) — the softmax-jacobian correction term,
@@ -319,27 +472,29 @@ def _flash_backward(q, k, v, o, lse, g, causal):
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
     dqt = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, nk=lk // bk,
+        functools.partial(_bwd_dq_kernel, scale=scale, ni=ni, nk=nk,
                           bq=bq, bk=bk, causal=causal),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma),
-        grid=(b, h, ni, lk // bk),
+        grid=(b, h, ni, nk),
         in_specs=[row(d), col(d), col(d), row(d), row(LANES), row(LANES)],
         out_specs=row(d),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        scratch_shapes=[] if _one_block(causal, ni, nk) else [
+            pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=params, interpret=_interpret(), name="flash_dq",
     )(qt, kt, vt, gt, lse, delta)
 
     dkt, dvt = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, ni=ni, rep=rep,
-                          bq=bq, bk=bk, causal=causal),
+        functools.partial(_bwd_dkv_kernel, scale=scale, ni=ni, nk=nk,
+                          rep=rep, bq=bq, bk=bk, causal=causal),
         out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype, vma=vma),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype, vma=vma)],
-        grid=(b, kv, lk // bk, ni * rep),
+        grid=(b, kv, nk, ni * rep),
         in_specs=[rowT(d), colT(d), colT(d), rowT(d), rowT(LANES),
                   rowT(LANES)],
         out_specs=[colT(d), colT(d)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+        scratch_shapes=[] if _one_block(causal, ni, nk, rep) else [
+            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32)],
         compiler_params=params, interpret=_interpret(), name="flash_dkv",
     )(qt, kt, vt, gt, lse, delta)
     return (dqt.transpose(0, 2, 1, 3), dkt.transpose(0, 2, 1, 3),
@@ -367,6 +522,33 @@ def _log_fallback(reason: str, q) -> None:
         logging.getLogger(__name__).warning(
             "flash attention requested but falling back to dense for "
             "q shape %s: %s", q.shape, reason)
+
+
+TILE_COUNTS: dict = {}   # (lq, lk, causal) -> (visited, total, masked)
+
+
+def _log_tiles(lq: int, lk: int, bq: int, bk: int, causal: bool) -> None:
+    """Record ONCE per call shape, at trace time, how many of the score
+    square's sub-tiles the kernels visit and how many of those they mask —
+    the counter that says the causal skip engages (visited < total)."""
+    key = (lq, lk, causal)
+    if key in TILE_COUNTS:
+        return
+    tq, tk = _tiles(bq, bk)
+    plans = [tile_plan(bq, bk, tq, tk, i * bq - j * bk, causal)
+             for i in range(lq // bq) for j in range(lk // bk)]
+    visited, masked = map(sum, zip(*map(tile_counts, plans)))
+    TILE_COUNTS[key] = (visited, (lq // tq) * (lk // tk), masked)
+    import logging
+    logging.getLogger(__name__).info(tiles_line(key))
+
+
+def tiles_line(key: tuple) -> str:
+    """``flash tiles L=1024 causal: visited 10/16, masked 4``"""
+    lq, lk, causal = key
+    return ("flash tiles L=%s %s: visited %d/%d, masked %d" % (
+        lq if lq == lk else f"{lq}x{lk}", "causal" if causal else "full",
+        *TILE_COUNTS[key]))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

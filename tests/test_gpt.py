@@ -61,6 +61,114 @@ class TestCausalAttention:
 
 
 @pytest.mark.slow
+
+
+def _flash_vs_dense(q, k, v, causal, atol=1e-5, gtol=1e-4):
+    """Flash forward and (dq, dk, dv) against dense attention, at the
+    tolerances of ``test_flash_causal_forward_and_grad``."""
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops.attention import dot_product_attention
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops.pallas_ops import flash_attention
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal)
+    dense = lambda q, k, v: dot_product_attention(q, k, v, causal=causal)
+    both = lambda fn: jax.jit(lambda *a: (fn(*a), jax.grad(
+        lambda *b: (fn(*b) ** 2).sum(), argnums=(0, 1, 2))(*a)))
+    (of, gf), (od, gd) = both(flash)(q, k, v), both(dense)(q, k, v)
+    np.testing.assert_allclose(of, od, atol=atol)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(a, b, atol=gtol)
+
+
+@pytest.fixture
+def pallas_ops(monkeypatch):
+    """The kernels' module with an empty tile registry."""
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
+    monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+    return pallas_ops
+
+
+class TestFlashCausalTiles:
+    """The causal sub-tiling inside a flash block: the plan (pure Python),
+    and the kernels (interpret mode) where it visits, skips and masks."""
+
+    @pytest.mark.parametrize("bq,bk,tile,off,causal,visited,masked", [
+        (1024, 1024, 256, 0, True, 10, 4),     # the benchmark's block
+        (1024, 1024, 128, 0, True, 36, 8),
+        (1024, 1024, 512, 0, True, 3, 2),
+        (1024, 1024, 256, 0, False, 16, 0),    # not causal: all, unmasked
+        (512, 1024, 256, 0, True, 3, 2),       # lq < lk, top-left aligned
+        (1024, 512, 256, 0, True, 7, 2),
+        (256, 256, 128, 256, True, 4, 0),      # a block below the diagonal
+        (256, 256, 128, -256, True, 0, 0),     # a block above it
+        (256, 384, 128, 128, True, 5, 2),      # bq != bk, shifted diagonal
+    ])
+    def test_tile_plan_counts(self, pallas_ops, bq, bk, tile, off, causal,
+                              visited, masked):
+        plan = pallas_ops.tile_plan(bq, bk, tile, tile, off, causal)
+        assert len(plan) == bq // tile
+        assert pallas_ops.tile_counts(plan) == (visited, masked)
+
+    @pytest.mark.parametrize("by_key", [False, True])
+    @pytest.mark.parametrize("bq,bk,tq,tk,off", [
+        (1024, 1024, 256, 256, 0), (512, 512, 128, 128, 0),
+        (256, 384, 128, 128, 128), (256, 384, 128, 128, -128),
+        (512, 256, 256, 128, 0), (256, 256, 128, 128, 255)])
+    def test_block_groups_cover_the_triangle_once(self, pallas_ops, by_key,
+                                                  bq, bk, tq, tk, off):
+        """Every visible (query, key) pair lies in exactly one piece, no
+        unmasked piece holds a hidden pair, and a masked piece's own
+        offset reproduces the block's mask."""
+        visible = (np.arange(bk)[None] <= np.arange(bq)[:, None] + off)
+        seen = np.zeros((bq, bk), int)
+        for major, pieces in pallas_ops._block_groups(bq, bk, tq, tk, off,
+                                                      by_key):
+            for minor, mask_off in pieces:
+                rows, cols = (minor, major) if by_key else (major, minor)
+                if mask_off is None:
+                    assert visible[rows, cols].all()
+                    seen[rows, cols] += 1
+                else:
+                    n, m = seen[rows, cols].shape
+                    own = np.arange(m)[None] <= np.arange(n)[:, None] + mask_off
+                    np.testing.assert_array_equal(own, visible[rows, cols])
+                    seen[rows, cols] += own
+        np.testing.assert_array_equal(seen, visible.astype(int))
+
+    @pytest.mark.parametrize("l,counts", [(512, (3, 4, 2)),
+                                          (1024, (10, 16, 4))])
+    def test_causal_mha_matches_dense(self, pallas_ops, l, counts):
+        """d = 64, one block a head: more than one sub-tile visited, at
+        least one skipped and one masked."""
+        _flash_vs_dense(*_qkv(l=l, h=2, d=64, b=1, seed=l), causal=True)
+        assert pallas_ops.TILE_COUNTS == {(l, l, True): counts}
+
+    @pytest.mark.parametrize("lq,lk,counts", [
+        (512, 1024, (3, 8, 2)),      # keys past the last query: dk = dv = 0
+        (1024, 512, (7, 8, 2))])
+    def test_one_block_lq_differs_from_lk(self, pallas_ops, lq, lk, counts):
+        q, _, _ = _qkv(l=lq, h=2, d=64, b=1, seed=lq)
+        _, k, v = _qkv(l=lk, h=2, d=64, b=1, seed=lk + 1)
+        _flash_vs_dense(q, k, v, causal=True)
+        assert pallas_ops.TILE_COUNTS == {(lq, lk, True): counts}
+
+    def test_not_causal_visits_every_tile_unmasked(self, pallas_ops):
+        _flash_vs_dense(*_qkv(l=512, h=2, d=64, b=1, seed=5), causal=False)
+        assert pallas_ops.TILE_COUNTS == {(512, 512, False): (4, 4, 0)}
+
+    @pytest.mark.parametrize("lq,lk,counts", [
+        (512, 512, (10, 16, 4)),     # lq = 2 * BQ: grid skip + tile skip
+        (256, 512, (3, 8, 2)),       # lq != lk: top-left aligned mask
+        (768, 768, (21, 36, 6))])    # three blocks a side
+    def test_grid_skip_and_tile_skip_together(self, pallas_ops, monkeypatch,
+                                              lq, lk, counts):
+        monkeypatch.setattr(pallas_ops, "BQ", 256)
+        monkeypatch.setattr(pallas_ops, "BK", 256)
+        monkeypatch.setattr(pallas_ops, "TILE", 128)
+        q, _, _ = _qkv(l=lq, h=2, d=64, b=1, seed=lq)
+        _, k, v = _qkv(l=lk, h=2, d=64, b=1, seed=lk + 1)
+        _flash_vs_dense(q, k, v, causal=True)
+        assert pallas_ops.TILE_COUNTS == {(lq, lk, True): counts}
+
+
 class TestGPT:
     def test_gpt2_small_param_count_canonical(self):
         """Tied-head GPT-2 small == 124,439,808 params (the published

@@ -97,6 +97,44 @@ class TestFlashGrouped:
             np.testing.assert_allclose(a, b, atol=5e-4)
 
 
+class TestFlashGroupedCausalTiles:
+    """GQA (rep = 2) through the causal sub-tiling of a flash block: the
+    dK/dV kernel folds the query group into its innermost grid dimension,
+    so its tile geometry has to use ``it % ni``.  d = 64, tolerances of
+    ``TestFlashGrouped``."""
+
+    @staticmethod
+    def _check(l, kv_l=None):
+        rng = np.random.default_rng(l)
+        q = jnp.asarray(rng.normal(size=(1, l, 2, 64)), jnp.float32)
+        k, v = (jnp.asarray(rng.normal(size=(1, kv_l or l, 1, 64)),
+                            jnp.float32) for _ in range(2))
+        both = lambda impl: jax.jit(lambda *a: (
+            attend(*a, impl=impl, causal=True),
+            jax.grad(lambda *b: (attend(*b, impl=impl, causal=True)
+                                 ** 2).sum(), argnums=(0, 1, 2))(*a)))
+        (of, gf), (od, gd) = both("flash")(q, k, v), both("dense")(q, k, v)
+        np.testing.assert_allclose(of, od, atol=2e-5)
+        for a, b in zip(gf, gd):
+            np.testing.assert_allclose(a, b, atol=5e-4)
+
+    @pytest.mark.parametrize("l,counts", [(512, (3, 4, 2)),
+                                          (1024, (10, 16, 4))])
+    def test_one_block_a_head(self, monkeypatch, l, counts):
+        from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
+        monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+        self._check(l)
+        assert pallas_ops.TILE_COUNTS == {(l, l, True): counts}
+
+    def test_grid_skip_and_tile_skip_together(self, monkeypatch):
+        from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
+        for name, value in (("BQ", 256), ("BK", 256), ("TILE", 128),
+                            ("TILE_COUNTS", {})):
+            monkeypatch.setattr(pallas_ops, name, value)
+        self._check(512)
+        assert pallas_ops.TILE_COUNTS == {(512, 512, True): (10, 16, 4)}
+
+
 @pytest.fixture(scope="module")
 def seq_mesh(devices):
     return Mesh(np.array(devices[:2]), ("seq",))

@@ -1,0 +1,45 @@
+"""The flash kernels compiled for the v5e by the TPU compiler installed in
+the sandbox: no chip is attached and nothing runs, so this proves that
+Mosaic lowers the kernels at the real widths (what interpret mode cannot
+show: tile alignment of the sub-tile slices, VMEM use), never a result
+and never a time.  The topology is described inside a fixture, and only
+in this file: one process at a time may load the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("b,lq,lk,h,kv,causal", [
+    (4, 1024, 1024, 12, 12, True),     # gpt2s_train_1k: one block a head
+    (1, 4096, 4096, 12, 12, True),     # grid skip + tile skip
+    (1, 2048, 2048, 16, 4, True),      # GQA: the group folded into dkv's grid
+    (2, 512, 1024, 4, 4, True),        # one block, keys past the last query
+    (16, 512, 512, 12, 12, False),     # bert / vit: the untiled body
+])
+def test_flash_kernels_lower_for_v5e(one_chip, monkeypatch, b, lq, lk, h, kv,
+                                     causal):
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    loss = lambda q, k, v: pallas_ops._flash(q, k, v, causal).astype(
+        jnp.float32).sum()
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        sds(b, lq, h, 64), sds(b, lk, kv, 64), sds(b, lk, kv, 64)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    (visited, total, masked), = pallas_ops.TILE_COUNTS.values()
+    assert (visited < total, masked > 0) == (causal, causal)
