@@ -120,19 +120,38 @@ def check_flash_kernels() -> None:
     )
     from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops.attention import (
         attend,
+        causal_mask,
         dot_product_attention,
     )
 
     def loss(fn):
         return lambda q, k, v, w: (fn(q, k, v).astype(jnp.float32) * w).sum()
 
-    flash = lambda q, k, v: attend(q, k, v, impl="flash", causal=True)
-    dense = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
-    for name, (b, l, h, kv, d) in {
+    def dense_in_blocks(window, bq=1024):
+        """Dense attention under the explicit mask, a block of queries at a
+        time and recomputed on the way back: at L = 8192 one layer's whole
+        score matrix is 8.6 GB."""
+        def fn(q, k, v):
+            b, l, h, d = q.shape
+            block = jax.checkpoint(lambda a: dot_product_attention(
+                a[0], k, v, mask=causal_mask(bq, l, q_offset=a[1],
+                                             window=window)))
+            qs = q.reshape(b, l // bq, bq, h, d).swapaxes(0, 1)
+            out = jax.lax.map(block, (qs, jnp.arange(l // bq) * bq))
+            return out.swapaxes(0, 1).reshape(b, l, h, d)
+        return fn
+
+    for name, (b, l, h, kv, d, window) in {
             "mha L=1024 (gpt2s_train_1k, the benchmark's)":
-                (4, 1024, 12, 12, 64),
-            "mha L=4096 (gpt2_4k_flash)": (1, 4096, 12, 12, 64),
-            "gqa L=2048 16/4 (llama_gqa4)": (1, 2048, 16, 4, 64)}.items():
+                (4, 1024, 12, 12, 64, None),
+            "mha L=4096 (gpt2_4k_flash)": (1, 4096, 12, 12, 64, None),
+            "gqa L=2048 16/4 (llama_gqa4)": (1, 2048, 16, 4, 64, None),
+            "gqa L=8192 32/4 d=128 window 1024 (mellum2_train_8k's)":
+                (1, 8192, 32, 4, 128, 1024)}.items():
+        flash = lambda q, k, v: attend(q, k, v, impl="flash", causal=True,
+                                       window=window)
+        dense = (dense_in_blocks(window) if window else
+                 lambda q, k, v: dot_product_attention(q, k, v, causal=True))
         keys = jax.random.split(jax.random.key(l), 4)
         q = jax.random.normal(keys[0], (b, l, h, d), jnp.bfloat16)
         k = jax.random.normal(keys[1], (b, l, kv, d), jnp.bfloat16)
@@ -170,7 +189,7 @@ def say_flash_tiles() -> None:
         pallas_ops,
     )
     for key, (visited, total, masked) in sorted(
-            pallas_ops.TILE_COUNTS.items()):
+            pallas_ops.TILE_COUNTS.items(), key=str):
         causal = key[2]
         say(pallas_ops.tiles_line(key))
         if ((visited < total) != (causal and total > 1)

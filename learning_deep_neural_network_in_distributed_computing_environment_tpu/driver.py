@@ -166,6 +166,11 @@ def checkpoint_metadata(cfg: Config, num_classes: int,
             # a global one is required — the manifest must say which
             # world wrote it
             "num_slices": int(cfg.num_slices)}
+    from .models import ARCHS
+    if cfg.model in ARCHS:
+        # the architecture as data (models/arch.py): what rebuilds the
+        # model without this tree's registry (DecoderArch.from_manifest)
+        meta["arch"] = ARCHS[cfg.model].as_manifest()
     if params_template is not None:
         # per-worker params leaf shapes (ISSUE 12 satellite): a
         # scatter-resident checkpoint's 1/N bucket rows carry no leaf
@@ -745,6 +750,12 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         train_kw.update(attention_impl=cfg.attention_impl)
     if train_kw:
         train_model = build_model_for(cfg, num_classes, **base_kw, **train_kw)
+    # the model a call outside the round program applies (the probe, the
+    # caller's final evaluation): the one that trains, with its attention
+    # implementation, window and remat policy, unless that one is bound to
+    # mesh axes and runs only inside shard_map; then the dense twin
+    host_model = (train_model if train_model is not None and set(train_kw)
+                  <= {"attention_impl", "remat_policy"} else model)
     if cfg.sync_staleness > 0 and jax.default_backend() == "cpu":
         # semi-synchronous rounds keep a standalone sync program running
         # CONCURRENTLY with the next round program — on an unpinned
@@ -869,7 +880,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     if elastic_snapshot is None:
         init_vars = engine.rank0_variables(state)
         durations, sec_per_batch = probe_lib.estimate_epoch_duration(
-            model, init_vars, sample, n, cfg.probe_batches,
+            host_model, init_vars, sample, n, cfg.probe_batches,
             simulated_durations)
         ratios = efficiency_ratios(durations, cfg.proportionality)
         log.info("probe durations %s -> ratios %s", durations, ratios)
@@ -1208,6 +1219,10 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         with span("round.assemble", timing, "assemble_ms",
                   round=global_epoch):
             _assemble_round_metrics(results, mx, wids)
+        for name, per_step in (mx.get("counters") or {}).items():
+            # what the model sowed (train.py, _loss_and_metrics), [N, E, S]:
+            # the round's mean over workers, local epochs and steps
+            timing[name] = float(np.mean(per_step))
         report_progress(mx, global_epoch, time.perf_counter() - t_dispatch,
                         wids)
         return mx
@@ -2029,7 +2044,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     # re-deriving it from the state
     results["variables"] = engine.rank0_variables(state)
     results["mesh"] = mesh
-    results["model"] = model
+    results["model"] = host_model
     results["engine"] = engine
     results["test"] = test if datasets is None else datasets[2]
     return results

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 from typing import Any
 
+from .arch import ARCHS
+
 
 def get_model(name: str, **kw: Any):
     """Build a flax module by registry name (lazy imports keep startup cheap)."""
@@ -16,6 +18,9 @@ def get_model(name: str, **kw: Any):
     if name not in MODEL_INPUT_SPECS:
         raise ValueError(
             f"unknown model {name!r}; available: {sorted(MODEL_INPUT_SPECS)}")
+    if name in ARCHS:
+        from .decoder import DecoderLM
+        return DecoderLM(arch=ARCHS[name], **kw)
     if name == "enhanced_cnn":
         from .cnn import EnhancedCNNModel
         return EnhancedCNNModel(**kw)
@@ -99,7 +104,8 @@ def is_attention_model(name: str) -> bool:
     """True for transformer families (bert_*/gpt_*/vit_*/llama_*) — the
     models that accept attention/parallelism kwargs (TP, PP, MoE,
     attention_impl)."""
-    return name.lower().startswith(("bert", "gpt", "vit", "llama"))
+    name = name.lower()
+    return name in ARCHS or name.startswith(("bert", "gpt", "vit", "llama"))
 
 
 def supports_layer_scan(name: str) -> bool:
@@ -115,7 +121,8 @@ def is_token_model(name: str) -> bool:
     """True for models whose input is a token-id sequence [B, L] — the
     shape sequence parallelism shards.  ViT is attention-based but takes
     images, so SP does not apply."""
-    return name.lower().startswith(("bert", "gpt", "llama"))
+    name = name.lower()
+    return name in ARCHS or name.startswith(("bert", "gpt", "llama"))
 
 
 # The named-activation vocabulary of the shared scanned-block path
@@ -132,8 +139,9 @@ def is_token_model(name: str) -> bool:
 # - ``block_out`` — the block's residual-stream output (the layer
 #   boundary — saving only these IS the GPipe-paper recipe, spelled as
 #   a named set);
-# - ``moe_dispatch`` — the MoE dispatch einsum's expert-batched tokens
-#   ([E, C, H]; emitted only when the family runs with num_experts > 0).
+# - ``moe_dispatch`` — the tokens as the experts get them: the Switch
+#   layer's expert-batched [E, C, H], the routed layer's rows in expert
+#   order [M, H] (emitted only when the model has experts).
 REMAT_NAMES = ("attn_out", "mlp_out", "block_out", "moe_dispatch")
 
 
@@ -145,7 +153,10 @@ def remat_name_vocab(name: str, num_experts: int = 0) -> tuple[str, ...]:
     if not is_attention_model(name):
         return ()
     base = ("attn_out", "mlp_out", "block_out")
-    return base + ("moe_dispatch",) if num_experts > 0 else base
+    arch = ARCHS.get(name.lower())
+    if num_experts > 0 or (arch is not None and arch.experts):
+        return base + ("moe_dispatch",)
+    return base
 
 
 # Named rematerialization policies for the layer-scan engine (ISSUE 3).
@@ -270,4 +281,5 @@ MODEL_INPUT_SPECS = {
     "vit_s16": ((224, 224, 3), 1000),
     "vit_b16": ((224, 224, 3), 1000),
     "vit_tiny": ((32, 32, 3), 10),
+    **{name: ((128,), arch.vocab) for name, arch in ARCHS.items()},
 }

@@ -47,12 +47,15 @@ class SelfAttention(nn.Module):
     #                                     attention (separate q / kv
     #                                     projections, kv heads shared by
     #                                     num_heads // num_kv_heads queries)
+    head_dim: Optional[int] = None  # given (None: hidden // num_heads)
+    window: Optional[int] = None    # causal sliding window, itself counted
+    rope_yarn: Optional[tuple] = None  # ops.attention.rope_frequencies
 
     @nn.compact
     def __call__(self, x, mask=None):
         from ..ops.attention import attend
         d = x.shape[-1]
-        head_dim = d // self.num_heads
+        head_dim = self.head_dim or d // self.num_heads
         if self.num_heads % self.tp_size:
             raise ValueError(
                 f"num_heads {self.num_heads} not divisible by tp_size "
@@ -93,15 +96,16 @@ class SelfAttention(nn.Module):
                 # absolute positions are offset by index * chunk length —
                 # rotated keys travel the ring already position-encoded
                 pos = pos + lax.axis_index(self.axis_name) * x.shape[1]
-            q = rope(q, pos, self.rope_theta)
-            k = rope(k, pos, self.rope_theta)
+            q = rope(q, pos, self.rope_theta, self.rope_yarn)
+            k = rope(k, pos, self.rope_theta, self.rope_yarn)
         # GQA K/V are passed GROUPED ([B, L, kv_local, D]) straight into
         # attend: every impl — dense (grouped einsum), flash kernel
         # (grouped block specs), ring (rep-x smaller rotating blocks),
         # Ulysses — consumes them without a repeat-to-full-heads expansion,
         # so the K/V bandwidth saving GQA exists for actually materializes
         out = attend(q, k, v, mask=mask, impl=self.attention_impl,
-                     axis_name=self.axis_name, causal=self.causal)
+                     axis_name=self.axis_name, causal=self.causal,
+                     window=self.window)
         y = nn.DenseGeneral(d, axis=(-2, -1), kernel_init=_init,
                             use_bias=False, dtype=self.dtype,
                             name="out")(out)
@@ -249,7 +253,7 @@ def apply_scanned_stack(scan_layer_cls, x, *, num_layers: int, pp_size: int,
         cls = nn.remat(scan_layer_cls, prevent_cse=False,
                        policy=checkpoint_policy(remat_policy))
     scanned = nn.scan(
-        cls, variable_axes={"params": 0, "aux": 0},
+        cls, variable_axes={"params": 0, "aux": 0, "counters": 0},
         split_rngs={"params": True}, in_axes=nn.broadcast,
         length=n_local)(
             train=train, name="layers", **layer_kw)

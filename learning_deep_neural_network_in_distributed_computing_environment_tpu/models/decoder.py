@@ -1,0 +1,110 @@
+"""The decoder-only LM that a ``models.arch.DecoderArch`` describes: one
+block definition, ``x + Attn_t(RMSNorm(x))`` then ``x + MoE(RMSNorm(x))``,
+whose attention takes its kind ``t`` (window or full, and that kind's
+rotary parameters) from the layer's place in the period.
+
+The stack is scanned a PERIOD at a time (``bert.apply_scanned_stack`` over
+``_ScanPeriod``): the layers of a period share one parameter shape and
+differ only in static arguments of attention, so a period is that many
+calls of the one block, named ``layer_<i>``, and the stacked ``layers``
+collection carries the periods on its leading axis.  Rematerialisation is
+per layer, inside the period: a period is the whole of a short stack.
+
+Untied head, no biases, no position table (rotary), no auxiliary loss.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from .arch import DecoderArch
+from .bert import SelfAttention, apply_scanned_stack, resolve_remat_policy
+from .moe import RoutedExperts
+
+_init = nn.initializers.normal(stddev=0.02)
+
+
+class DecoderBlock(nn.Module):
+    arch: DecoderArch
+    layer_type: str                # "sliding" | "full"
+    dtype: Any = jnp.float32
+    attention_impl: str = "dense"
+
+    @nn.compact
+    def __call__(self, x):
+        a = self.arch
+        norm = lambda name: nn.RMSNorm(epsilon=a.norm_eps, dtype=self.dtype,
+                                       name=name)
+        rope = a.rope_of(self.layer_type)
+        attn = SelfAttention(
+            a.heads, dtype=self.dtype, attention_impl=self.attention_impl,
+            causal=True, use_bias=False, num_kv_heads=a.kv_heads,
+            head_dim=a.head_dim, window=a.window_of(self.layer_type),
+            rope_theta=rope.theta, rope_yarn=rope.yarn, name="attn")
+        x = x + checkpoint_name(attn(norm("rms1")(x)), "attn_out")
+        f = RoutedExperts(a.experts, a.expert_ffn, a.experts_per_token,
+                          experts_held=a.experts_held, dtype=self.dtype,
+                          name="moe")(norm("rms2")(x))
+        return checkpoint_name(x + checkpoint_name(f, "mlp_out"),
+                               "block_out")
+
+
+class _ScanPeriod(nn.Module):
+    """carry-API adapter: one period of the stack a scan step."""
+
+    arch: DecoderArch
+    dtype: Any = jnp.float32
+    attention_impl: str = "dense"
+    layer_remat: Optional[str] = None   # a named policy, per layer
+    train: bool = False
+
+    @nn.compact
+    def __call__(self, x, _):
+        block = DecoderBlock
+        if self.layer_remat:
+            from . import checkpoint_policy
+            block = nn.remat(DecoderBlock, prevent_cse=False,
+                             policy=checkpoint_policy(self.layer_remat))
+        for i, kind in enumerate(self.arch.layer_types):
+            x = block(self.arch, kind, dtype=self.dtype,
+                      attention_impl=self.attention_impl,
+                      name=f"layer_{i}")(x)
+        return x, None
+
+
+class DecoderLM(nn.Module):
+    """Token ids [B, L] -> next-token logits [B, L, rows of the head held]."""
+
+    arch: DecoderArch
+    num_classes: int = 0           # the engine's name for the vocabulary
+    dtype: Any = jnp.float32
+    attention_impl: str = "dense"
+    scan_layers: bool = True
+    remat_policy: Optional[str] = None  # a named policy, applied per layer
+
+    @nn.compact
+    def __call__(self, input_ids, *, train: bool = False):
+        a = self.arch
+        if not self.scan_layers:
+            raise ValueError(
+                "a DecoderArch model has one parameter layout, the stacked "
+                "periods: run it with --layer_scan auto|on")
+        if self.num_classes != a.vocab:
+            raise ValueError(
+                f"the data has {self.num_classes} token ids and this model "
+                f"holds {a.vocab} rows of its vocabulary")
+        x = nn.Embed(a.vocab, a.hidden,
+                     embedding_init=nn.initializers.normal(a.embed_std),
+                     dtype=self.dtype, name="tok_emb")(input_ids)
+        x = apply_scanned_stack(
+            _ScanPeriod, x, num_layers=a.periods, pp_size=1,
+            pipeline_axis=None, num_microbatches=0, train=train,
+            arch=a, dtype=self.dtype, attention_impl=self.attention_impl,
+            layer_remat=resolve_remat_policy(False, self.remat_policy))
+        x = nn.RMSNorm(epsilon=a.norm_eps, dtype=self.dtype, name="rms_f")(x)
+        return nn.Dense(a.vocab, use_bias=False, kernel_init=_init,
+                        dtype=self.dtype, name="lm_head")(x)

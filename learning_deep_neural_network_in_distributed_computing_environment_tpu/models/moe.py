@@ -2,9 +2,15 @@
 mesh axis.
 
 Beyond-reference capability (the reference is data-parallel only,
-SURVEY.md 2.3).  Switch-Transformer-style top-1 token routing with a
-capacity limit, formulated TPU-first as dispatch/combine einsums (dense
-one-hot dispatch tensors -> MXU work, no gather/scatter):
+SURVEY.md 2.3).  Two routed layers over ONE dispatch (``routed_apply``:
+the (token, expert) pairs sorted by expert, grouped products over the
+groups, a gather back; no ``[tokens, E, C]`` tensor):
+
+- ``RoutedExperts``: sparse SwiGLU experts as deployed, top-k of E with
+  renormalised weights, no capacity and no dropped token, told which
+  experts it holds (one expert-parallel rank's share of a layer);
+- ``MoEFFN``: Switch-Transformer-style top-1 routing with a capacity
+  limit, the capacity being a weight of 0 on the tokens past it:
 
 - the gate (replicated) scores every token against all ``num_experts``
   experts; each token goes to its top-1 expert, capped at
@@ -26,6 +32,7 @@ both worlds, as with tensor parallelism.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional
 
@@ -91,48 +98,164 @@ class MoEFFN(nn.Module):
         self.sow("aux", "load_balance",
                  jnp.asarray(aux_scale, jnp.float32)
                  * e * jnp.sum(onehot.mean(0) * probs.mean(0)))
-        # position of each token within its expert's queue; drop overflow
+        # position of each token within its expert's queue; a token past
+        # the capacity keeps its place in the dispatch and loses its weight:
+        # Switch's drop, as a case of the routed layer below
         pos = jnp.einsum("ne,ne->n", jnp.cumsum(onehot, axis=0) - 1.0,
                          onehot).astype(jnp.int32)
         keep = (pos < cap).astype(jnp.float32)
-        dispatch = (onehot * keep[:, None])[..., None] * jax.nn.one_hot(
-            jnp.clip(pos, 0, cap - 1), cap,
-            dtype=jnp.float32)[:, None, :]                      # [N, E, C]
 
         # --- local expert slice ------------------------------------------
-        if self.expert_axis is not None:
-            off = lax.axis_index(self.expert_axis) * e_local
-            dispatch_local = lax.dynamic_slice_in_dim(dispatch, off, e_local,
-                                                      axis=1)
-        else:
-            dispatch_local = dispatch
-
+        first = (lax.axis_index(self.expert_axis) * e_local
+                 if self.expert_axis is not None else 0)
         w1 = self.param("w1", _init, (e_local, h, f_local))
         b1 = self.param("b1", nn.initializers.zeros, (e_local, f_local))
         w2 = self.param("w2", _init, (e_local, f_local, h))
         b2 = self.param("b2", nn.initializers.zeros, (e_local, h))
-
-        dl = dispatch_local.astype(self.dtype)
-        # named activation "moe_dispatch" (ISSUE 15): the expert-batched
-        # dispatched tokens [E, C, H] — the MoE-specific residual a
-        # save_names:/offload_names: policy may pin (recomputing it
-        # re-pays the dense one-hot dispatch einsum)
-        xe = checkpoint_name(
-            jnp.einsum("nec,nh->ech", dl, toks.astype(self.dtype)),
-            "moe_dispatch")
-        h1 = nn.gelu(jnp.einsum("ech,ehf->ecf", xe, w1.astype(self.dtype))
-                     + b1[:, None, :].astype(self.dtype), approximate=False)
         # row-parallel w2: per-shard partial sums over the local F slice;
         # b2 is scaled so the cross-shard psum below adds it exactly once
         b2_scale = 1.0 / self.tp_size if self.model_axis is not None else 1.0
-        ye = jnp.einsum("ecf,efh->ech", h1, w2.astype(self.dtype)) \
-            + b2_scale * b2[:, None, :].astype(self.dtype)
-        combine = dl * gate[:, None, None].astype(self.dtype)
-        out = jnp.einsum("nec,ech->nh", combine, ye)
+
+        def experts(rows, sizes, expert_of_row):
+            from ..ops.grouped_matmul import grouped_matmul
+            e_row = jnp.minimum(expert_of_row, e_local - 1)
+            h1 = nn.gelu(
+                grouped_matmul(rows, w1.astype(self.dtype), sizes)
+                + b1.astype(self.dtype)[e_row], approximate=False)
+            return (grouped_matmul(h1, w2.astype(self.dtype), sizes)
+                    + b2_scale * b2.astype(self.dtype)[e_row])
+
+        out, _ = routed_apply(toks.astype(self.dtype), expert_idx[:, None],
+                              (gate * keep)[:, None], first, e_local, experts)
         reduce_axes = tuple(a for a in (self.expert_axis, self.model_axis)
                             if a is not None)
         if reduce_axes:
             out = lax.psum(out, reduce_axes)
+        return out.reshape(b, t, h)
+
+
+# ----------------------------------------------------------------------
+# Routed experts without a capacity: sort, grouped products, gather back
+# ----------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _spread(toks, order, inv, owned, k):
+    """[N, H] tokens -> [M, H] rows in expert order: sorted row r is the
+    token of flat (token, choice) pair ``order[r]``.  The transpose is a
+    gather too (``inv`` is the sort's inverse), with the rows of pairs
+    that no held expert owns selected away: they were never written."""
+    return toks[jnp.minimum(order // k, toks.shape[0] - 1)]
+
+
+def _spread_fwd(toks, order, inv, owned, k):
+    return _spread(toks, order, inv, owned, k), (inv, owned, toks.shape[0])
+
+
+def _spread_bwd(k, res, g):
+    inv, owned, n = res
+    back = jnp.where(owned[:, None], g[inv], 0)[:n * k]
+    return back.reshape(n, k, -1).sum(1), None, None, None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@jax.custom_vjp
+def _collect(rows, inv, order):
+    """[M, H] rows in expert order -> [M, H] in flat pair order; the
+    transpose is the gather by ``order``."""
+    return rows[inv]
+
+
+_collect.defvjp(lambda rows, inv, order: (rows[inv], order),
+                lambda order, g: (g[order], None, None))
+
+
+def routed_apply(toks, expert_idx, weights, first, held: int, expert_fn):
+    """Send every (token, chosen expert) pair whose expert is one of the
+    ``held`` experts from ``first`` on through ``expert_fn`` and add the
+    results back into the tokens, weighted.  No capacity and no dropped
+    pair: the rows are sorted by expert, ``expert_fn(rows [M, H], sizes
+    [held], expert_of_row [M])`` runs grouped products over them
+    (``ops.grouped_matmul``) and the rows come back by a gather.  M is the
+    worst case, every pair on a held expert, rounded up to the kernels' row
+    tile; pairs of experts held elsewhere sort behind the last group, where
+    the kernels write nothing, and are selected away before use.
+
+    ``toks`` [N, H], ``expert_idx`` / ``weights`` [N, k].  Returns ``(out
+    [N, H], sizes [held])``: the held experts' part of the layer's sum and
+    the rows each of them got."""
+    from ..ops.grouped_matmul import TM, vary_alike
+    n, k = expert_idx.shape
+    m = n * k
+    m_pad = -(-m // TM) * TM if m >= TM else -(-m // 8) * 8
+    local = expert_idx.reshape(-1).astype(jnp.int32) - first
+    owned = (local >= 0) & (local < held)
+    key = jnp.pad(jnp.where(owned, local, held), (0, m_pad - m),
+                  constant_values=held)
+    owned = jnp.pad(owned, (0, m_pad - m))
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros(m_pad, jnp.int32).at[order].set(
+        jnp.arange(m_pad, dtype=jnp.int32), unique_indices=True)
+    sizes = (key[:, None] == jnp.arange(held)[None]).sum(0, dtype=jnp.int32)
+    # named activation "moe_dispatch" (ISSUE 15): the tokens in expert
+    # order, the residual a save_names: / offload_names: policy may pin
+    toks, order, inv, owned = vary_alike(toks, order, inv, owned)
+    rows = checkpoint_name(_spread(toks, order, inv, owned, k),
+                           "moe_dispatch")
+    y = _collect(*vary_alike(expert_fn(rows, sizes, key[order]), inv, order))
+    y = jnp.where(owned[:, None], y, 0)[:m].reshape(n, k, -1)
+    return (y * weights[..., None].astype(y.dtype)).sum(1), sizes
+
+
+class RoutedExperts(nn.Module):
+    """Sparse SwiGLU experts as deployed: ``top_k`` of ``num_experts`` a
+    token, weights renormalised over the chosen, no capacity, no dropped
+    token, no auxiliary loss.
+
+    ``experts_held = (first, count)`` makes this one expert-parallel
+    rank's layer: the router scores all ``num_experts``, the top-k and the
+    renormalisation are over all of them, and the layer holds, applies and
+    returns the part of ``count`` experts from ``first`` on.  The ranks'
+    outputs sum to the whole layer's; nothing stands in for the experts
+    held elsewhere.  Sown into ``counters``: the rows that landed on held
+    experts and the fullest held expert's rows over the mean."""
+
+    num_experts: int               # the router's width
+    ffn_dim: int                   # per-expert SwiGLU width
+    top_k: int
+    experts_held: Optional[tuple] = None   # (first, count); None: all
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.grouped_matmul import grouped_matmul
+        b, t, h = x.shape
+        first, held = self.experts_held or (0, self.num_experts)
+        toks = x.reshape(b * t, h)
+        with jax.named_scope("moe_route"):
+            logits = nn.Dense(self.num_experts, use_bias=False,
+                              dtype=jnp.float32, kernel_init=_init,
+                              name="gate")(toks.astype(jnp.float32))
+            weights, idx = lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     self.top_k)
+            weights = weights / weights.sum(-1, keepdims=True)
+        w1, w3 = (self.param(name, _init, (held, h, self.ffn_dim)).astype(
+            self.dtype) for name in ("w1", "w3"))
+        w2 = self.param("w2", _init, (held, self.ffn_dim, h)).astype(
+            self.dtype)
+
+        def experts(rows, sizes, _):
+            gate = grouped_matmul(rows, w1, sizes)
+            up = grouped_matmul(rows, w3, sizes)
+            return grouped_matmul(nn.silu(gate) * up, w2, sizes)
+
+        out, sizes = routed_apply(toks.astype(self.dtype), idx, weights,
+                                  first, held, experts)
+        rows = sizes.sum().astype(jnp.float32)
+        self.sow("counters", "expert_rows", rows)
+        self.sow("counters", "expert_load_max_over_mean",
+                 sizes.max() * held / jnp.maximum(rows, 1.0))
         return out.reshape(b, t, h)
 
 

@@ -29,31 +29,67 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float = 10000.0):
+def rope_frequencies(head_dim: int, theta: float,
+                     yarn: Optional[tuple] = None):
+    """``(inv_freq [head_dim / 2] f32, scale)`` of a rotary embedding.
+
+    Plain: ``inv_freq_n = theta ** (-2n / head_dim)``, scale 1.  ``yarn``
+    = ``(factor, original_max_position, beta_fast, beta_slow,
+    attention_factor)`` blends each frequency between itself and itself
+    over ``factor`` (YaRN's "NTK by parts"): dimensions that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    those that turn fewer than ``beta_slow`` times are interpolated, with a
+    linear ramp between; cos and sin are then multiplied by
+    ``attention_factor``."""
+    import math
+    import numpy as np
+    half = head_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        return inv, 1.0
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+    turns_at = lambda b: (head_dim * math.log(original / (2 * math.pi * b))
+                          / (2 * math.log(theta)))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), head_dim - 1)
+    ramp = jnp.asarray(np.clip((np.arange(half) - low)
+                               / max(high - low, 1e-3), 0, 1), jnp.float32)
+    return inv / factor * ramp + inv * (1 - ramp), float(attention_factor)
+
+
+def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float = 10000.0,
+         yarn: Optional[tuple] = None):
     """Rotary position embedding, rotate-half convention.
 
     ``x`` [B, L, H, Dh], ``pos`` [L] absolute token positions.  Angles are
     computed in f32 (bf16 positions lose integer precision past 256) and
     the result is cast back to ``x.dtype``.  Used by the Llama recipe
-    (``models/llama.py``) via ``models.bert.SelfAttention(rope_theta=...)``.
+    (``models/llama.py``) via ``models.bert.SelfAttention(rope_theta=...)``;
+    ``yarn`` as in ``rope_frequencies``.
     """
     half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)  # [Dh/2]
+    freqs, scale = rope_frequencies(x.shape[-1], theta, yarn)       # [Dh/2]
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]         # [L, Dh/2]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
-def causal_mask(lq: int, lk: int, q_offset: int = 0, k_offset: int = 0):
+def causal_mask(lq: int, lk: int, q_offset: int = 0, k_offset: int = 0,
+                window: Optional[int] = None):
     """[lq, lk] bool mask: query at global position q_offset+i may attend
-    key positions <= it."""
+    key positions <= it, and with ``window`` only the last ``window`` of
+    them, its own included."""
     qpos = q_offset + jnp.arange(lq)[:, None]
     kpos = k_offset + jnp.arange(lk)[None, :]
-    return kpos <= qpos
+    if window is None:
+        return kpos <= qpos
+    return (kpos <= qpos) & (kpos > qpos - window)
 
 
 def kv_group_size(q: jnp.ndarray, k: jnp.ndarray) -> int:
@@ -70,7 +106,8 @@ def kv_group_size(q: jnp.ndarray, k: jnp.ndarray) -> int:
 
 def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           mask: Optional[jnp.ndarray] = None,
-                          causal: bool = False) -> jnp.ndarray:
+                          causal: bool = False,
+                          window: Optional[int] = None) -> jnp.ndarray:
     """[B, Lq, H, D] x [B, Lk, KV, D] -> [B, Lq, H, D]; softmax in fp32.
 
     KV == H is plain multi-head attention; KV < H (divisible) is
@@ -91,7 +128,7 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                        preferred_element_type=jnp.float32) / scale
         s = s.reshape(b, h, lq, k.shape[1])
     if causal:
-        cm = causal_mask(q.shape[1], k.shape[1])
+        cm = causal_mask(q.shape[1], k.shape[1], window=window)
         mask = cm if mask is None else jnp.logical_and(mask, cm)
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
@@ -107,13 +144,21 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
            mask: Optional[jnp.ndarray] = None, impl: str = "dense",
            axis_name: Optional[str] = None,
-           causal: bool = False) -> jnp.ndarray:
+           causal: bool = False,
+           window: Optional[int] = None) -> jnp.ndarray:
+    """``window`` (with ``causal``): a sliding window, query i attends keys
+    ``i - window < j <= i``; dense and flash take it."""
     if impl == "dense":
-        return dot_product_attention(q, k, v, mask, causal=causal)
+        return dot_product_attention(q, k, v, mask, causal=causal,
+                                     window=window)
     if impl == "flash":
         from .pallas_ops import flash_attention
-        return flash_attention(q, k, v, mask, causal=causal)
+        return flash_attention(q, k, v, mask, causal=causal, window=window)
     if impl in ("ring", "ring_zigzag", "all_to_all"):
+        if window is not None:
+            raise NotImplementedError(
+                f"{impl} attention has no sliding window; use dense or "
+                "flash")
         if axis_name is None:
             raise ValueError(f"{impl} attention requires axis_name (the mesh "
                              "axis the sequence is sharded over)")

@@ -77,59 +77,96 @@ def _dot(a, b, dims):
 
 
 def tile_plan(bq: int, bk: int, tq: int, tk: int, off: int = 0,
-              causal: bool = True) -> list:
-    """``(n_full, n_vis)`` for each ``tq``-row sub-tile of a ``[bq, bk]``
-    block whose first query sits ``off`` positions after its first key
-    (top-left aligned mask: key c visible to query r iff c <= r + off).
-    Of the row's ``bk // tk`` key sub-tiles the first ``n_full`` lie wholly
-    on or below the diagonal (no mask), the next ``n_vis - n_full`` are
-    crossed by it (masked) and the rest lie above it (not visited)."""
+              causal: bool = True, window: Optional[int] = None) -> list:
+    """``(n_skip, n_lo, n_full, n_vis)`` for each ``tq``-row sub-tile of a
+    ``[bq, bk]`` block whose first query sits ``off`` positions after its
+    first key (top-left aligned mask: key c visible to query r iff
+    ``r + off - window < c <= r + off``; no ``window``: no lower edge).
+    Of the row's ``bk // tk`` key sub-tiles the first ``n_skip`` lie wholly
+    under the window's lower edge (not visited), those up to ``n_lo`` are
+    touched by it (masked), those from there up to ``n_full`` lie wholly
+    inside (no mask), those up to ``n_vis`` are crossed by the diagonal
+    (masked) and the rest lie above it (not visited).  Where ``n_lo >
+    n_full`` the sub-tiles between are crossed by both edges."""
     nkt = bk // tk
     if not causal:
-        return [(nkt, nkt)] * (bq // tq)
+        return [(0, 0, nkt, nkt)] * (bq // tq)
     clip = lambda n: max(0, min(nkt, n))
-    return [(clip((off + a * tq + 1) // tk),
-             clip((off + a * tq + tq - 1) // tk + 1))
-            for a in range(bq // tq)]
+    plan = []
+    for a in range(bq // tq):
+        first, last = off + a * tq, off + a * tq + tq - 1
+        n_skip = n_lo = 0
+        if window is not None:
+            n_skip = clip((first - window + 1) // tk)
+            n_lo = clip((last - window) // tk + 1)
+        plan.append((n_skip, n_lo, clip((first + 1) // tk),
+                     clip(last // tk + 1)))
+    return plan
 
 
 def tile_counts(plan: list) -> tuple:
     """(visited, masked) sub-tiles of a plan."""
-    return (sum(n_vis for _, n_vis in plan),
-            sum(n_vis - n_full for n_full, n_vis in plan))
+    visited = sum(n_vis - n_skip for n_skip, _, _, n_vis in plan)
+    unmasked = sum(max(0, n_full - n_lo) for _, n_lo, n_full, _ in plan)
+    return visited, visited - unmasked
 
 
-def _block_groups(bq, bk, tq, tk, off, by_key=False):
-    """The pieces of a block that the causal mask leaves, as static slices.
+def _slabs(n0, n1, n2, n3, unit, base):
+    """The run ``[n0, n3)`` of visited sub-tiles along one axis of a block,
+    cut where the mask changes: ``[(slice, first, second, off), ...]``.
+    Sub-tiles before ``n1`` are touched by the first of the mask's two
+    edges along this axis, those from ``n2`` on by the second, those
+    between by neither; where ``n1 >= n2`` no sub-tile is free of both and
+    the run stays one piece.  ``off`` is the piece's own offset (first
+    query position minus first key position), ``base`` of where it starts."""
+    piece = lambda a, b, first, second: (slice(a * unit, b * unit), first,
+                                         second, base(a))
+    if n0 >= n3:
+        return []
+    if n1 >= n2:
+        return [piece(n0, n3, n0 < n1, n2 < n3)]
+    pieces = [piece(n0, n1, True, False)] if n0 < n1 else []
+    pieces.append(piece(n1, n2, False, False))
+    if n2 < n3:
+        pieces.append(piece(n2, n3, False, True))
+    return pieces
 
-    A list of ``(rows, [(cols, mask_off), ...])``: per query sub-tile its
-    unmasked key slab then its masked one (``by_key``: per key sub-tile its
-    masked query slab then its unmasked one; the pair then reads
-    ``(cols, [(rows, mask_off), ...])``).  ``mask_off`` is the piece's own
-    ``off`` for ``_scores``, ``None`` where no mask is needed; a sub-tile
-    that sees nothing keeps its entry, with no pieces."""
-    plan = tile_plan(bq, bk, tq, tk, off)
+
+def _block_groups(bq, bk, tq, tk, off, by_key=False, window=None):
+    """The pieces of a block that the mask leaves, as static slices.
+
+    A list of ``(rows, [(cols, hi, lo), ...])``: per query sub-tile its key
+    slab on the window's lower edge, its unmasked slab and its slab on the
+    diagonal (``by_key``: per key sub-tile its query slab on the diagonal,
+    its unmasked one and the one on the window's edge; the pair then reads
+    ``(cols, [(rows, hi, lo), ...])``).  ``hi`` and ``lo`` are the piece's
+    own offsets for ``_scores``, each ``None`` where that edge needs no
+    mask; a sub-tile that sees nothing keeps its entry, with no pieces."""
+    plan = tile_plan(bq, bk, tq, tk, off, window=window)
+    under = lambda o: None if window is None else o - window
     groups = []
     if by_key:
         for c in range(bk // tk):
-            vis = sum(n_vis <= c for _, n_vis in plan)      # first visited
-            full = sum(n_full <= c for n_full, _ in plan)   # first unmasked
-            pieces = []
-            if full > vis:
-                pieces.append((slice(vis * tq, full * tq),
-                               off + vis * tq - c * tk))
-            if full < len(plan):
-                pieces.append((slice(full * tq, bq), None))
-            groups.append((slice(c * tk, (c + 1) * tk), pieces))
+            # down a key column the diagonal comes first, the window's
+            # edge second: rows before ``vis`` do not see the column yet,
+            # rows from ``end`` on have left it behind
+            vis, full, lo, end = (sum(p[n] <= c for p in plan)
+                                  for n in (3, 2, 1, 0))
+            pieces = _slabs(vis, full, lo, end, tq,
+                            lambda v, c=c: off + v * tq - c * tk)
+            groups.append((slice(c * tk, (c + 1) * tk),
+                           [(s, o if first else None,
+                             under(o) if second else None)
+                            for s, first, second, o in pieces]))
         return groups
-    for a, (n_full, n_vis) in enumerate(plan):
-        pieces = []
-        if n_full:
-            pieces.append((slice(0, n_full * tk), None))
-        if n_vis > n_full:
-            pieces.append((slice(n_full * tk, n_vis * tk),
-                           off + a * tq - n_full * tk))
-        groups.append((slice(a * tq, (a + 1) * tq), pieces))
+    for a, row in enumerate(plan):
+        # along a query row the window's edge comes first, the diagonal
+        # second
+        pieces = _slabs(*row, tk, lambda c, a=a: off + a * tq - c * tk)
+        groups.append((slice(a * tq, (a + 1) * tq),
+                       [(s, o if second else None,
+                         under(o) if first else None)
+                        for s, first, second, o in pieces]))
     return groups
 
 
@@ -138,41 +175,55 @@ def _tiles(bq, bk):
     return _block_size(bq, TILE), _block_size(bk, TILE)
 
 
-def _crossing_offsets(ni, nk, bq, bk):
-    """Every ``i * bq - j * bk`` of a grid block the diagonal crosses
-    (``{0}`` whenever bq == bk or one block covers the sequence)."""
-    return sorted({i * bq - j * bk for i in range(ni) for j in range(nk)
-                   if -bq < i * bq - j * bk < bk - 1})
+def _crossing_offsets(ni, nk, bq, bk, window=None):
+    """Every ``i * bq - j * bk`` of a grid block that an edge of the mask
+    crosses: the diagonal (``{0}`` whenever bq == bk or one block covers
+    the sequence) and, with a ``window``, its lower edge."""
+    seen = lambda o: o > -bq and (window is None or o - window < bk - 1)
+    return sorted({o for o in (i * bq - j * bk for i in range(ni)
+                               for j in range(nk))
+                   if seen(o) and not _inside(o, bq, bk, window)})
 
 
-def _visit(i, j, *, ni, nk, bq, bk, causal, body, by_key=False):
+def _inside(off, bq, bk, window):
+    """Block at offset ``off`` lies wholly inside the mask: on or below the
+    diagonal and above the window's lower edge.  ``off`` may be traced."""
+    below = off >= bk - 1
+    return below if window is None else below & (off < window - bq + 1)
+
+
+def _visit(i, j, *, ni, nk, bq, bk, causal, body, by_key=False, window=None):
     """Run ``body(major, pieces)`` over the part of grid block (i, j) that
-    the mask leaves.  Not causal, or wholly below the diagonal: the block
-    in one unmasked piece.  Crossed by the diagonal: sub-tiled, unrolled
-    from the static geometry.  Wholly above it: nothing."""
-    whole = lambda: body(ALL, [(ALL, None)])
+    the mask leaves.  Not causal, or wholly inside the mask: the block in
+    one unmasked piece.  Crossed by the diagonal or by the window's lower
+    edge: sub-tiled, unrolled from the static geometry.  Wholly above the
+    one or under the other: nothing."""
+    whole = lambda: body(ALL, [(ALL, None, None)])
     if not causal:
         return whole()
     static = ni * nk == 1       # the one block: i = j = 0, nothing to test
     off = i * bq - j * bk
-    if not static:
-        pl.when(off >= bk - 1)(whole)
-    for o in _crossing_offsets(ni, nk, bq, bk):
+    if not static and (window is None or window > bq + bk - 2):
+        pl.when(_inside(off, bq, bk, window))(whole)
+    for o in _crossing_offsets(ni, nk, bq, bk, window):
         def crossed(o=o):
             for major, pieces in _block_groups(bq, bk, *_tiles(bq, bk), o,
-                                               by_key):
+                                               by_key, window):
                 body(major, pieces)
         crossed() if static else pl.when(off == o)(crossed)
 
 
-def _scores(q, k, scale, off):
-    """f32 ``q @ k.T * scale``; with ``off`` (first query position minus
-    first key position of the piece) the future keys read NEG_INF."""
+def _scores(q, k, scale, hi, lo=None):
+    """f32 ``q @ k.T * scale``; with ``hi`` (first query position minus
+    first key position of the piece) the future keys read NEG_INF, with
+    ``lo`` (the same less the window) so do the keys the window has left
+    behind."""
     s = _dot(q, k, _NT) * scale
-    if off is not None:
-        qpos = off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
+    for off, seen in ((hi, jnp.less_equal), (lo, jnp.greater)):
+        if off is not None:
+            qpos = off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(seen(kpos, qpos), s, NEG_INF)
     return s
 
 
@@ -186,7 +237,7 @@ def _one_block(causal: bool, ni: int, nk: int, rep: int = 1) -> bool:
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
                   scale: float, ni: int, nk: int, bq: int, bk: int,
-                  causal: bool):
+                  causal: bool, window: Optional[int] = None):
     # refs are [1, 1, block, D] tiles of the [B, H, L, D] operands: the TPU
     # lowering needs the (sublane, lane) = last-two dims to be the tiled
     # (sequence, head_dim) pair, not (head, head_dim).  ``state`` is the
@@ -217,14 +268,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
         # one online-softmax update of the query rows over all their key
         # pieces at once (bf16 operands into the MXU, f32 from there on).
         # j == 0 always holds key 0, which every query attends, so the
-        # running max is real from the first processed block on
+        # running max is real from the first processed block on.  Under a
+        # window a row may meet only hidden keys first (NEG_INF, finite):
+        # what it gathers there is wiped by ``corr`` at its first real key,
+        # and its own position is one
         if not pieces:
             return
         q = q_ref[0, 0, rows, :]
         kv = [(k_ref[0, 0, cols, :], v_ref[0, 0, cols, :])
-              for cols, _ in pieces]
-        s = [_scores(q, k, scale, off)
-             for (k, _), (_, off) in zip(kv, pieces)]
+              for cols, _, _ in pieces]
+        s = [_scores(q, k, scale, hi, lo)
+             for (k, _), (_, hi, lo) in zip(kv, pieces)]
         tops = [x.max(axis=-1, keepdims=True) for x in s]
         if state:
             m_prev = m_ref[rows, :]
@@ -243,7 +297,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
         acc_ref[rows, :] = acc_ref[rows, :] * corr + acc
         m_ref[rows, :] = m
 
-    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step)
+    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
+           window=window)
 
     if state:
         @pl.when(j == nk - 1)
@@ -263,7 +318,31 @@ def _fwd_kernel_nolse(q_ref, k_ref, v_ref, o_ref, *state, **kw):
     _flash_kernel(q_ref, k_ref, v_ref, o_ref, None, *state, **kw)
 
 
-def _flash_forward(q, k, v, causal=False, with_lse=False):
+def _needed_blocks(ni, nk, bq, bk, causal, window):
+    """``(key_block(i, j), query_block(i, j))`` for the block specs' index
+    maps: the block a grid step loads.  A step that the mask skips loads
+    the nearest block its row (its column) needs, which is the one already
+    there or the next one wanted, so a skipped step moves no data.  One
+    block, or no mask: the step's own."""
+    if not causal or ni * nk == 1:
+        return (lambda i, j: j), (lambda i, j: i)
+
+    def key_block(i, j):
+        last = jnp.minimum((i * bq + bq - 1) // bk, nk - 1)
+        first = 0 if window is None else jnp.maximum(
+            (i * bq - window + 1) // bk, 0)
+        return jnp.clip(j, first, last)
+
+    def query_block(i, j):
+        first = jnp.minimum((j * bk) // bq, ni - 1)
+        last = ni - 1 if window is None else jnp.minimum(
+            (j * bk + bk + window - 2) // bq, ni - 1)
+        return jnp.clip(i, first, last)
+
+    return key_block, query_block
+
+
+def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
     b, lq, h, d = q.shape
     lk = k.shape[1]
     # K/V may carry fewer heads (grouped-query attention): the grid still
@@ -276,14 +355,15 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
     bq, bk = _block_size(lq, BQ), _block_size(lk, BK)
     scale = 1.0 / (d ** 0.5)
     grid = (b, h, lq // bq, lk // bk)
-    _log_tiles(lq, lk, bq, bk, causal)
+    _log_tiles(lq, lk, bq, bk, causal, window)
+    key_block, _ = _needed_blocks(lq // bq, lk // bk, bq, bk, causal, window)
     # [B, L, H, D] -> [B, H, L, D]: the kernel tiles over (seq, head_dim)
     qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     # under shard_map's varying-manual-axes typing the out aval must carry
     # the same mesh-varying set as the inputs
     vma = jax.typeof(qt).vma
     kw = dict(scale=scale, ni=lq // bq, nk=lk // bk, bq=bq, bk=bk,
-              causal=causal)
+              causal=causal, window=window)
     kernel = (functools.partial(_flash_kernel, **kw) if with_lse
               else functools.partial(_fwd_kernel_nolse, **kw))
     o_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0),
@@ -303,10 +383,12 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
         in_specs=[
             o_spec,
             pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // rep, j, 0),
+                         lambda b_, h_, i, j: (b_, h_ // rep,
+                                               key_block(i, j), 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // rep, j, 0),
+                         lambda b_, h_, i, j: (b_, h_ // rep,
+                                               key_block(i, j), 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=out_specs,
@@ -326,16 +408,16 @@ def _flash_forward(q, k, v, causal=False, with_lse=False):
     return out[0].transpose(0, 2, 1, 3)
 
 
-def _p_ds(q, k, v, do, lse, delta, scale, off):
+def _p_ds(q, k, v, do, lse, delta, scale, hi, lo):
     """Softmax probabilities of a piece, recomputed from the saved
     log-sum-exp, and ds = p * (do v^T - delta) * scale (both f32)."""
-    p = jnp.exp(_scores(q, k, scale, off) - lse)
+    p = jnp.exp(_scores(q, k, scale, hi, lo) - lse)
     return p, p * (_dot(do, v, _NT) - delta) * scale
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                    *acc, scale: float, ni: int, nk: int, bq: int, bk: int,
-                   causal: bool):
+                   causal: bool, window: Optional[int] = None):
     """dQ pass: grid (b, h, iq, jk), K/V innermost; accumulates
     dq_i = sum_j ds_ij k_j with ds = p * (do v^T - delta) * scale, in the
     f32 scratch ``acc`` (a ``_one_block`` call has none)."""
@@ -357,10 +439,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         lse = lse_ref[0, 0, rows, :1]                    # [rows, 1]
         delta = dl_ref[0, 0, rows, :1]
         parts = []
-        for cols, off in pieces:
+        for cols, hi, lo in pieces:
             k = k_ref[0, 0, cols, :]
             _, ds = _p_ds(q, k, v_ref[0, 0, cols, :], do, lse, delta,
-                          scale, off)
+                          scale, hi, lo)
             parts.append(_dot(ds.astype(k.dtype), k, _NN))   # [rows, d]
         dq = functools.reduce(jnp.add, parts)
         if acc:
@@ -368,7 +450,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         else:
             dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
 
-    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step)
+    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
+           window=window)
 
     if acc:
         @pl.when(j == nk - 1)
@@ -378,7 +461,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                     dk_ref, dv_ref, *acc, scale: float, ni: int, nk: int,
-                    rep: int, bq: int, bk: int, causal: bool):
+                    rep: int, bq: int, bk: int, causal: bool,
+                    window: Optional[int] = None):
     """dK/dV pass: grid (b, kv_head, jk, it), Q innermost; accumulates
     dv_j = sum_i p^T do_i and dk_j = sum_i ds^T q_i in the f32 scratch
     ``acc`` (a ``_one_block`` call has none).
@@ -410,11 +494,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         k = k_ref[0, 0, cols, :]
         v = v_ref[0, 0, cols, :]
         parts = []
-        for rows, off in pieces:
+        for rows, hi, lo in pieces:
             q = q_ref[0, 0, rows, :]
             do = do_ref[0, 0, rows, :]
             p, ds = _p_ds(q, k, v, do, lse_ref[0, 0, rows, :1],
-                          dl_ref[0, 0, rows, :1], scale, off)
+                          dl_ref[0, 0, rows, :1], scale, hi, lo)
             parts.append((_dot(p.astype(do.dtype), do, _TN),     # [cols, d]
                           _dot(ds.astype(q.dtype), q, _TN)))
         dv, dk = (functools.reduce(jnp.add, x) for x in zip(*parts))
@@ -426,7 +510,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
 
     _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
-           by_key=True)
+           by_key=True, window=window)
 
     if acc:
         @pl.when(it == ni * rep - 1)
@@ -435,7 +519,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, o, lse, g, causal):
+def _flash_backward(q, k, v, o, lse, g, causal, window=None):
     """Blockwise flash backward: O(L) memory, no L x L score materialization
     (the FlashAttention-2 construction: recompute p from q, k and the saved
     log-sum-exp, accumulate dq / dk / dv per block pair)."""
@@ -452,18 +536,21 @@ def _flash_backward(q, k, v, o, lse, g, causal):
                        ot.astype(jnp.float32))
     delta = jnp.broadcast_to(delta[..., None], (b, h, lq, LANES))
     vma = jax.typeof(qt).vma
+    key_block, query_block = _needed_blocks(ni, nk, bq, bk, causal, window)
     row = lambda m: pl.BlockSpec((1, 1, bq, m),
                                  lambda b_, h_, i, j: (b_, h_, i, 0),
                                  memory_space=pltpu.VMEM)
     col = lambda m: pl.BlockSpec((1, 1, bk, m),
-                                 lambda b_, h_, i, j: (b_, h_ // rep, j, 0),
+                                 lambda b_, h_, i, j: (b_, h_ // rep,
+                                                       key_block(i, j), 0),
                                  memory_space=pltpu.VMEM)
     # dkv grid (b, kv_head, j, it) with it = member * ni + iq: per-q-head
     # operands map query head g * rep + it // ni; K/V-side blocks map the
     # group head directly (with rep == 1 these reduce to the plain maps)
     rowT = lambda m: pl.BlockSpec(
         (1, 1, bq, m),
-        lambda b_, g, j, it: (b_, g * rep + it // ni, it % ni, 0),
+        lambda b_, g, j, it: (b_, g * rep + it // ni,
+                              query_block(it % ni, j), 0),
         memory_space=pltpu.VMEM)
     colT = lambda m: pl.BlockSpec((1, 1, bk, m),
                                   lambda b_, g, j, it: (b_, g, j, 0),
@@ -473,7 +560,7 @@ def _flash_backward(q, k, v, o, lse, g, causal):
 
     dqt = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, ni=ni, nk=nk,
-                          bq=bq, bk=bk, causal=causal),
+                          bq=bq, bk=bk, causal=causal, window=window),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma),
         grid=(b, h, ni, nk),
         in_specs=[row(d), col(d), col(d), row(d), row(LANES), row(LANES)],
@@ -485,7 +572,8 @@ def _flash_backward(q, k, v, o, lse, g, causal):
 
     dkt, dvt = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, ni=ni, nk=nk,
-                          rep=rep, bq=bq, bk=bk, causal=causal),
+                          rep=rep, bq=bq, bk=bk, causal=causal,
+                          window=window),
         out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype, vma=vma),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype, vma=vma)],
         grid=(b, kv, nk, ni * rep),
@@ -524,18 +612,20 @@ def _log_fallback(reason: str, q) -> None:
             "q shape %s: %s", q.shape, reason)
 
 
-TILE_COUNTS: dict = {}   # (lq, lk, causal) -> (visited, total, masked)
+TILE_COUNTS: dict = {}   # (lq, lk, causal, window) -> (visited, total, masked)
 
 
-def _log_tiles(lq: int, lk: int, bq: int, bk: int, causal: bool) -> None:
+def _log_tiles(lq: int, lk: int, bq: int, bk: int, causal: bool,
+               window: Optional[int] = None) -> None:
     """Record ONCE per call shape, at trace time, how many of the score
     square's sub-tiles the kernels visit and how many of those they mask —
-    the counter that says the causal skip engages (visited < total)."""
-    key = (lq, lk, causal)
+    the counter that says the causal skip and the window's engage
+    (visited < total)."""
+    key = (lq, lk, causal, window)
     if key in TILE_COUNTS:
         return
     tq, tk = _tiles(bq, bk)
-    plans = [tile_plan(bq, bk, tq, tk, i * bq - j * bk, causal)
+    plans = [tile_plan(bq, bk, tq, tk, i * bq - j * bk, causal, window)
              for i in range(lq // bq) for j in range(lk // bk)]
     visited, masked = map(sum, zip(*map(tile_counts, plans)))
     TILE_COUNTS[key] = (visited, (lq // tq) * (lk // tk), masked)
@@ -544,25 +634,28 @@ def _log_tiles(lq: int, lk: int, bq: int, bk: int, causal: bool) -> None:
 
 
 def tiles_line(key: tuple) -> str:
-    """``flash tiles L=1024 causal: visited 10/16, masked 4``"""
-    lq, lk, causal = key
+    """``flash tiles L=1024 causal: visited 10/16, masked 4``; a windowed
+    shape reads ``L=8192 causal window 1024: ...``"""
+    lq, lk, causal, window = key
+    mask = "causal" if causal else "full"
+    if window is not None:
+        mask += f" window {window}"
     return ("flash tiles L=%s %s: visited %d/%d, masked %d" % (
-        lq if lq == lk else f"{lq}x{lk}", "causal" if causal else "full",
-        *TILE_COUNTS[key]))
+        lq if lq == lk else f"{lq}x{lk}", mask, *TILE_COUNTS[key]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash(q, k, v, causal=False):
-    return _flash_forward(q, k, v, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, causal=False, window=None):
+    return _flash_forward(q, k, v, causal, window=window)
 
 
-def _flash_fwd_rule(q, k, v, causal):
-    o, lse = _flash_forward(q, k, v, causal, with_lse=True)
+def _flash_fwd_rule(q, k, v, causal, window):
+    o, lse = _flash_forward(q, k, v, causal, with_lse=True, window=window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, res, g):
-    return _flash_backward(*res, g, causal)
+def _flash_bwd_rule(causal, window, res, g):
+    return _flash_backward(*res, g, causal, window)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -570,10 +663,17 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     mask: Optional[jnp.ndarray] = None,
-                    causal: bool = False) -> jnp.ndarray:
+                    causal: bool = False,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """[B, L, H, D] flash attention (K/V may carry fewer heads — GQA);
-    dense fallback off the fast path, logged once per shape."""
+    dense fallback off the fast path, logged once per shape.  ``window``
+    (causal only): query i attends keys ``i - window < j <= i``."""
     from .attention import dot_product_attention
+    if window is not None and not causal:
+        raise ValueError("a sliding window is the causal mask's lower edge: "
+                         "window needs causal=True")
+    dense = functools.partial(dot_product_attention, q, k, v, mask,
+                              causal=causal, window=window)
     # the Pallas HLO interpreter (CPU test path) cannot lower kernels whose
     # operands are mesh-varying inside shard_map; the unit tests cover the
     # kernel outside shard_map and the real path compiles on TPU
@@ -581,14 +681,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if mask is not None:
         _log_fallback("arbitrary masks are not tiled (use causal=True for "
                       "autoregressive masking)", q)
-        return dot_product_attention(q, k, v, mask, causal=causal)
+        return dense()
     if not _supported(q, k):
         _log_fallback(
             "shape outside tiling constraints (needs a 128-multiple block "
             "dividing both sequence lengths, head_dim <= 256, and query "
             "heads divisible by kv heads)", q)
-        return dot_product_attention(q, k, v, mask, causal=causal)
+        return dense()
     if _interpret() and in_shard_map:
         # expected on the CPU test mesh, not a perf surprise: no warning
-        return dot_product_attention(q, k, v, mask, causal=causal)
-    return _flash(q, k, v, causal)
+        return dense()
+    return _flash(q, k, v, causal, window)

@@ -194,15 +194,25 @@ def memory_report(programs: dict, *, state_bytes: dict | None = None,
     return report
 
 
+UNMEASURED = 1e-9   # sec/batch of a worker that no probe has timed
+
+
 def measure_step_time(model, variables, sample_batch: np.ndarray,
                       num_batches: int = 10) -> float:
-    """Seconds for ``num_batches`` jitted fwd+bwd executions (post-compile)."""
+    """Seconds for ``num_batches`` jitted fwd+bwd executions (post-compile).
+
+    ``model`` is the model that trains (the driver's ``host_model``).  The
+    executions run one at a time and each hands back one scalar, the sum
+    of its gradient, so the probe holds one gradient tree as a temporary
+    and never ``num_batches`` of them as outputs beside the resident
+    state."""
 
     def fwd_bwd(params, rest, x):
         def loss(p):
             out = model.apply({"params": p, **rest}, x, train=False)
             return out.sum()
-        return jax.grad(loss)(params)
+        return sum(g.sum() for g in jax.tree_util.tree_leaves(
+            jax.grad(loss)(params)))
 
     num_batches = max(num_batches, 1)
     params = variables["params"]
@@ -216,8 +226,7 @@ def measure_step_time(model, variables, sample_batch: np.ndarray,
     jax.block_until_ready(fn(params, rest, x))  # compile
     t0 = time.perf_counter()
     for _ in range(num_batches):
-        g = fn(params, rest, x)
-    jax.block_until_ready(g)
+        jax.block_until_ready(fn(params, rest, x))
     return time.perf_counter() - t0
 
 
@@ -280,7 +289,14 @@ def joiner_sec_per_batch(survivor_spb: np.ndarray,
 def estimate_epoch_duration(model, variables, sample_batch: np.ndarray,
                             world_size: int, num_batches: int = 10,
                             simulated_durations=None):
-    """Returns (durations [world_size], sec_per_batch [world_size])."""
+    """Returns (durations [world_size], sec_per_batch [world_size]).
+
+    One worker has nobody to be compared with: its ratio is 1 whatever the
+    probe would read, so none runs.  Its duration is a unit one and its
+    sec/batch ``UNMEASURED``, which caps no round; the measured round
+    walls take the estimate over from there (``driver.consume_walls``)."""
+    if simulated_durations is None and world_size == 1:
+        return np.ones(1), np.full(1, UNMEASURED)
     if simulated_durations is None:
         local = measure_step_time(model, variables, sample_batch, num_batches)
     else:

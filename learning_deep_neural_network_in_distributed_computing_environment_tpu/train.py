@@ -228,6 +228,17 @@ def rank0_variables(state: "TrainState", *, params_template=None,
     return variables
 
 
+def _mean_by_name(collection: dict) -> dict:
+    """{name: scalar} of a sown collection: every value sown under one
+    name (one a layer, stacked under a scan) averaged."""
+    from flax.traverse_util import flatten_dict
+    by_name: dict = {}
+    for path, sown in flatten_dict(dict(collection)).items():
+        by_name.setdefault(path[-1], []).extend(
+            jnp.mean(v) for v in sown)
+    return {name: jnp.mean(jnp.stack(vs)) for name, vs in by_name.items()}
+
+
 def steplr(lr0: float, gamma: float, step_size: int, epoch: jnp.ndarray):
     """torch.optim.lr_scheduler.StepLR equivalent, stepped per LOCAL epoch
     (ref trainer.py:218 + main.py:54; StepLR(step_size=25), default gamma
@@ -1467,7 +1478,7 @@ class LocalSGDEngine:
             # schedule aux counted this device's batch slice / seq chunk
             correct = lax.psum(correct, part)
             total = lax.psum(total, part)
-        return loss, (batch_stats, correct, total)
+        return loss, (batch_stats, correct, total, {})
 
     def _loss_and_metrics(self, params, batch_stats, xb, yb, mb,
                           denom=None, aux_div=1.0):
@@ -1476,7 +1487,13 @@ class LocalSGDEngine:
         ``aux_div`` come from the gradient-accumulation wrapper: this
         call then sees ONE microbatch slice and returns its numerator
         over the shared denominator, so the K slice losses/grads SUM to
-        the full-batch step's (``_accum_value_and_grad``)."""
+        the full-batch step's (``_accum_value_and_grad``).
+
+        The aux's last element is the step's ``counters``: what the model
+        sowed into that collection (``models.moe.RoutedExperts``: rows on
+        held experts, fullest expert over the mean), each name averaged
+        over the layers that sowed it; ``{}`` for a model that sows
+        none."""
         if self.onef1b:
             return self._onef1b_loss_and_metrics(params, batch_stats,
                                                  xb, yb, mb, denom=denom,
@@ -1489,7 +1506,8 @@ class LocalSGDEngine:
             params = gather_params(params, self.param_specs, self.fsdp_axis)
         out, mut = self.train_model.apply(
             {"params": params, "batch_stats": batch_stats}, xb, train=True,
-            mutable=["batch_stats", "aux"])
+            mutable=["batch_stats", "aux", "counters"])
+        counters = _mean_by_name(mut.get("counters", {}))
         ce, w, correct = self._token_stats(out, yb, mb)
         part_axes = self._part_axes()
         if denom is not None:
@@ -1564,7 +1582,7 @@ class LocalSGDEngine:
             # stats are averaged so the stored tree stays replicated along
             # the fsdp axis
             new_bs = lax.pmean(new_bs, self.fsdp_axis)
-        return loss, (new_bs, correct, total)
+        return loss, (new_bs, correct, total, counters)
 
     def _accum_value_and_grad(self, params, batch_stats, xb, yb, mb):
         """Microbatch gradient accumulation (ISSUE 3): split the step's
@@ -1596,7 +1614,7 @@ class LocalSGDEngine:
 
         def micro(g, inp):
             x_k, y_k, m_k = inp
-            (loss_k, (_bs, c_k, t_k)), g_k = jax.value_and_grad(
+            (loss_k, (_bs, c_k, t_k, n_k)), g_k = jax.value_and_grad(
                 self._loss_and_metrics, has_aux=True)(
                     params, batch_stats, x_k, y_k, m_k,
                     denom=denom, aux_div=float(k))
@@ -1605,17 +1623,18 @@ class LocalSGDEngine:
             # the scalars ride as stacked scan OUTPUTS — ys have no
             # carry type-matching constraint on either runtime — and
             # sum after the loop
-            return g, (loss_k, c_k, t_k)
+            return g, (loss_k, c_k, t_k, n_k)
 
         zeros = _zeros_like_varying(params, dtype=jnp.float32,
                                     extra_axes=part)
-        grads, (losses, corrects, totals) = lax.scan(
+        grads, (losses, corrects, totals, counters) = lax.scan(
             micro, zeros, (xs, ys, ms))
         loss, correct, total = losses.sum(), corrects.sum(), totals.sum()
+        counters = jax.tree_util.tree_map(lambda c: c.mean(0), counters)
         # batch_stats pass through unchanged: accumulation is gated to
         # models without BatchNorm (driver validates), so the tree is
         # empty and the step's _tree_where keeps it as-is
-        return (loss, (batch_stats, correct, total)), grads
+        return (loss, (batch_stats, correct, total, counters)), grads
 
     def _make_step_fns(self, augment: bool):
         """The shared per-batch bodies: one SGD step and one eval step.
@@ -1636,11 +1655,11 @@ class LocalSGDEngine:
                         k, lax.axis_index(self.fsdp_axis))
                 xb = augment_batch(k, xb)
             if self.grad_accum > 1:
-                (loss, (new_bs, correct, total)), grads = \
+                (loss, (new_bs, correct, total, counters)), grads = \
                     self._accum_value_and_grad(params, batch_stats,
                                                xb, yb, mb)
             else:
-                (loss, (new_bs, correct, total)), grads = \
+                (loss, (new_bs, correct, total, counters)), grads = \
                     jax.value_and_grad(
                         self._loss_and_metrics, has_aux=True)(
                             params, batch_stats, xb, yb, mb)
@@ -1672,7 +1691,7 @@ class LocalSGDEngine:
             opt_state = _tree_where(do, new_opt, opt_state)
             grads = _tree_where(do, grads, carry[5])
             return ((params, batch_stats, opt_state, rng, lr, grads),
-                    (loss, correct, total))
+                    (loss, correct, total, counters))
 
         def eval_step(carry, inp):
             # NOTE: under FSDP the carry must hold FULL params — callers
@@ -1730,7 +1749,7 @@ class LocalSGDEngine:
                 if lr_scale is not None:
                     lr = lr * lr_scale
                 (params, batch_stats, opt_state, rng, _, last_grads), \
-                    (losses, corrects, totals) = lax.scan(
+                    (losses, corrects, totals, counters) = lax.scan(
                         train_step,
                         (params, batch_stats, opt_state, rng, lr,
                          zero_grads),
@@ -1756,7 +1775,9 @@ class LocalSGDEngine:
                 per_epoch = dict(
                     batch_losses=losses, batch_mask=real_step,
                     train_loss=train_loss, train_acc=train_acc,
-                    val_loss=val_loss, val_acc=val_acc)
+                    val_loss=val_loss, val_acc=val_acc,
+                    # per training step, [S] each; {} where none is sown
+                    counters=counters)
                 return ((params, batch_stats, opt_state, lr_epoch, rng,
                          last_grads), per_epoch)
 
@@ -2316,7 +2337,9 @@ class LocalSGDEngine:
             carry = (params, batch_stats, opt_state, rng, lr, grads)
             carry, ys = lax.scan(train_step, carry, (x, y, m))
             params, batch_stats, opt_state, rng, _, grads = carry
-            return (params, batch_stats, opt_state, rng, grads), ys
+            # (loss, correct, total): the streamed round carries no
+            # counters
+            return (params, batch_stats, opt_state, rng, grads), ys[:3]
 
         xs, ys_, ms = self._pack_specs()
         inner = self._inner_specs()

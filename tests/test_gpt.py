@@ -108,29 +108,40 @@ class TestFlashCausalTiles:
         assert pallas_ops.tile_counts(plan) == (visited, masked)
 
     @pytest.mark.parametrize("by_key", [False, True])
-    @pytest.mark.parametrize("bq,bk,tq,tk,off", [
-        (1024, 1024, 256, 256, 0), (512, 512, 128, 128, 0),
-        (256, 384, 128, 128, 128), (256, 384, 128, 128, -128),
-        (512, 256, 256, 128, 0), (256, 256, 128, 128, 255)])
+    @pytest.mark.parametrize("bq,bk,tq,tk,off,window", [
+        (1024, 1024, 256, 256, 0, None), (512, 512, 128, 128, 0, None),
+        (256, 384, 128, 128, 128, None), (256, 384, 128, 128, -128, None),
+        (512, 256, 256, 128, 0, None), (256, 256, 128, 128, 255, None),
+        (1024, 1024, 256, 256, 1024, 1024),  # the block under the diagonal's
+        (1024, 1024, 256, 256, 0, 1024),     # window = block: edge outside
+        (512, 512, 128, 128, 0, 200),        # edge inside a sub-tile
+        (512, 512, 128, 128, 0, 256),        # edge on a sub-tile boundary
+        (512, 512, 128, 128, 0, 16),         # both edges in one sub-tile
+        (256, 256, 128, 128, 256, 300),      # the edge alone, shifted
+        (256, 384, 128, 128, 128, 130)])
     def test_block_groups_cover_the_triangle_once(self, pallas_ops, by_key,
-                                                  bq, bk, tq, tk, off):
+                                                  bq, bk, tq, tk, off,
+                                                  window):
         """Every visible (query, key) pair lies in exactly one piece, no
         unmasked piece holds a hidden pair, and a masked piece's own
-        offset reproduces the block's mask."""
-        visible = (np.arange(bk)[None] <= np.arange(bq)[:, None] + off)
+        offsets reproduce the block's mask."""
+        r, c = np.arange(bq)[:, None] + off, np.arange(bk)[None]
+        visible = c <= r
+        if window is not None:
+            visible &= c > r - window
         seen = np.zeros((bq, bk), int)
         for major, pieces in pallas_ops._block_groups(bq, bk, tq, tk, off,
-                                                      by_key):
-            for minor, mask_off in pieces:
+                                                      by_key, window):
+            for minor, hi, lo in pieces:
                 rows, cols = (minor, major) if by_key else (major, minor)
-                if mask_off is None:
-                    assert visible[rows, cols].all()
-                    seen[rows, cols] += 1
-                else:
-                    n, m = seen[rows, cols].shape
-                    own = np.arange(m)[None] <= np.arange(n)[:, None] + mask_off
-                    np.testing.assert_array_equal(own, visible[rows, cols])
-                    seen[rows, cols] += own
+                n, m = seen[rows, cols].shape
+                own = np.ones((n, m), bool)
+                if hi is not None:
+                    own &= np.arange(m)[None] <= np.arange(n)[:, None] + hi
+                if lo is not None:
+                    own &= np.arange(m)[None] > np.arange(n)[:, None] + lo
+                np.testing.assert_array_equal(own, visible[rows, cols])
+                seen[rows, cols] += own
         np.testing.assert_array_equal(seen, visible.astype(int))
 
     @pytest.mark.parametrize("l,counts", [(512, (3, 4, 2)),
@@ -139,7 +150,7 @@ class TestFlashCausalTiles:
         """d = 64, one block a head: more than one sub-tile visited, at
         least one skipped and one masked."""
         _flash_vs_dense(*_qkv(l=l, h=2, d=64, b=1, seed=l), causal=True)
-        assert pallas_ops.TILE_COUNTS == {(l, l, True): counts}
+        assert pallas_ops.TILE_COUNTS == {(l, l, True, None): counts}
 
     @pytest.mark.parametrize("lq,lk,counts", [
         (512, 1024, (3, 8, 2)),      # keys past the last query: dk = dv = 0
@@ -148,11 +159,11 @@ class TestFlashCausalTiles:
         q, _, _ = _qkv(l=lq, h=2, d=64, b=1, seed=lq)
         _, k, v = _qkv(l=lk, h=2, d=64, b=1, seed=lk + 1)
         _flash_vs_dense(q, k, v, causal=True)
-        assert pallas_ops.TILE_COUNTS == {(lq, lk, True): counts}
+        assert pallas_ops.TILE_COUNTS == {(lq, lk, True, None): counts}
 
     def test_not_causal_visits_every_tile_unmasked(self, pallas_ops):
         _flash_vs_dense(*_qkv(l=512, h=2, d=64, b=1, seed=5), causal=False)
-        assert pallas_ops.TILE_COUNTS == {(512, 512, False): (4, 4, 0)}
+        assert pallas_ops.TILE_COUNTS == {(512, 512, False, None): (4, 4, 0)}
 
     @pytest.mark.parametrize("lq,lk,counts", [
         (512, 512, (10, 16, 4)),     # lq = 2 * BQ: grid skip + tile skip
@@ -166,7 +177,7 @@ class TestFlashCausalTiles:
         q, _, _ = _qkv(l=lq, h=2, d=64, b=1, seed=lq)
         _, k, v = _qkv(l=lk, h=2, d=64, b=1, seed=lk + 1)
         _flash_vs_dense(q, k, v, causal=True)
-        assert pallas_ops.TILE_COUNTS == {(lq, lk, True): counts}
+        assert pallas_ops.TILE_COUNTS == {(lq, lk, True, None): counts}
 
 
 class TestGPT:

@@ -124,7 +124,7 @@ class TestFlashGroupedCausalTiles:
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
         monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
         self._check(l)
-        assert pallas_ops.TILE_COUNTS == {(l, l, True): counts}
+        assert pallas_ops.TILE_COUNTS == {(l, l, True, None): counts}
 
     def test_grid_skip_and_tile_skip_together(self, monkeypatch):
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
@@ -132,7 +132,7 @@ class TestFlashGroupedCausalTiles:
                             ("TILE_COUNTS", {})):
             monkeypatch.setattr(pallas_ops, name, value)
         self._check(512)
-        assert pallas_ops.TILE_COUNTS == {(512, 512, True): (10, 16, 4)}
+        assert pallas_ops.TILE_COUNTS == {(512, 512, True, None): (10, 16, 4)}
 
 
 @pytest.fixture(scope="module")

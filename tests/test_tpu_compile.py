@@ -43,3 +43,44 @@ def test_flash_kernels_lower_for_v5e(one_chip, monkeypatch, b, lq, lk, h, kv,
     assert compiled.as_text().count("tpu_custom_call") == 3
     (visited, total, masked), = pallas_ops.TILE_COUNTS.values()
     assert (visited < total, masked > 0) == (causal, causal)
+
+
+@pytest.mark.parametrize("window,counts", [(1024, (150, 1024, 60)),
+                                           (None, (528, 1024, 32))])
+def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
+                                         counts):
+    """mellum2_train_8k's two attention calls: L = 8192, 32 query and 4
+    key-value heads of width 128, the window of 1024 or none; the first
+    calls through the blocks that carry their softmax state."""
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    loss = lambda q, k, v: pallas_ops._flash(q, k, v, True, window).astype(
+        jnp.float32).sum()
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        sds(1, 8192, 32, 128), sds(1, 8192, 4, 128),
+        sds(1, 8192, 4, 128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert pallas_ops.TILE_COUNTS == {(8192, 8192, True, window): counts}
+
+
+def test_grouped_expert_products_lower_for_v5e(one_chip, monkeypatch):
+    """The routed layer's three products and their gradients at the
+    published widths, over the worst-case 65,536 rows: 2 forward products
+    that the gradient needs, 3 for the rows' gradient, 3 for the
+    weights'."""
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops.grouped_matmul import grouped_matmul
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    sds = lambda *s, d=jnp.bfloat16: jax.ShapeDtypeStruct(s, d,
+                                                          sharding=one_chip)
+
+    def ffn(x, w1, w3, w2, sizes):
+        gate = grouped_matmul(x, w1, sizes)
+        up = grouped_matmul(x, w3, sizes)
+        return grouped_matmul(jax.nn.silu(gate) * up, w2, sizes).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(ffn, (0, 1, 2, 3))).lower(
+        sds(65536, 2304), sds(16, 2304, 896), sds(16, 2304, 896),
+        sds(16, 896, 2304), sds(16, d=jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 8

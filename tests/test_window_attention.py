@@ -1,0 +1,157 @@
+"""Window and full attention mixed in the flash kernels (ISSUE 26): the
+windowed kernels against ``dot_product_attention`` with the explicit mask
+(interpret mode), the tile plan of the benchmark's L = 8192 / window 1024
+call against a count by hand, and the YaRN frequencies against the
+formula."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
+from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops.attention import (
+    attend, causal_mask, dot_product_attention, rope, rope_frequencies)
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """256-wide grid blocks of 128-wide sub-tiles, so that a 512-position
+    call has grid blocks to skip and sub-tiles to mask."""
+    monkeypatch.setattr(pallas_ops, "BQ", 256)
+    monkeypatch.setattr(pallas_ops, "BK", 256)
+    monkeypatch.setattr(pallas_ops, "TILE", 128)
+    monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+    return pallas_ops
+
+
+def _qkv(l, h, kv, d, seed):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (1, l, h, d), jnp.float32),
+            jax.random.normal(ks[1], (1, l, kv, d), jnp.float32),
+            jax.random.normal(ks[2], (1, l, kv, d), jnp.float32))
+
+
+class TestWindowedFlash:
+    @pytest.mark.parametrize("l,window,counts", [
+        (512, 200, (9, 16, 9)),      # edge inside a sub-tile
+        (512, 256, (9, 16, 6)),      # edge on a sub-tile boundary
+        (512, 300, (10, 16, 7)),     # edge across grid blocks
+        (512, 16, (7, 16, 7)),       # both edges in the diagonal's sub-tile
+        (768, 512, (20, 36, 8)),    # three blocks a side, one skipped
+    ])
+    def test_forward_and_gradients_match_dense(self, small_blocks, l, window,
+                                               counts):
+        """GQA 8:1 at head width 128: forward, dq and the grouped dk / dv
+        against the dense path with the explicit mask."""
+        q, k, v = _qkv(l, 8, 1, 128, seed=window)
+        flash = lambda q, k, v: pallas_ops.flash_attention(
+            q, k, v, causal=True, window=window)
+        dense = lambda q, k, v: dot_product_attention(
+            q, k, v, mask=causal_mask(l, l, window=window))
+        both = lambda fn: jax.jit(lambda *a: (fn(*a), jax.grad(
+            lambda *b: (fn(*b) ** 2).sum(), argnums=(0, 1, 2))(*a)))
+        (of, gf), (od, gd) = both(flash)(q, k, v), both(dense)(q, k, v)
+        np.testing.assert_allclose(of, od, atol=2e-5)
+        for a, b in zip(gf, gd):
+            np.testing.assert_allclose(a, b, atol=2e-4)
+        assert small_blocks.TILE_COUNTS == {(l, l, True, window): counts}
+
+    def test_window_of_the_whole_sequence_is_the_causal_call(self,
+                                                             small_blocks):
+        q, k, v = _qkv(256, 2, 2, 64, seed=3)
+        a = pallas_ops.flash_attention(q, k, v, causal=True, window=256)
+        b = pallas_ops.flash_attention(q, k, v, causal=True)
+        np.testing.assert_array_equal(a, b)
+
+    def test_window_needs_causal(self):
+        q, k, v = _qkv(128, 2, 2, 64, seed=4)
+        with pytest.raises(ValueError, match="causal"):
+            pallas_ops.flash_attention(q, k, v, window=16)
+        with pytest.raises(NotImplementedError, match="window"):
+            attend(q, k, v, impl="ring", axis_name="seq", causal=True,
+                   window=16)
+
+    def test_dense_window_mask_counts_the_position_itself(self):
+        m = np.asarray(causal_mask(6, 6, window=2))
+        assert m.sum(1).tolist() == [1, 2, 2, 2, 2, 2]
+        assert m[3].tolist() == [False, False, True, True, False, False]
+
+    def test_tile_counts_of_the_benchmark_call_by_hand(self, monkeypatch):
+        """L = 8192, window 1024, 1024-wide blocks of 256-wide sub-tiles.
+        By hand: 8 blocks on the diagonal, each 10 of 16 sub-tiles (4 on
+        the diagonal masked); 7 blocks one under it, which the window's
+        edge crosses from corner to corner: the 6 sub-tiles above the
+        block's own diagonal whole, the 4 on it masked; everything further
+        down is behind the window.  15 x 10 = 150 of 32 x 32 visited, 15 x
+        4 = 60 masked."""
+        monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+        pallas_ops._log_tiles(8192, 8192, 1024, 1024, True, 1024)
+        assert pallas_ops.TILE_COUNTS == {
+            (8192, 8192, True, 1024): (150, 1024, 60)}
+        assert pallas_ops.tiles_line((8192, 8192, True, 1024)) == (
+            "flash tiles L=8192 causal window 1024: visited 150/1024, "
+            "masked 60")
+        pallas_ops._log_tiles(8192, 8192, 1024, 1024, True, None)
+        assert pallas_ops.TILE_COUNTS[(8192, 8192, True, None)] == (
+            528, 1024, 32)
+
+
+class TestYarn:
+    THETA, DIM = 500000.0, 128
+    YARN = (16.0, 8192, 32.0, 1.0, 1.2772588722239782)
+
+    def _by_formula(self, n):
+        inv = self.THETA ** (-2 * n / self.DIM)
+        d = lambda b: (self.DIM * math.log(8192 / (2 * math.pi * b))
+                       / (2 * math.log(self.THETA)))
+        low, high = math.floor(d(32.0)), math.ceil(d(1.0))
+        ramp = min(max((n - low) / (high - low), 0.0), 1.0)
+        return inv / 16.0 * ramp + inv * (1 - ramp)
+
+    @pytest.mark.parametrize("n", [0, 25, 63])
+    def test_frequencies_against_the_formula(self, n):
+        """One dimension that keeps its frequency, one on the ramp, one
+        interpolated by the whole factor."""
+        inv, scale = rope_frequencies(self.DIM, self.THETA, self.YARN)
+        plain, one = rope_frequencies(self.DIM, self.THETA)
+        np.testing.assert_allclose(inv[n], self._by_formula(n), rtol=1e-6)
+        assert (scale, one) == (self.YARN[-1], 1.0)
+        ratio = float(inv[n] / plain[n])
+        assert {0: ratio == 1.0, 25: 1 / 16 < ratio < 1.0,
+                63: abs(ratio - 1 / 16) < 1e-6}[n]
+
+    def test_rope_scales_cos_and_sin(self):
+        x = jnp.ones((1, 4, 1, self.DIM), jnp.float32)
+        pos = jnp.zeros((4,), jnp.int32)
+        np.testing.assert_allclose(rope(x, pos, self.THETA, self.YARN),
+                                   x * self.YARN[-1], rtol=1e-6)
+
+
+class TestOldCallsUnchanged:
+    """A causal call of one block and a call with no mask lower to the
+    kernels they lowered to before the window came (ISSUE 26): the jaxpr of
+    forward and both gradients, kernel bodies, grids and index maps
+    included, source locations left out, hashed.  The pins were read off
+    the parent commit (549092b) by the same code; a PR that changes these
+    kernels on purpose reads new ones."""
+
+    @pytest.mark.parametrize("shape,causal,pin", [
+        ((4, 1024, 12, 12, 64), True, "1fea3930ac522bc2"),    # gpt2_small
+        ((16, 512, 12, 12, 64), False, "9504b7eee0c4fe0c"),   # bert_base
+    ])
+    def test_jaxpr_hash(self, monkeypatch, shape, causal, pin):
+        import hashlib
+        import re
+        monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+        b, l, h, kv, d = shape
+        loss = lambda q, k, v: pallas_ops.flash_attention(
+            q, k, v, causal=causal).astype(jnp.float32).sum()
+        sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+            sds(b, l, h, d), sds(b, l, kv, d), sds(b, l, kv, d)))
+        text = re.sub(r"/\S*pallas_ops\.py\S*", "", re.sub(r" at /[^\s\]]*",
+                                                          "", text))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == pin
