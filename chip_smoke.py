@@ -179,12 +179,17 @@ def check_flash_kernels() -> None:
         raise AssertionError(
             f"flash fell back to dense: {pallas_ops._FALLBACK_LOGGED}")
     say_flash_tiles()
+    grid = pallas_ops.GRID_COUNTS[(8192, 8192, True, 1024)]
+    if grid != ((16, 15), (16, 15), 64):
+        raise AssertionError(f"the window call's grid does not follow its "
+                             f"window: {grid}")
 
 
 def say_flash_tiles() -> None:
     """The kernels' tile registry: a causal shape of more than one sub-tile
     must skip some and mask some, any other shape must visit them all
-    unmasked."""
+    unmasked; every block that holds work is a grid step of forward / dq
+    and of dkv."""
     from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import (
         pallas_ops,
     )
@@ -192,8 +197,10 @@ def say_flash_tiles() -> None:
             pallas_ops.TILE_COUNTS.items(), key=str):
         causal = key[2]
         say(pallas_ops.tiles_line(key))
+        (walked, work), (walked_t, work_t), _ = pallas_ops.GRID_COUNTS[key]
         if ((visited < total) != (causal and total > 1)
-                or (masked > 0) != causal):
+                or (masked > 0) != causal
+                or not work == work_t <= min(walked, walked_t)):
             raise AssertionError(f"wrong for this shape: "
                                  f"{pallas_ops.tiles_line(key)}")
 

@@ -5,7 +5,12 @@ instead of the O(L^2) score matrix, the standard flash construction mapped
 onto the MXU/VMEM model.  The K/V loop is the innermost GRID dimension
 (not an in-kernel ``fori_loop``), so Pallas double-buffers the K/V block
 HBM->VMEM copies against compute; the online-softmax state (m, l, acc)
-lives in VMEM scratch and persists across that grid dimension.  Matmul
+lives in VMEM scratch and persists across that grid dimension.  That
+dimension spans the key blocks one query block's rows of the mask can
+need, not all of them (``_walks``): step ``jj`` of query block ``i``
+stands for key block ``first(i) + jj``, so a window-1024 call at
+L = 8192 walks 2 steps a query block where the sequence has 8 key
+blocks (the dK/dV kernel the same down a key block's column).  Matmul
 inputs stay in the incoming dtype (bf16 on TPU) with float32 MXU
 accumulation — casting inputs to f32 first would halve MXU throughput.
 
@@ -17,7 +22,9 @@ two passes — a dQ kernel (K/V innermost) and a dK/dV kernel (Q innermost)
 — so training never materializes an L x L score matrix either.
 
 Causal calls do work only on the causal triangle: grid blocks above the
-diagonal are skipped, and a block the diagonal crosses is walked in
+diagonal are skipped (where a window leaves fewer blocks a row than the
+sequence has, they are not grid steps at all), and a block the diagonal
+crosses is walked in
 ``TILE``-wide sub-tiles inside the kernel (``_visit``), of which those
 above the diagonal are not visited and only those on it are masked.
 
@@ -31,10 +38,11 @@ these kernels are part of the TPU-first performance layer.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -192,25 +200,30 @@ def _inside(off, bq, bk, window):
     return below if window is None else below & (off < window - bq + 1)
 
 
-def _visit(i, j, *, ni, nk, bq, bk, causal, body, by_key=False, window=None):
+def _visit(i, j, *, ni, nk, bq, bk, causal, body, by_key=False, window=None,
+           live=None):
     """Run ``body(major, pieces)`` over the part of grid block (i, j) that
     the mask leaves.  Not causal, or wholly inside the mask: the block in
     one unmasked piece.  Crossed by the diagonal or by the window's lower
     edge: sub-tiled, unrolled from the static geometry.  Wholly above the
-    one or under the other: nothing."""
+    one or under the other: nothing.  ``live`` (traced; ``_walks``) is
+    false on a grid step past its row's or column's last block, whose
+    (i, j) names no block and whose offset may still read as a crossing
+    one: nothing there either."""
     whole = lambda: body(ALL, [(ALL, None, None)])
     if not causal:
         return whole()
     static = ni * nk == 1       # the one block: i = j = 0, nothing to test
     off = i * bq - j * bk
+    when = pl.when if live is None else lambda hit: pl.when(live & hit)
     if not static and (window is None or window > bq + bk - 2):
-        pl.when(_inside(off, bq, bk, window))(whole)
+        when(_inside(off, bq, bk, window))(whole)
     for o in _crossing_offsets(ni, nk, bq, bk, window):
         def crossed(o=o):
             for major, pieces in _block_groups(bq, bk, *_tiles(bq, bk), o,
                                                by_key, window):
                 body(major, pieces)
-        crossed() if static else pl.when(off == o)(crossed)
+        crossed() if static else when(off == o)(crossed)
 
 
 def _scores(q, k, scale, hi, lo=None):
@@ -243,8 +256,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
     # (sequence, head_dim) pair, not (head, head_dim).  ``state`` is the
     # (m, l, acc) scratch that carries the online softmax across the K/V
     # grid dimension; a ``_one_block`` call has none
+    keys, _ = _walks(ni, nk, bq, bk, causal, window)
     i = pl.program_id(2)
-    j = pl.program_id(3)
+    jj = pl.program_id(3)
+    j, live = keys.block(i, jj)
 
     def _out(rows, m, l, acc):
         o_ref[0, 0, rows, :] = (acc / l).astype(o_ref.dtype)
@@ -258,7 +273,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
     if state:
         m_ref, l_ref, acc_ref = state
 
-        @pl.when(j == 0)
+        @pl.when(jj == 0)
         def _init():
             m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
             l_ref[...] = jnp.zeros_like(l_ref)
@@ -267,9 +282,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
     def _step(rows, pieces):
         # one online-softmax update of the query rows over all their key
         # pieces at once (bf16 operands into the MXU, f32 from there on).
-        # j == 0 always holds key 0, which every query attends, so the
-        # running max is real from the first processed block on.  Under a
-        # window a row may meet only hidden keys first (NEG_INF, finite):
+        # Without a window the first block holds key 0, which every query
+        # attends, so the running max is real from the first processed
+        # block on.  Under a window a row may meet only hidden keys first
+        # (NEG_INF, finite):
         # what it gathers there is wiped by ``corr`` at its first real key,
         # and its own position is one
         if not pieces:
@@ -298,10 +314,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
         m_ref[rows, :] = m
 
     _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
-           window=window)
+           window=window, live=live)
 
     if state:
-        @pl.when(j == nk - 1)
+        @pl.when(jj == keys.span - 1)
         def _finish():
             _out(ALL, m_ref[...], l_ref[...], acc_ref[...])
 
@@ -318,28 +334,76 @@ def _fwd_kernel_nolse(q_ref, k_ref, v_ref, o_ref, *state, **kw):
     _flash_kernel(q_ref, k_ref, v_ref, o_ref, None, *state, **kw)
 
 
-def _needed_blocks(ni, nk, bq, bk, causal, window):
-    """``(key_block(i, j), query_block(i, j))`` for the block specs' index
-    maps: the block a grid step loads.  A step that the mask skips loads
-    the nearest block its row (its column) needs, which is the one already
-    there or the next one wanted, so a skipped step moves no data.  One
-    block, or no mask: the step's own."""
+def _key_bounds(i, nk, bq, bk, window, xp=jnp):
+    """First and last key block that query block ``i``'s rows of the causal
+    mask reach (``xp=np``: a whole ``arange`` of blocks at trace time)."""
+    last = xp.minimum((i * bq + bq - 1) // bk, nk - 1)
+    first = 0 if window is None else xp.maximum(
+        (i * bq - window + 1) // bk, 0)
+    return first, last
+
+
+def _query_bounds(j, ni, bq, bk, window, xp=jnp):
+    """First and last query block that reaches key block ``j``."""
+    first = xp.minimum((j * bk) // bq, ni - 1)
+    last = ni - 1 if window is None else xp.minimum(
+        (j * bk + bk + window - 2) // bq, ni - 1)
+    return first, last
+
+
+class _Walk(NamedTuple):
+    """One kernel's innermost grid dimension.  ``span``: its extent.
+    ``block(major, step)``: for the kernel body, the block that grid step
+    stands for and whether it is one its row (its column) has (``None``:
+    every step is).  ``loaded(major, step)``: for the block specs' index
+    maps, the block the step loads; a step that the mask skips loads the
+    nearest block its row (its column) needs, which is the one already
+    there or the next one wanted, so a skipped step moves no data.
+    ``held``: per row (column) the blocks its steps stand for, static, for
+    the counter."""
+    span: int
+    block: Callable
+    loaded: Callable
+    held: list
+
+
+def _walk(n, n_major, bounds):
+    """The walk along ``n`` blocks for each of ``n_major`` rows (columns)
+    with ``bounds(major) -> (first, last)``: as many steps as the longest
+    run ``first..last`` has, step ``s`` standing for block ``first + s``.
+    Where some row needs all ``n`` the steps are the blocks themselves."""
+    runs = list(zip(*(np.broadcast_to(x, (n_major,)).tolist()
+                      for x in bounds(np.arange(n_major), xp=np))))
+    span = max(1 + max(b - a for a, b in runs), 1)
+    whole = span == n
+    held = [range(0 if whole else a, min(a + span, b + 1)) for a, b in runs]
+
+    def block(major, step):
+        if whole:
+            return step, None
+        first, last = bounds(major)
+        return first + step, first + step <= last
+
+    def loaded(major, step):
+        first, last = bounds(major)
+        return jnp.clip(step if whole else first + step, first, last)
+
+    return _Walk(span, block, loaded, held)
+
+
+def _walks(ni, nk, bq, bk, causal, window):
+    """``(keys, queries)``: the walk over key blocks for a query block
+    (forward and dQ, grid ``(b, h, ni, keys.span)``) and over query blocks
+    for a key block (dK/dV, ``queries.span`` steps a group member).  One
+    block, or no mask: every step its own block."""
     if not causal or ni * nk == 1:
-        return (lambda i, j: j), (lambda i, j: i)
-
-    def key_block(i, j):
-        last = jnp.minimum((i * bq + bq - 1) // bk, nk - 1)
-        first = 0 if window is None else jnp.maximum(
-            (i * bq - window + 1) // bk, 0)
-        return jnp.clip(j, first, last)
-
-    def query_block(i, j):
-        first = jnp.minimum((j * bk) // bq, ni - 1)
-        last = ni - 1 if window is None else jnp.minimum(
-            (j * bk + bk + window - 2) // bq, ni - 1)
-        return jnp.clip(i, first, last)
-
-    return key_block, query_block
+        own = lambda major, step: (step, None), lambda major, step: step
+        return (_Walk(nk, *own, [range(nk)] * ni),
+                _Walk(ni, *own, [range(ni)] * nk))
+    return (_walk(nk, ni, functools.partial(_key_bounds, nk=nk, bq=bq, bk=bk,
+                                            window=window)),
+            _walk(ni, nk, functools.partial(_query_bounds, ni=ni, bq=bq,
+                                            bk=bk, window=window)))
 
 
 def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
@@ -354,16 +418,17 @@ def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
     rep = h // k.shape[2]
     bq, bk = _block_size(lq, BQ), _block_size(lk, BK)
     scale = 1.0 / (d ** 0.5)
-    grid = (b, h, lq // bq, lk // bk)
+    ni, nk = lq // bq, lk // bk
+    keys, _ = _walks(ni, nk, bq, bk, causal, window)
+    grid = (b, h, ni, keys.span)
     _log_tiles(lq, lk, bq, bk, causal, window)
-    key_block, _ = _needed_blocks(lq // bq, lk // bk, bq, bk, causal, window)
     # [B, L, H, D] -> [B, H, L, D]: the kernel tiles over (seq, head_dim)
     qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     # under shard_map's varying-manual-axes typing the out aval must carry
     # the same mesh-varying set as the inputs
     vma = jax.typeof(qt).vma
-    kw = dict(scale=scale, ni=lq // bq, nk=lk // bk, bq=bq, bk=bk,
-              causal=causal, window=window)
+    kw = dict(scale=scale, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal,
+              window=window)
     kernel = (functools.partial(_flash_kernel, **kw) if with_lse
               else functools.partial(_fwd_kernel_nolse, **kw))
     o_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0),
@@ -384,15 +449,15 @@ def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
             o_spec,
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h_, i, j: (b_, h_ // rep,
-                                               key_block(i, j), 0),
+                                               keys.loaded(i, j), 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h_, i, j: (b_, h_ // rep,
-                                               key_block(i, j), 0),
+                                               keys.loaded(i, j), 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=out_specs,
-        scratch_shapes=[] if _one_block(causal, *grid[2:]) else [
+        scratch_shapes=[] if _one_block(causal, ni, nk) else [
             pltpu.VMEM((bq, 1), jnp.float32),    # running max m
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom l
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
@@ -418,16 +483,19 @@ def _p_ds(q, k, v, do, lse, delta, scale, hi, lo):
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                    *acc, scale: float, ni: int, nk: int, bq: int, bk: int,
                    causal: bool, window: Optional[int] = None):
-    """dQ pass: grid (b, h, iq, jk), K/V innermost; accumulates
+    """dQ pass: grid (b, h, iq, jk), K/V innermost (the key blocks query
+    block iq can need: ``_walks``); accumulates
     dq_i = sum_j ds_ij k_j with ds = p * (do v^T - delta) * scale, in the
     f32 scratch ``acc`` (a ``_one_block`` call has none)."""
+    keys, _ = _walks(ni, nk, bq, bk, causal, window)
     i = pl.program_id(2)
-    j = pl.program_id(3)
+    jj = pl.program_id(3)
+    j, live = keys.block(i, jj)
 
     if acc:
         acc_ref, = acc
 
-        @pl.when(j == 0)
+        @pl.when(jj == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -451,10 +519,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
 
     _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
-           window=window)
+           window=window, live=live)
 
     if acc:
-        @pl.when(j == nk - 1)
+        @pl.when(jj == keys.span - 1)
         def _finish():
             dq_ref[0, 0, :, :] = acc_ref[...].astype(dq_ref.dtype)
 
@@ -468,13 +536,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     ``acc`` (a ``_one_block`` call has none).
 
     Grouped-query attention folds the ``rep`` query heads sharing each
-    K/V head into the innermost grid dim: it = member * ni + iq (member
-    slow, Q block fast); the dk/dv accumulators run over all of it, so the
+    K/V head into the innermost grid dim: it = member * nqw + iq (member
+    slow, Q block fast, ``nqw`` the query blocks a key block can need:
+    ``_walks``); the dk/dv accumulators run over all of it, so the
     grouped dk/dv gradients come out summed over their query group without
     ever materializing per-query-head dk/dv."""
+    _, queries = _walks(ni, nk, bq, bk, causal, window)
     j = pl.program_id(2)
     it = pl.program_id(3)
-    i = it % ni if rep > 1 else it
+    i, live = queries.block(j, it % queries.span if rep > 1 else it)
 
     if acc:
         dk_acc, dv_acc = acc
@@ -510,10 +580,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
 
     _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
-           by_key=True, window=window)
+           by_key=True, window=window, live=live)
 
     if acc:
-        @pl.when(it == ni * rep - 1)
+        @pl.when(it == queries.span * rep - 1)
         def _finish():
             dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
@@ -536,21 +606,22 @@ def _flash_backward(q, k, v, o, lse, g, causal, window=None):
                        ot.astype(jnp.float32))
     delta = jnp.broadcast_to(delta[..., None], (b, h, lq, LANES))
     vma = jax.typeof(qt).vma
-    key_block, query_block = _needed_blocks(ni, nk, bq, bk, causal, window)
+    keys, queries = _walks(ni, nk, bq, bk, causal, window)
+    nqw = queries.span
     row = lambda m: pl.BlockSpec((1, 1, bq, m),
                                  lambda b_, h_, i, j: (b_, h_, i, 0),
                                  memory_space=pltpu.VMEM)
     col = lambda m: pl.BlockSpec((1, 1, bk, m),
                                  lambda b_, h_, i, j: (b_, h_ // rep,
-                                                       key_block(i, j), 0),
+                                                       keys.loaded(i, j), 0),
                                  memory_space=pltpu.VMEM)
-    # dkv grid (b, kv_head, j, it) with it = member * ni + iq: per-q-head
-    # operands map query head g * rep + it // ni; K/V-side blocks map the
+    # dkv grid (b, kv_head, j, it) with it = member * nqw + iq: per-q-head
+    # operands map query head g * rep + it // nqw; K/V-side blocks map the
     # group head directly (with rep == 1 these reduce to the plain maps)
     rowT = lambda m: pl.BlockSpec(
         (1, 1, bq, m),
-        lambda b_, g, j, it: (b_, g * rep + it // ni,
-                              query_block(it % ni, j), 0),
+        lambda b_, g, j, it: (b_, g * rep + it // nqw,
+                              queries.loaded(j, it % nqw), 0),
         memory_space=pltpu.VMEM)
     colT = lambda m: pl.BlockSpec((1, 1, bk, m),
                                   lambda b_, g, j, it: (b_, g, j, 0),
@@ -562,7 +633,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, window=None):
         functools.partial(_bwd_dq_kernel, scale=scale, ni=ni, nk=nk,
                           bq=bq, bk=bk, causal=causal, window=window),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma),
-        grid=(b, h, ni, nk),
+        grid=(b, h, ni, keys.span),
         in_specs=[row(d), col(d), col(d), row(d), row(LANES), row(LANES)],
         out_specs=row(d),
         scratch_shapes=[] if _one_block(causal, ni, nk) else [
@@ -576,7 +647,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, window=None):
                           window=window),
         out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype, vma=vma),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype, vma=vma)],
-        grid=(b, kv, nk, ni * rep),
+        grid=(b, kv, nk, nqw * rep),
         in_specs=[rowT(d), colT(d), colT(d), rowT(d), rowT(LANES),
                   rowT(LANES)],
         out_specs=[colT(d), colT(d)],
@@ -613,35 +684,54 @@ def _log_fallback(reason: str, q) -> None:
 
 
 TILE_COUNTS: dict = {}   # (lq, lk, causal, window) -> (visited, total, masked)
+# same key -> ((walked, with work), the same for dkv, blocks of the square):
+# grid steps a query head, counted as the kernels walk them
+GRID_COUNTS: dict = {}
 
 
 def _log_tiles(lq: int, lk: int, bq: int, bk: int, causal: bool,
                window: Optional[int] = None) -> None:
     """Record ONCE per call shape, at trace time, how many of the score
-    square's sub-tiles the kernels visit and how many of those they mask —
-    the counter that says the causal skip and the window's engage
-    (visited < total)."""
+    square's sub-tiles the kernels visit and how many of those they mask,
+    and how many grid steps a head they walk for the blocks that hold
+    work — the counters that say the causal skip and the window's engage
+    (visited < total; under a window, walked < the square's blocks)."""
     key = (lq, lk, causal, window)
     if key in TILE_COUNTS:
         return
     tq, tk = _tiles(bq, bk)
-    plans = [tile_plan(bq, bk, tq, tk, i * bq - j * bk, causal, window)
-             for i in range(lq // bq) for j in range(lk // bk)]
-    visited, masked = map(sum, zip(*map(tile_counts, plans)))
+    ni, nk = lq // bq, lk // bk
+    counts = {(i, j): tile_counts(tile_plan(bq, bk, tq, tk, i * bq - j * bk,
+                                            causal, window))
+              for i in range(ni) for j in range(nk)}
+    visited, masked = map(sum, zip(*counts.values()))
     TILE_COUNTS[key] = (visited, (lq // tq) * (lk // tk), masked)
+    keys, queries = _walks(ni, nk, bq, bk, causal, window)
+    # a step holds work where it stands for a block its row (column) has
+    # and the mask leaves some of that block
+    work = lambda walk, at: sum(
+        counts[at(major, block)][0] > 0
+        for major, blocks in enumerate(walk.held) for block in blocks)
+    GRID_COUNTS[key] = (
+        (ni * keys.span, work(keys, lambda i, j: (i, j))),
+        (nk * queries.span, work(queries, lambda j, i: (i, j))), ni * nk)
     import logging
     logging.getLogger(__name__).info(tiles_line(key))
 
 
 def tiles_line(key: tuple) -> str:
-    """``flash tiles L=1024 causal: visited 10/16, masked 4``; a windowed
-    shape reads ``L=8192 causal window 1024: ...``"""
+    """``flash tiles L=1024 causal: visited 10/16, masked 4; grid steps a
+    head 1 of 1 walked, 1 with work (dkv 1 of 1, 1)``; a windowed shape
+    reads ``L=8192 causal window 1024: ...``"""
     lq, lk, causal, window = key
     mask = "causal" if causal else "full"
     if window is not None:
         mask += f" window {window}"
-    return ("flash tiles L=%s %s: visited %d/%d, masked %d" % (
-        lq if lq == lk else f"{lq}x{lk}", mask, *TILE_COUNTS[key]))
+    (walked, work), (walked_t, work_t), blocks = GRID_COUNTS[key]
+    return ("flash tiles L=%s %s: visited %d/%d, masked %d; grid steps a "
+            "head %d of %d walked, %d with work (dkv %d of %d, %d)" % (
+                lq if lq == lk else f"{lq}x{lk}", mask, *TILE_COUNTS[key],
+                walked, blocks, work, walked_t, blocks, work_t))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

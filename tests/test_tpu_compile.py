@@ -5,12 +5,20 @@ show: tile alignment of the sub-tile slices, VMEM use), never a result
 and never a time.  The topology is described inside a fixture, and only
 in this file: one process at a time may load the TPU's library."""
 
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks.lib.trace import kernel_of, load_kernels  # noqa: E402
+from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -45,23 +53,35 @@ def test_flash_kernels_lower_for_v5e(one_chip, monkeypatch, b, lq, lk, h, kv,
     assert (visited < total, masked > 0) == (causal, causal)
 
 
-@pytest.mark.parametrize("window,counts", [(1024, (150, 1024, 60)),
-                                           (None, (528, 1024, 32))])
+@pytest.mark.parametrize("window,counts,grid", [
+    (1024, (150, 1024, 60), ((16, 15), (16, 15), 64)),
+    (None, (528, 1024, 32), ((64, 36), (64, 36), 64))])
 def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
-                                         counts):
+                                         counts, grid):
     """mellum2_train_8k's two attention calls: L = 8192, 32 query and 4
     key-value heads of width 128, the window of 1024 or none; the first
-    calls through the blocks that carry their softmax state."""
+    calls through the blocks that carry their softmax state, on a grid of
+    the 2 blocks a row its window leaves.  The benchmark's picker names
+    each compiled call from its text (operand count and output kind), so
+    a change of a kernel's signature fails here and not as a metric
+    missing on the chip."""
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
     monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+    monkeypatch.setattr(pallas_ops, "GRID_COUNTS", {})
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
     loss = lambda q, k, v: pallas_ops._flash(q, k, v, True, window).astype(
         jnp.float32).sum()
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
         sds(1, 8192, 32, 128), sds(1, 8192, 4, 128),
         sds(1, 8192, 4, 128)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert sorted(kernel_of(line, load_kernels()) for line in calls) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
     assert pallas_ops.TILE_COUNTS == {(8192, 8192, True, window): counts}
+    assert pallas_ops.GRID_COUNTS == {(8192, 8192, True, window): grid}
 
 
 def test_grouped_expert_products_lower_for_v5e(one_chip, monkeypatch):

@@ -2,7 +2,9 @@
 windowed kernels against ``dot_product_attention`` with the explicit mask
 (interpret mode), the tile plan of the benchmark's L = 8192 / window 1024
 call against a count by hand, and the YaRN frequencies against the
-formula."""
+formula.  The grid that walks only the blocks a window leaves (ISSUE 27):
+the same comparison where it shrinks, its step counts by hand, and the
+three ``pallas_call`` grids read out of the jaxpr."""
 
 import math
 
@@ -24,6 +26,7 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(pallas_ops, "BK", 256)
     monkeypatch.setattr(pallas_ops, "TILE", 128)
     monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+    monkeypatch.setattr(pallas_ops, "GRID_COUNTS", {})
     return pallas_ops
 
 
@@ -32,6 +35,23 @@ def _qkv(l, h, kv, d, seed):
     return (jax.random.normal(ks[0], (1, l, h, d), jnp.float32),
             jax.random.normal(ks[1], (1, l, kv, d), jnp.float32),
             jax.random.normal(ks[2], (1, l, kv, d), jnp.float32))
+
+
+def _assert_matches_dense(lq, lk, window):
+    """GQA 8:1 at head width 128: forward, dq and the grouped dk / dv of
+    the windowed kernels against the dense path with the explicit mask."""
+    q, _, _ = _qkv(lq, 8, 1, 128, seed=window)
+    _, k, v = _qkv(lk, 8, 1, 128, seed=window + (lq != lk))
+    flash = lambda q, k, v: pallas_ops.flash_attention(
+        q, k, v, causal=True, window=window)
+    dense = lambda q, k, v: dot_product_attention(
+        q, k, v, mask=causal_mask(lq, lk, window=window))
+    both = lambda fn: jax.jit(lambda *a: (fn(*a), jax.grad(
+        lambda *b: (fn(*b) ** 2).sum(), argnums=(0, 1, 2))(*a)))
+    (of, gf), (od, gd) = both(flash)(q, k, v), both(dense)(q, k, v)
+    np.testing.assert_allclose(of, od, atol=2e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(a, b, atol=2e-4)
 
 
 class TestWindowedFlash:
@@ -44,20 +64,60 @@ class TestWindowedFlash:
     ])
     def test_forward_and_gradients_match_dense(self, small_blocks, l, window,
                                                counts):
-        """GQA 8:1 at head width 128: forward, dq and the grouped dk / dv
-        against the dense path with the explicit mask."""
-        q, k, v = _qkv(l, 8, 1, 128, seed=window)
-        flash = lambda q, k, v: pallas_ops.flash_attention(
-            q, k, v, causal=True, window=window)
-        dense = lambda q, k, v: dot_product_attention(
-            q, k, v, mask=causal_mask(l, l, window=window))
-        both = lambda fn: jax.jit(lambda *a: (fn(*a), jax.grad(
-            lambda *b: (fn(*b) ** 2).sum(), argnums=(0, 1, 2))(*a)))
-        (of, gf), (od, gd) = both(flash)(q, k, v), both(dense)(q, k, v)
-        np.testing.assert_allclose(of, od, atol=2e-5)
-        for a, b in zip(gf, gd):
-            np.testing.assert_allclose(a, b, atol=2e-4)
+        _assert_matches_dense(l, l, window)
         assert small_blocks.TILE_COUNTS == {(l, l, True, window): counts}
+
+    @pytest.mark.parametrize("lq,lk,window,grid", [
+        # 256-wide blocks.  L = 1024, window 256: a row needs its own block
+        # and the one before, 2 of 4; row 0 has one, so 8 walked, 7 with work
+        (1024, 1024, 256, ((8, 7), (8, 7), 16)),
+        # window 300 reaches a third block (300 - 1 > 256): rows 0..4 have
+        # 1, 2, 3, 3, 3 blocks
+        (1280, 1280, 300, ((15, 12), (15, 12), 25)),
+        # an edge inside a sub-tile: the blocks are window 256's
+        (1024, 1024, 200, ((8, 7), (8, 7), 16)),
+        # one sub-tile of window: still the block before, for its last keys
+        (1024, 1024, 128, ((8, 7), (8, 7), 16)),
+        # more keys than queries (top-left aligned): rows 0, 1 have 1, 2 of
+        # the 4 key blocks; key block 0 has both query blocks, so each of
+        # the 4 columns walks 2, and key blocks 2 and 3 have no query
+        (512, 1024, 256, ((4, 3), (8, 3), 8)),
+    ])
+    def test_shrunk_grid_matches_dense(self, small_blocks, lq, lk, window,
+                                       grid):
+        """Where the window leaves a row fewer blocks than the sequence
+        has, the innermost grid dimension is the most any row needs: GQA
+        8:1, forward, dq and the grouped dk / dv against the dense mask,
+        and the walked / with-work grid steps a head by hand.  Every block
+        holds random data, the last key block's column and block 0's row
+        too: a step past the end of either clamps to them, and an
+        unguarded one would add them a second time."""
+        _assert_matches_dense(lq, lk, window)
+        assert small_blocks.GRID_COUNTS == {(lq, lk, True, window): grid}
+
+    def test_a_step_past_the_last_block_does_nothing(self, small_blocks):
+        """dkv at the last key block's column: with L = 1024, window 256,
+        step (j = 3, iqq = 1) names query block 4, which is not there; its
+        offset 4 x 256 - 3 x 256 = 256 is the window's crossing offset, and
+        the index map has clamped the step to query block 3.  The guard is
+        ``live``: without it dk / dv of the last 256 keys gain block 3's
+        share twice.  The same for the forward at block 0's row were its
+        offset not above the diagonal."""
+        _, queries = small_blocks._walks(4, 4, 256, 256, True, 256)
+        i, live = queries.block(jnp.int32(3), jnp.int32(1))
+        assert (int(i), bool(live)) == (4, False)
+        assert int(queries.loaded(jnp.int32(3), jnp.int32(1))) == 3
+        assert (4 * 256 - 3 * 256) in small_blocks._crossing_offsets(
+            4, 4, 256, 256, 256)
+        q, k, v = _qkv(1024, 8, 1, 128, seed=11)
+        grads = lambda fn: jax.jit(jax.grad(
+            lambda *a: (fn(*a) ** 2).sum(), argnums=(1, 2)))(q, k, v)
+        flash = grads(lambda q, k, v: pallas_ops.flash_attention(
+            q, k, v, causal=True, window=256))
+        dense = grads(lambda q, k, v: dot_product_attention(
+            q, k, v, mask=causal_mask(1024, 1024, window=256)))
+        for a, b in zip(flash, dense):
+            np.testing.assert_allclose(a[:, -256:], b[:, -256:], atol=2e-4)
 
     def test_window_of_the_whole_sequence_is_the_causal_call(self,
                                                              small_blocks):
@@ -88,15 +148,66 @@ class TestWindowedFlash:
         down is behind the window.  15 x 10 = 150 of 32 x 32 visited, 15 x
         4 = 60 masked."""
         monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+        monkeypatch.setattr(pallas_ops, "GRID_COUNTS", {})
         pallas_ops._log_tiles(8192, 8192, 1024, 1024, True, 1024)
         assert pallas_ops.TILE_COUNTS == {
             (8192, 8192, True, 1024): (150, 1024, 60)}
+        # the grid: a query block needs its own key block and the one
+        # before, 2 steps a row for the sequence's 8; block 0's row has no
+        # block before it, so 8 x 2 = 16 walked of 64 and 15 with work; the
+        # same down the columns, where the last key block has no query
+        # block after it
+        assert pallas_ops.GRID_COUNTS == {
+            (8192, 8192, True, 1024): ((16, 15), (16, 15), 64)}
         assert pallas_ops.tiles_line((8192, 8192, True, 1024)) == (
             "flash tiles L=8192 causal window 1024: visited 150/1024, "
-            "masked 60")
+            "masked 60; grid steps a head 16 of 64 walked, 15 with work "
+            "(dkv 16 of 64, 15)")
         pallas_ops._log_tiles(8192, 8192, 1024, 1024, True, None)
         assert pallas_ops.TILE_COUNTS[(8192, 8192, True, None)] == (
             528, 1024, 32)
+        # no window: the last row needs every block, the triangle cannot be
+        # made a rectangle: 64 walked, 8 x 9 / 2 = 36 with work
+        assert pallas_ops.GRID_COUNTS[(8192, 8192, True, None)] == (
+            (64, 36), (64, 36), 64)
+        assert pallas_ops.tiles_line((8192, 8192, True, None)).endswith(
+            "grid steps a head 64 of 64 walked, 36 with work "
+            "(dkv 64 of 64, 36)")
+
+
+def _pallas_grids(jaxpr) -> dict:
+    """``{kernel name: grid}`` of every ``pallas_call`` in a jaxpr, the
+    nested ones too."""
+    grids = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids[eqn.params["name"]] = tuple(
+                eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids.update(_pallas_grids(sub))
+    return grids
+
+
+class TestGridOfTheBenchmarkCall:
+    @pytest.mark.parametrize("window,grids", [
+        (1024, {"flash_fwd": (1, 32, 8, 2), "flash_dq": (1, 32, 8, 2),
+                "flash_dkv": (1, 4, 8, 16)}),
+        (None, {"flash_fwd": (1, 32, 8, 8), "flash_dq": (1, 32, 8, 8),
+                "flash_dkv": (1, 4, 8, 64)}),
+    ])
+    def test_grids_in_the_jaxpr(self, monkeypatch, window, grids):
+        """mellum2_train_8k's two attention calls, (1, 8192, 32 / 4, 128):
+        under the window of 1024 the innermost grid dimension is 2 key
+        blocks a query block (dkv: 2 query blocks for each of the 8 group
+        members), with no window the whole 8 (8 x 8)."""
+        monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+        loss = lambda q, k, v: pallas_ops.flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32).sum()
+        sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        closed = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+            sds(1, 8192, 32, 128), sds(1, 8192, 4, 128),
+            sds(1, 8192, 4, 128))
+        assert _pallas_grids(closed.jaxpr) == grids
 
 
 class TestYarn:
@@ -136,11 +247,15 @@ class TestOldCallsUnchanged:
     forward and both gradients, kernel bodies, grids and index maps
     included, source locations left out, hashed.  The pins were read off
     the parent commit (549092b) by the same code; a PR that changes these
-    kernels on purpose reads new ones."""
+    kernels on purpose reads new ones.  A causal call of many blocks with
+    no window lowers to what it lowered to before the grid followed the
+    window (ISSUE 27): its pin was read off that parent (f6f7aa9)."""
 
     @pytest.mark.parametrize("shape,causal,pin", [
         ((4, 1024, 12, 12, 64), True, "1fea3930ac522bc2"),    # gpt2_small
         ((16, 512, 12, 12, 64), False, "9504b7eee0c4fe0c"),   # bert_base
+        # mellum2_12b_a2p5b's full layer
+        ((1, 8192, 32, 4, 128), True, "bb8152b91081eff4"),
     ])
     def test_jaxpr_hash(self, monkeypatch, shape, causal, pin):
         import hashlib
