@@ -159,12 +159,12 @@ class CheckpointEngine:
     """Per-run checkpoint engine: sweeps stale leftovers on open, then
     serves off-critical-path sharded saves and every-process pruning.
 
-    ``async_write=False`` runs the identical write path inline (the A/B
-    twin for bench and tests).  ``timing`` dicts passed to ``save`` get
-    ``ckpt_snapshot_ms`` filled synchronously and ``ckpt_write_ms`` when
-    the (possibly background) write lands — the driver threads its
-    per-round ``round_timings`` entry through so stall vs hidden wall is
-    attributed per round."""
+    ``async_write=False`` runs the identical write path inline (the
+    blocking twin of tests/test_checkpoint.py).  ``timing`` dicts
+    passed to ``save`` get ``ckpt_snapshot_ms`` filled synchronously and
+    ``ckpt_write_ms`` when the (possibly background) write lands — the
+    driver threads its per-round ``round_timings`` entry through so stall
+    vs hidden wall is attributed per round."""
 
     def __init__(self, ckpt_dir: str, keep: int = 3,
                  async_write: bool = True,
@@ -1063,8 +1063,8 @@ def save_checkpoint(ckpt_dir: str, state, global_epoch: int,
 
 def save_checkpoint_legacy(ckpt_dir: str, state, global_epoch: int) -> str:
     """The pre-engine blocking save (format 1): gather the FULL state to
-    every host, serialize one msgpack inline.  Kept as the bench A/B twin
-    and to manufacture legacy checkpoints for the back-compat tests."""
+    every host, serialize one msgpack inline.  Kept to manufacture legacy
+    checkpoints for the back-compat tests (tests/test_checkpoint.py)."""
     state = _strip_buddy(state)
     if jax.process_count() > 1:
         from jax.experimental import multihost_utils

@@ -1047,7 +1047,7 @@ def make_resident_gather(mesh, per_worker_template: PyTree, *,
                          bucket_bytes: int = DEFAULT_BUCKET_BYTES,
                          donate: bool = False):
     """Jitted stand-alone round-entry gather over a worker-stacked
-    resident layout (tests / bench A/Bs): takes ``{bucket:
+    resident layout (tests/test_param_residency.py): takes ``{bucket:
     [n, padded // n]}`` and returns the worker-stacked full tree
     ([n, ...] leaves).  ``donate=True`` donates the resident input —
     the engine's enter program shape."""
@@ -1664,7 +1664,7 @@ def gossip_sync(tree: PyTree, *, topology: str, how: str = "equal",
 # gossip-blended consensus back to every worker of the slice.  DCN bytes
 # per round per worker: hops x padded/W x outer_wire_itemsize per bucket
 # — exactly 1/N_inner of what a flat gossip over the full tree would
-# move (asserted in tests/test_sync.py and bench --entry hier).
+# move (asserted in tests/test_hier_sync.py::TestHierWireBytes).
 #
 # Semantics ("gossip of means"): g_s = gossip_blend(m_s, m_{s-1}[, m_{s-2}])
 # where m_s is slice s's equal mean.  ``equal`` output is g_s for every
@@ -2000,7 +2000,7 @@ def make_hier_host_sync(mesh, *, topology: str, how: str = "equal",
                         bucket_bytes: int = DEFAULT_BUCKET_BYTES,
                         residency: str = "replicated"):
     """Jitted stand-alone hierarchical round sync over worker-stacked
-    pytrees (tests / bench A/Bs) — the two-level twin of
+    pytrees (tests/test_hier_sync.py) — the two-level twin of
     ``make_host_sync``.  Leaves carry a leading worker axis of size
     S x W sharded over ``(slice, data)`` (slice-major rows).  Returns
     ``run(tree, residual=None, outer_residual=None)`` ->
@@ -2064,8 +2064,8 @@ def make_host_sync(mesh, *, mode: str = "sharded", how: str = "equal",
                    screen: bool = False):
     """Jitted stand-alone round sync over worker-stacked pytrees.
 
-    The sync-engine twin of ``make_host_aggregator`` (tests, bench A/Bs,
-    federated checkpoint averaging): takes worker-stacked pytrees
+    The sync-engine twin of ``make_host_aggregator`` (tests, federated
+    checkpoint averaging): takes worker-stacked pytrees
     ([N, ...] leaves over the mesh's data axis) plus an optional residual
     pytree of the same structure, and returns ``(synced, new_residual)``.
     ``mode="dense"`` routes through ``aggregate`` (per-leaf, any

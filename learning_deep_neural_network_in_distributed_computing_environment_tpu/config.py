@@ -74,9 +74,10 @@ class Config:
     # Async checkpoint engine (ISSUE 5): True = the round loop pays only
     # the device->host snapshot and a background thread serializes,
     # checksums, fsyncs, and manifest-commits the per-process shards;
-    # False = the identical sharded write path runs inline (debugging /
-    # A-B benches).  Either way the save is gather-free and atomic (an
-    # epoch without its MANIFEST.json is never restored from).
+    # False = the identical sharded write path runs inline (debugging;
+    # the blocking twin of tests/test_checkpoint.py).  Either way the
+    # save is gather-free and atomic (an epoch without its MANIFEST.json
+    # is never restored from).
     ckpt_async: bool = True
     ckpt_keep: int = 3            # committed checkpoints retained by prune
     resume: bool = False
@@ -162,7 +163,8 @@ class Config:
     # host while the device computes — the between-round host gap hides
     # behind device time.  The straggler EMA consumes measured walls one
     # round delayed in BOTH modes, so overlapped and serial runs produce
-    # identical results (False = fully serial, for debugging/benchmarks).
+    # identical results (False = fully serial: debugging, and the twin
+    # tests/test_overlap.py compares against).
     overlap_rounds: bool = True
     # Semi-synchronous rounds (ISSUE 16): K > 0 dispatches round R+1's
     # local phase immediately off the PRE-sync params while round R's
@@ -238,13 +240,13 @@ class Config:
     # round-optimizer moments sharded over the worker axis (per-worker
     # state and apply FLOPs drop N-fold; only post-update weights ride the
     # all_gather home); "replicated" is the post-gather full-size twin
-    # (every worker applies the whole update — the A/B gate + bench
-    # baseline); "auto" = sharded whenever the bucketed sharded sync
-    # engine is active.  Ring/double-ring gossip resolves to "local":
-    # gossip blends are worker-specific by construction (no global
-    # reduce), so there is no cross-replica-redundant apply to shard —
-    # see docs/ARCHITECTURE.md.  In fp32 the two placements are
-    # bit-identical (tests/test_opt_placement.py).
+    # (every worker applies the whole update); "auto" = sharded whenever
+    # the bucketed sharded sync engine is active.  Ring/double-ring
+    # gossip resolves to "local": gossip blends are worker-specific by
+    # construction (no global reduce), so there is no
+    # cross-replica-redundant apply to shard — see docs/ARCHITECTURE.md.
+    # In fp32 the two placements are bit-identical
+    # (tests/test_opt_placement.py).
     opt_placement: str = "auto"      # auto | replicated | sharded
     # --- scatter-resident consensus params (ISSUE 11) ----------------------
     # param_residency: where the consensus parameter tree LIVES between

@@ -1662,8 +1662,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 if t_ready is not None:
                     # host time the device sat idle between the previous
                     # round finishing and this round's dispatch — the
-                    # round gap the overlap exists to close (bench.py
-                    # round_gap entry)
+                    # round gap the overlap exists to close
                     results["round_timings"][-2]["gap_ms"] = round(
                         (t_disp - t_ready) * 1e3, 3)
                 # stage_ms is the DISPATCH span: the packs were staged in

@@ -150,10 +150,10 @@ def memory_report(programs: dict, *, state_bytes: dict | None = None,
       executable (``programs``: name -> TrackedProgram).  ``temp_bytes``
       is where a remat policy shows up — saved activations are XLA temp
       allocations, so ``none >= dots_saveable >= save_names:<set> >=
-      everything`` is an asserted ordering (bench ``--entry memory``),
-      not a narrative.  A program with no compiled executable (a
-      multi-process run's jit path) contributes no row and flips
-      ``available`` off.
+      everything`` is an asserted ordering (tests/test_remat_memory.py
+      ``test_temp_bytes_monotone_down_the_ladder``), not a narrative.  A
+      program with no compiled executable (a multi-process run's jit
+      path) contributes no row and flips ``available`` off.
     - **analytic resident model**: ``per_worker_state_bytes`` (the
       ISSUE 9/11 accounting) extended with the stacked/fleet total
       (``state_bytes_total`` = workers x per-worker — on a simulated run

@@ -2,10 +2,10 @@
 
 The device-side cache layout and attention live in ``models/decode.py``;
 this module is the HOST side: which pages belong to which sequence, and
-the byte-exact occupancy accounting the telemetry/bench gate on.  Page id
-0 is the trash page (``models.decode.TRASH_PAGE``): masked writes from
-prefill padding and inactive decode slots land there, so the allocator
-never hands it out.
+the byte-exact occupancy accounting the telemetry reports and
+tests/test_serve.py gates on.  Page id 0 is the trash page
+(``models.decode.TRASH_PAGE``): masked writes from prefill padding and
+inactive decode slots land there, so the allocator never hands it out.
 
 PR 17 makes pages content-addressed.  A page's key is the rolling hash of
 the token prefix it CLOSES (``page_prefix_keys``), so two sequences that

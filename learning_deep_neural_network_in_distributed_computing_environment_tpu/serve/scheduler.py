@@ -112,7 +112,8 @@ class _Slot:
 class ContinuousBatchingScheduler:
     """Drives one ``ServeEngine``.  ``max_active`` caps concurrently
     decoding slots below ``engine.max_batch`` — ``max_active=1`` is the
-    naive sequential-request baseline the bench A/Bs against."""
+    one-sequence-at-a-time twin tests/test_serve.py holds the batched
+    token streams to."""
 
     def __init__(self, engine: ServeEngine, *, eos_id: int = -1,
                  max_active: Optional[int] = None,

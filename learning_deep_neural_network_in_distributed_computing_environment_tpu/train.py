@@ -875,8 +875,8 @@ class LocalSGDEngine:
         overwrites ``sync_ms`` with the measured wait on the sync when a
         standalone sync program ran.  The schema is identical across all
         three topologies and every engine (zero-filled where a
-        measurement does not apply), so downstream viz/bench can key on
-        the fields unconditionally."""
+        measurement does not apply), so a reader of ``round_timings`` can
+        key on the fields unconditionally."""
         if self._sync_bytes is None:
             # the per-worker template is authoritative once set (the
             # resident layout's stacked params are bucket rows, not

@@ -1,5 +1,1 @@
-"""Framework utilities: FLOPs accounting + MFU measurement."""
-
-from .flops import compiled_flops, hbm_bytes_per_sec, mfu, peak_flops
-
-__all__ = ["compiled_flops", "hbm_bytes_per_sec", "mfu", "peak_flops"]
+"""Framework utilities: batch padding and bucketing (`batching`)."""

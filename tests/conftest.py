@@ -27,6 +27,7 @@ ensure_sequential_cpu_collectives()
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 # --- quick tier ----------------------------------------------------------
@@ -84,3 +85,20 @@ def devices():
 def mesh8(devices):
     from learning_deep_neural_network_in_distributed_computing_environment_tpu import mesh as mesh_lib
     return mesh_lib.build_mesh({"data": 8})
+
+
+@pytest.fixture(scope="session")
+def assert_within_ulps():
+    """The gate between two DIFFERENTLY SHAPED float32 programs that
+    state the same arithmetic (XLA fuses, contracts into FMAs and orders
+    reductions by program shape, so they differ in the last places):
+    absolute, in float32 ulps of the reference tensor's largest
+    magnitude.  Per-element ``rtol`` is the wrong measure there: an
+    element that cancels to near zero still carries the rounding of its
+    large terms.  One program run twice stays ``assert_array_equal``."""
+    def check(actual, desired, ulps):
+        actual, desired = np.asarray(actual), np.asarray(desired)
+        assert actual.dtype == desired.dtype == np.float32
+        atol = ulps * float(np.spacing(np.abs(desired).max()))
+        np.testing.assert_allclose(actual, desired, rtol=0, atol=atol)
+    return check

@@ -209,7 +209,8 @@ class TestFlashAttention:
         (Pallas HLO-interpreter limitation), so this asserts the plumbing
         and exact numerical agreement; the kernel itself is covered by the
         unit tests above and compiles for real inside the TPU round
-        program (bench.py flash entry)."""
+        program (the benchmark's cells ``gpt2s_train_1k`` and
+        ``mellum2_train_8k``)."""
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.config import Config
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.driver import train_global
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.mesh import build_mesh

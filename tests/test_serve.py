@@ -96,18 +96,26 @@ def _engine(model, variables, **kw):
 
 class TestPagedEquivalence:
     @pytest.mark.parametrize("fam", list(FAMILIES))
-    def test_prefill_bitwise_and_decode_tolerance(self, served, fam):
+    def test_prefill_and_decode_match_full_forward(
+            self, served, assert_within_ulps, fam):
         model, v = served(fam)
         toks = np.asarray(PROMPT, np.int32)[None]
         full = np.asarray(model.apply(v, toks, train=False))
         spec = D.spec_from_model(model)
         table = jnp.asarray(np.array([[1, 2, 3, 4]], np.int32))
-        # whole-prompt prefill: same op order over the same keys => bitwise
+        # whole-prompt prefill: the same op order over the same keys,
+        # but another program (paged gather/scatter around the
+        # attention), so XLA fuses it differently: read at most 4 ulps
+        # of the largest logit (2.4e-07 at 0.74; gpt and gpt_moe, the
+        # llama families equal), 16 allowed = 9.5e-07, under the 5e-6
+        # of the incremental decode below
         kc, vc = D.init_paged_cache(spec, 8, 4)
         lg, kc, vc = D.forward_paged(
             spec, v["params"], jnp.asarray(toks), jnp.zeros(1, jnp.int32),
             jnp.array([8], jnp.int32), table, kc, vc)
-        np.testing.assert_array_equal(np.asarray(lg), full)
+        assert_within_ulps(lg, full, ulps=16)
+        np.testing.assert_array_equal(np.asarray(lg).argmax(-1),
+                                      full.argmax(-1))
         # prefill 4 + decode 4 single tokens: fp32 tolerance + argmax
         kc, vc = D.init_paged_cache(spec, 8, 4)
         lg4, kc, vc = D.forward_paged(
@@ -804,8 +812,8 @@ class TestPrefixCache:
 class TestChunkedPrefill:
     @pytest.mark.parametrize("fam", ["gpt", "llama", "llama_gqa"])
     @pytest.mark.parametrize("chunk", [4, 8])
-    def test_bitwise_logits_and_cache_vs_monolithic(self, served, fam,
-                                                    chunk):
+    def test_logits_and_cache_match_monolithic(
+            self, served, assert_within_ulps, fam, chunk):
         model, v = served(fam)
         prompt = np.asarray(PROMPT + [6, 2, 8, 3], np.int32)   # 12 tokens
         kw = dict(prompt_buckets=(16,), max_seq=16)
@@ -818,16 +826,21 @@ class TestChunkedPrefill:
         for s in range(0, len(prompt), chunk):
             tok_c, lg_c = ec.prefill_chunk_step(
                 prompt[s:s + chunk], s, row_c, 0.0, 7)
+        # the emitted token is exact.  Logits and the sequence's written
+        # pages are the monolithic prefill's to float32 rounding: a
+        # chunk attends over [chunk, span] scores where the monolithic
+        # program has [bucket, bucket], two program shapes.  Read: gpt
+        # and llama equal; llama_gqa at most 4 ulps of the largest logit
+        # (1.2e-07 at 0.49) and 1.75 of the largest cache entry
+        # (1.0e-07 at 0.65); 16 allowed.  (Page 0 is the trash page:
+        # bucket padding scribbles there, chunk-aligned spans don't, and
+        # decode never reads it.)
         assert tok_c == tok_m
-        np.testing.assert_array_equal(np.asarray(lg_c), np.asarray(lg_m))
-        # the sequence's written pages are bitwise the monolithic
-        # prefill's — chunked decode continues from EXACTLY the same
-        # state (page 0 is the trash page: bucket padding scribbles
-        # there, chunk-aligned spans don't, and decode never reads it)
-        np.testing.assert_array_equal(np.asarray(ec.kcache)[:, 1:5],
-                                      np.asarray(em.kcache)[:, 1:5])
-        np.testing.assert_array_equal(np.asarray(ec.vcache)[:, 1:5],
-                                      np.asarray(em.vcache)[:, 1:5])
+        assert_within_ulps(lg_c, lg_m, ulps=16)
+        assert_within_ulps(np.asarray(ec.kcache)[:, 1:5],
+                           np.asarray(em.kcache)[:, 1:5], ulps=16)
+        assert_within_ulps(np.asarray(ec.vcache)[:, 1:5],
+                           np.asarray(em.vcache)[:, 1:5], ulps=16)
         assert ec.compiled_buckets == []   # no bucket ever specialized
 
     def test_streams_identical_and_chunk_counts(self, served):
@@ -1165,7 +1178,7 @@ class TestSpeculative:
     def test_self_similar_deterministic_acceptance(self, served):
         """Draft sharing the target's params accepts every proposal:
         acceptance pins at (k-1)/k (the cap) and target steps per
-        emitted token at ~1/k — the backend-robust bench bar."""
+        emitted token at 1/k: counts, so the same on every backend."""
         model, v = served("gpt")
         k = 4
         draft = _engine(model, v, max_seq=32)

@@ -112,31 +112,45 @@ class TestAggregateSim:
                                   out_specs=P("data")))
         return f(tree, poison) if poison is not None else f(tree)
 
-    @pytest.mark.parametrize("topo", TOPOS)
-    @pytest.mark.parametrize("how", HOWS)
-    def test_bitwise_vs_dense_reference(self, mesh8, topo, how):
-        # the simulator's sync IS the dense path's arithmetic: stacked
-        # fp32 blends bitwise == the shard_map collectives (rank-order
-        # fold == psum, roll == ppermute).  One cell — weighted x
-        # double_ring — is ulp-tight instead of bitwise in THIS
-        # standalone harness: its three-term blend gives LLVM an FMA
-        # contraction choice that can differ between the tiny
-        # standalone programs (<= 1 ulp).  The acceptance gate lives at
-        # round level, where TestEngineParity/TestDriverParity assert
-        # the same cell BITWISE inside the real round programs.
-        tree = stacked_tree(scale=100.0)
-        real = self._real(mesh8, tree, how, topo)
+    def _sim(self, tree, how, topo):
         sim, res = jax.jit(functools.partial(
             comms.aggregate_sim, how=how, topology=topo,
             local_weight=0.3))(tree)
         assert res is None
-        if (topo, how) == ("double_ring", "weighted"):
-            for k in tree:
-                np.testing.assert_allclose(np.asarray(real[k]),
-                                           np.asarray(sim[k]),
-                                           rtol=3e-7, atol=0)
-        else:
-            assert_trees_equal(real, sim)
+        return sim
+
+    @pytest.mark.parametrize("how,topo", [
+        ("equal", "allreduce"), ("equal", "ring"), ("equal", "double_ring"),
+        ("weighted", "allreduce")])
+    def test_bitwise_vs_dense_reference(self, mesh8, topo, how):
+        # the simulator's sync IS the dense path's arithmetic: stacked
+        # fp32 blends bitwise == the shard_map collectives (rank-order
+        # fold == psum, roll == ppermute) wherever the blend leaves the
+        # compiler no contraction choice
+        tree = stacked_tree(scale=100.0)
+        assert_trees_equal(self._real(mesh8, tree, how, topo),
+                           self._sim(tree, how, topo))
+
+    @pytest.mark.parametrize("topo", ["ring", "double_ring"])
+    def test_weighted_gossip_within_ulps_of_dense_reference(
+            self, mesh8, assert_within_ulps, topo):
+        # w*own + (1-w)*peer(s): two or three products and their sum,
+        # which LLVM contracts into FMAs one way in the stacked program
+        # and another in the shard_map one.  Each choice moves one
+        # rounding of a term, and a term is at most the leaf's largest
+        # input, so the two differ by an ulp or so of the LEAF'S largest
+        # magnitude wherever the element lies (a blend that cancels to
+        # -0.999 carries the error of its 1e2-sized terms: no
+        # per-element rtol holds).  Read on jax 0.9.0's CPU backend over
+        # six seeds at scale 1 and 100: at most 1.0 ulp of the leaf's
+        # largest magnitude (3.05e-05 where it is 287), in a third of
+        # the elements; 4 allowed.  Inside the real round programs the
+        # same cells are BITWISE: TestEngineParity / TestDriverParity.
+        tree = stacked_tree(scale=100.0)
+        real = self._real(mesh8, tree, "weighted", topo)
+        sim = self._sim(tree, "weighted", topo)
+        for k in tree:
+            assert_within_ulps(sim[k], real[k], ulps=4)
 
     def test_fold_matches_psum_and_roll_matches_ppermute(self, mesh8):
         # the two primitives the whole bitwise argument rests on

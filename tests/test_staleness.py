@@ -280,8 +280,7 @@ class TestSimStaleness:
     @pytest.mark.slow
     def test_convergence_curves_across_matrix(self):
         # the paper's 2x3 matrix x K in {0,1,2}: every cell produces a
-        # finite curve of the full run length (the sim-lab numbers the
-        # ROADMAP closure quotes come from bench --entry async)
+        # finite curve of the full run length
         for mode in ("balanced", "disbalanced"):
             for topo in ("allreduce", "ring", "double_ring"):
                 for k in (0, 1, 2):

@@ -5,8 +5,8 @@ BIT-IDENTICAL to the dense all-reduce across worker counts; uneven-bucket
 padding round-trips exactly; the bf16-compressed path drifts within bf16
 rounding per sync and, with error feedback, tracks the fp32 path over many
 rounds where the uncompensated path stalls; the engine wires the mode
-selection, residual state, and per-round telemetry; and the bench A/B
-reports bytes-on-the-wire with sharded at 2(N-1)/N of dense.
+selection, residual state, and per-round telemetry; and the wire-byte
+accounting puts sharded at 2(N-1)/N of dense.
 """
 
 import jax
@@ -323,32 +323,6 @@ class TestDriverTelemetry:
         pw = res["sync_engine"]["per_worker_state_bytes"]
         assert res["sync_engine"]["param_residency"] == "resident"
         assert pw["params"] * 8 == pw["params_gathered_peak"]
-
-
-class TestBenchEntry:
-    def test_measure_sync_reports_bytes_wall_and_identity(self):
-        import bench
-
-        out = bench.measure_sync()
-        assert out["n_workers"] == N
-        assert out["bitwise_sharded_eq_dense"] is True
-        assert out["sharded_vs_dense_bytes"] == pytest.approx(
-            out["expected_bytes_ratio"], rel=0.02)
-        for mode in ("dense", "sharded", "compressed"):
-            assert out[mode]["ms"] > 0
-            assert out[mode]["wire_mb"] > 0
-        assert out["compressed"]["wire_mb"] == pytest.approx(
-            out["sharded"]["wire_mb"] / 2, rel=0.01)
-        assert out["compressed_max_abs_err"] < 0.05
-        # optimizer-placement axis (ISSUE 9): per-worker opt-state bytes
-        # at exactly 1/N of replicated, both placements bitwise
-        pl = out["opt_placement"]
-        assert pl["opt_state_bytes_ratio"] == pl["expected_opt_state_ratio"]
-        assert pl["bitwise_sharded_eq_replicated"] is True
-        assert pl["tracker_bitwise_consistent"] is True
-        for row in ("replicated", "sharded"):
-            assert pl[row]["ms"] > 0
-            assert pl[row]["opt_state_mb_per_worker"] > 0
 
 
 class TestInt8Compressed:
