@@ -265,7 +265,10 @@ def apply_scanned_stack(scan_layer_cls, x, *, num_layers: int, pp_size: int,
 
 
 class BertForMLM(nn.Module):
-    """Token ids [B, L] -> MLM logits [B, L, vocab].
+    """Token ids [B, L] -> MLM logits [B, L, vocab]; ``mode='encode'``
+    stops before the head and ``mode='head'`` is the head alone, on rows
+    of any leading shape (how the engine runs it on the labelled
+    positions only: ``labelled_rows_head`` below).
 
     ``scan_layers=True`` stores the encoder stack STACKED (one ``layers``
     collection with a leading [num_layers] axis, applied via ``nn.scan``)
@@ -302,15 +305,24 @@ class BertForMLM(nn.Module):
     # class marker (not a field): with tp_size > 1 this model's output is
     # its LOCAL vocab slice and the loss must be vocab-parallel
     vocab_parallel_head = True
+    # class marker: the task labels a minority of positions (MLM masks
+    # 15%) and the head is position-wise (Dense -> gelu -> LayerNorm ->
+    # Dense), so head(rows[i]) == head(rows)[i] and the engine may run
+    # head and loss on the labelled rows alone (train.py,
+    # ``_labelled_row_sums``).  Its value names the parameter collections
+    # that ``mode='head'`` reads.  A causal LM labels every position: no
+    # marker, and its programs are untouched.
+    labelled_rows_head = ("mlm_dense", "mlm_ln", "mlm_decoder")
 
     @nn.compact
     def __call__(self, input_ids, *, train: bool = False,
                  mode: str = "full"):
-        """``mode`` partitions the forward for the 1F1B engine path
-        (parallel/pp.py): 'embed' -> embedded activations, 'stage' ->
-        apply this device's local scanned layers to activations (no
-        pipeline schedule), 'head' -> MLM transform + decode on
-        activations.  'full' (default) is the ordinary forward; init
+        """``mode`` partitions the forward: 'embed' -> embedded
+        activations, 'stage' -> apply this device's local scanned layers
+        to activations (no pipeline schedule), 'head' -> MLM transform +
+        decode on activations (the three of the 1F1B engine path,
+        parallel/pp.py), 'encode' -> the encoder's output [B, L, hidden]
+        without the head.  'full' (default) is the ordinary forward; init
         always uses it so every mode shares one parameter structure."""
         if self.tp_size > 1 and self.num_classes % self.tp_size:
             raise ValueError(
@@ -350,6 +362,8 @@ class BertForMLM(nn.Module):
                                  ep_size=self.ep_size,
                                  capacity_factor=self.capacity_factor,
                                  name=f"layer{i}")(x, train=train)
+        if mode == "encode":
+            return x
         return self._mlm_head(x)
 
     def _mlm_head(self, x):

@@ -119,6 +119,9 @@ class SimEngine(LocalSGDEngine):
         self.resident_on = False
         self.round_opt_on = False
         self.buddy_on = False
+        # the row buffer's loop has a trip count a worker; under ``vmap``
+        # every worker would run the fullest one's
+        self.labelled_rows_head = False
         # error feedback for the SIMULATED compressed wire (the gossip
         # engine's single-stage model, comms.aggregate_sim): armed on
         # weights aggregation exactly like the real engines

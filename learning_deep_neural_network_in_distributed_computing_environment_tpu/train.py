@@ -492,6 +492,16 @@ class LocalSGDEngine:
         self._inner_axes = tuple(
             a for a in mesh.axis_names
             if a not in (DATA_AXIS, SLICE_AXIS) and int(mesh.shape[a]) > 1)
+        # head and loss on the labelled rows only (ISSUE 29): the model
+        # class says its task labels a minority of positions and its head
+        # is position-wise; the engine takes it up where a worker is one
+        # device (no vocab-parallel head, no 1F1B head slot, no seq / fsdp
+        # batch split).  Label density is a runtime value and decides
+        # nothing here: a step whose labels do not fit the row buffer
+        # fills it again (``_labelled_row_sums``).
+        self.labelled_rows_head = (
+            bool(getattr(tm, "labelled_rows_head", ()))
+            and not self._inner_axes)
         # Microbatch gradient accumulation (ISSUE 3): K > 1 scans the
         # step's batch in K slices with an fp32 gradient carry — bounded
         # activation memory, unchanged effective batch/optimizer/sync
@@ -1361,6 +1371,91 @@ class LocalSGDEngine:
             return vocab_parallel_token_stats(out, yb, mb, self.vp_axis)
         return masked_token_stats(out, yb, mb)
 
+    def _labelled_row_sums(self, params, x, yb, mb):
+        """``(sum(ce * w), correct, sum(w), head_rows)`` of a model that
+        carries ``labelled_rows_head``, from its encoder output ``x``
+        [B, L, H]: head, cross-entropy and argmax run on the labelled rows
+        (label >= 0 in a real batch row), gathered labelled-first into a
+        buffer of ``K`` rows, and on nothing else.  ``K`` is static: a
+        quarter of the ``N = B * L`` positions, rounded up to a multiple
+        of 128 (BERT's data pipeline fixes ``max_predictions_per_seq`` the
+        same way); the filler rows are unlabelled positions at weight 0.
+
+        Exact for every batch: the buffer is the body of a loop that runs
+        as often as there are labelled rows to fill it, once as a rule
+        (MLM labels 15%), ``ceil(count / K)`` times where more are
+        labelled, so nothing of [N, vocab] ever exists.  ``head_rows`` is
+        the rows the head ran on, ``K`` a pass."""
+        n = yb.size
+        k = min(n, -(-n // 512) * 128)
+        rows = x.reshape(n, x.shape[-1])
+        labels = yb.reshape(n)
+        w = masked_weights(yb, mb).reshape(n)
+        total = w.sum()
+        # the functions below close over the model, never over ``self``:
+        # the custom derivative's thunks live as long as the traced round
+        # program, and an engine reachable from its own program's jaxpr
+        # outlives the call that built it, compiled program and all
+        model = self.train_model
+        head_params = {name: params[name]
+                       for name in model.labelled_rows_head}
+
+        def sums(head_params, rows, labels, w):
+            logits = model.apply({"params": head_params}, rows, mode="head")
+            ce, w, correct = masked_token_stats(logits, labels, w)
+            return (ce * w).sum(), correct
+
+        if k == n:
+            return (*sums(head_params, rows, labels, w), total,
+                    jnp.float32(n))
+        passes = jnp.maximum(jnp.ceil(total / k), 1.0).astype(jnp.int32)
+        order = jnp.argsort(w == 0, stable=True)
+
+        def buffer(head_params, rows, c):
+            # ranks c*k .. c*k + k - 1 of ``order``; the last buffer is
+            # moved back to end at n, and the ranks it then shares with
+            # the buffer before it weigh 0
+            start = jnp.minimum(c * k, n - k)
+            idx = lax.dynamic_slice(order, (start,), (k,))
+
+            def take(a):
+                return a.at[idx].get(mode="promise_in_bounds",
+                                     unique_indices=True)
+
+            fresh = start + jnp.arange(k) >= c * k
+            return sums(head_params, take(rows), take(labels),
+                        take(w) * fresh)
+
+        def walk(one):
+            """``one(c)`` summed over the passes.  One loop body serves
+            the single pass and the rare further ones alike: a ``cond``
+            around a second copy of the head was 16 MB more program text
+            a step on the v5e, which the device holds in HBM."""
+            return lax.fori_loop(
+                0, passes, lambda c, acc: jax.tree_util.tree_map(
+                    jnp.add, acc, one(c)),
+                _zeros_like_varying(jax.eval_shape(one, jnp.int32(0))))
+
+        # A loop of a dynamic trip count has no reverse-mode derivative,
+        # so the derivative is stated by hand: a pass's value and gradient
+        # are taken together inside the loop, and the backward pass scales
+        # the summed gradient.
+        @jax.custom_vjp
+        def head(head_params, rows):
+            return walk(lambda c: buffer(head_params, rows, c))
+
+        def head_fwd(head_params, rows):
+            return walk(lambda c: jax.value_and_grad(
+                buffer, argnums=(0, 1), has_aux=True)(head_params, rows, c))
+
+        def head_bwd(grads, cotangent):
+            return jax.tree_util.tree_map(
+                lambda g: (cotangent[0] * g).astype(g.dtype), grads)
+
+        head.defvjp(head_fwd, head_bwd)
+        num, correct = head(head_params, rows)
+        return num, correct, total, (k * passes).astype(jnp.float32)
+
     def _onef1b_loss_and_metrics(self, params, batch_stats, xb, yb, mb,
                                  denom=None, aux_div=1.0):
         """1F1B train-step loss: embeddings and the per-microbatch head +
@@ -1506,48 +1601,58 @@ class LocalSGDEngine:
             params = gather_params(params, self.param_specs, self.fsdp_axis)
         out, mut = self.train_model.apply(
             {"params": params, "batch_stats": batch_stats}, xb, train=True,
-            mutable=["batch_stats", "aux", "counters"])
+            mutable=["batch_stats", "aux", "counters"],
+            **({"mode": "encode"} if self.labelled_rows_head else {}))
         counters = _mean_by_name(mut.get("counters", {}))
-        ce, w, correct = self._token_stats(out, yb, mb)
-        part_axes = self._part_axes()
-        if denom is not None:
-            # accumulation microbatch: local numerator over the external
-            # full-step denominator; correct/total stay slice-local sums
-            # psum'd over batch-partial axes exactly as below, so the
-            # wrapper's running sums match the full-batch step's values
-            if part_axes:
-                w = lax.optimization_barrier((w, ce))[0]
-            loss = (ce * w).sum() / denom
-            total = w.sum()
-            if part_axes:
-                correct = lax.psum(correct, part_axes)
-                total = lax.psum(total, part_axes)
-        elif part_axes:
-            # ORDER the mask-only psums below after the model's own
-            # collectives: ``w`` derives from the batch mask alone, so its
-            # psums are otherwise DAG-independent of the forward pass and
-            # the XLA:CPU thunk executor may start them concurrently with
-            # the model's ppermutes on different devices — intersecting-
-            # group collectives entered in different per-device orders
-            # deadlock the CPU collective rendezvous (reproduced by
-            # SP x PP stress runs; 40 s timeout then SIGABRT).  Routing
-            # ``w`` through a barrier with ``ce`` (which depends on the
-            # model output) serializes them; free on TPU.
-            w = lax.optimization_barrier((w, ce))[0]
-            # the batch is partial on this device: under seq parallelism it
-            # holds one chunk of every sequence, under FSDP a slice of the
-            # worker's batch (composable — psum over both).  The loss is
-            # the GLOBAL masked mean; returning the local numerator over
-            # the global denominator makes the cross-device gradient
-            # reduction (psum over seq / reduce-scatter over fsdp) equal
-            # grad(global loss).
-            denom = jnp.maximum(lax.psum(w.sum(), part_axes), 1.0)
-            loss = (ce * w).sum() / denom
-            correct = lax.psum(correct, part_axes)
-            total = lax.psum(w.sum(), part_axes)
+        if self.labelled_rows_head:
+            # ``out`` is the encoder's output; one device a worker, so no
+            # batch-partial axis: the masked mean, or the accumulation
+            # slice's numerator over the external denominator
+            num, correct, total, counters["head_rows"] = \
+                self._labelled_row_sums(params, out, yb, mb)
+            loss = num / (jnp.maximum(total, 1.0) if denom is None
+                          else denom)
         else:
-            loss = _masked_mean(ce, w)
-            total = w.sum()
+            ce, w, correct = self._token_stats(out, yb, mb)
+            part_axes = self._part_axes()
+            if denom is not None:
+                # accumulation microbatch: local numerator over the external
+                # full-step denominator; correct/total stay slice-local sums
+                # psum'd over batch-partial axes exactly as below, so the
+                # wrapper's running sums match the full-batch step's values
+                if part_axes:
+                    w = lax.optimization_barrier((w, ce))[0]
+                loss = (ce * w).sum() / denom
+                total = w.sum()
+                if part_axes:
+                    correct = lax.psum(correct, part_axes)
+                    total = lax.psum(total, part_axes)
+            elif part_axes:
+                # ORDER the mask-only psums below after the model's own
+                # collectives: ``w`` derives from the batch mask alone, so its
+                # psums are otherwise DAG-independent of the forward pass and
+                # the XLA:CPU thunk executor may start them concurrently with
+                # the model's ppermutes on different devices — intersecting-
+                # group collectives entered in different per-device orders
+                # deadlock the CPU collective rendezvous (reproduced by
+                # SP x PP stress runs; 40 s timeout then SIGABRT).  Routing
+                # ``w`` through a barrier with ``ce`` (which depends on the
+                # model output) serializes them; free on TPU.
+                w = lax.optimization_barrier((w, ce))[0]
+                # the batch is partial on this device: under seq parallelism it
+                # holds one chunk of every sequence, under FSDP a slice of the
+                # worker's batch (composable — psum over both).  The loss is
+                # the GLOBAL masked mean; returning the local numerator over
+                # the global denominator makes the cross-device gradient
+                # reduction (psum over seq / reduce-scatter over fsdp) equal
+                # grad(global loss).
+                denom = jnp.maximum(lax.psum(w.sum(), part_axes), 1.0)
+                loss = (ce * w).sum() / denom
+                correct = lax.psum(correct, part_axes)
+                total = lax.psum(w.sum(), part_axes)
+            else:
+                loss = _masked_mean(ce, w)
+                total = w.sum()
         # MoE load-balance auxiliary losses sown by models/moe.py.  Leaves
         # may be stacked: [n_local] under scan_layers, [steps, n_local]
         # under the GPipe schedule (bubble steps sown as exact zeros and
@@ -1699,6 +1804,13 @@ class LocalSGDEngine:
             # eval; a per-batch all_gather would be pure waste)
             params, batch_stats = carry
             xb, yb, mb = inp
+            if self.labelled_rows_head:
+                enc = self.train_model.apply(
+                    {"params": params, "batch_stats": batch_stats}, xb,
+                    train=False, mode="encode")
+                num, correct, total, _ = self._labelled_row_sums(
+                    params, enc, yb, mb)
+                return carry, (num, correct, total)
             out = self.train_model.apply(
                 {"params": params, "batch_stats": batch_stats}, xb,
                 train=False)
