@@ -138,25 +138,29 @@ def check_flash_kernels() -> None:
                                              window=window)))
             qs = q.reshape(b, l // bq, bq, h, d).swapaxes(0, 1)
             out = jax.lax.map(block, (qs, jnp.arange(l // bq) * bq))
-            return out.swapaxes(0, 1).reshape(b, l, h, d)
+            return out.swapaxes(0, 1).reshape(b, l, h, v.shape[-1])
         return fn
 
+    # d: the scores' width, or (the scores', the values')
     for name, (b, l, h, kv, d, window) in {
             "mha L=1024 (gpt2s_train_1k, the benchmark's)":
                 (4, 1024, 12, 12, 64, None),
             "mha L=4096 (gpt2_4k_flash)": (1, 4096, 12, 12, 64, None),
             "gqa L=2048 16/4 (llama_gqa4)": (1, 2048, 16, 4, 64, None),
             "gqa L=8192 32/4 d=128 window 1024 (mellum2_train_8k's)":
-                (1, 8192, 32, 4, 128, 1024)}.items():
+                (1, 8192, 32, 4, 128, 1024),
+            "mla L=8192 32 heads, scores 192 values 128 (kanana2_train_8k's)":
+                (1, 8192, 32, 32, (192, 128), None)}.items():
+        d, dv = d if isinstance(d, tuple) else (d, d)
         flash = lambda q, k, v: attend(q, k, v, impl="flash", causal=True,
                                        window=window)
-        dense = (dense_in_blocks(window) if window else
+        dense = (dense_in_blocks(window) if l > 4096 else
                  lambda q, k, v: dot_product_attention(q, k, v, causal=True))
         keys = jax.random.split(jax.random.key(l), 4)
         q = jax.random.normal(keys[0], (b, l, h, d), jnp.bfloat16)
         k = jax.random.normal(keys[1], (b, l, kv, d), jnp.bfloat16)
-        v = jax.random.normal(keys[2], (b, l, kv, d), jnp.bfloat16)
-        w = jax.random.normal(keys[3], (b, l, h, d), jnp.float32)
+        v = jax.random.normal(keys[2], (b, l, kv, dv), jnp.bfloat16)
+        w = jax.random.normal(keys[3], (b, l, h, dv), jnp.float32)
         run = lambda fn: jax.jit(
             lambda *a: (fn(*a[:3]), jax.grad(loss(fn), (0, 1, 2))(*a)))
         lowered = run(flash).lower(q, k, v, w)

@@ -31,11 +31,38 @@ class Rope:
 
 
 @dataclasses.dataclass(frozen=True)
+class Latent:
+    """Latent attention's widths (MLA without a query bottleneck): keys
+    and values are expanded from a ``rank``-wide normalised latent, a
+    score is ``nope + rope`` wide of which the last ``rope`` carry the
+    rotary (one rotary key for all heads), a value ``v`` wide."""
+    rank: int
+    nope: int
+    rope: int
+    v: int
+
+
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """How a routed layer scores and chooses: ``score`` over all experts
+    (``softmax`` | ``sigmoid``), the top k taken on ``score + bias`` where
+    ``select_bias`` (the weights stay the scores without it), the chosen
+    weights over ``their sum + eps`` and times ``scale``.  ``bias_std``:
+    the scale the bias is drawn at (it has no gradient and no rule moves
+    it here, so what it is drawn as is what it stays)."""
+    score: str = "softmax"
+    select_bias: bool = False
+    scale: float = 1.0
+    eps: float = 0.0
+    bias_std: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderArch:
     hidden: int
     heads: int
     kv_heads: int
-    head_dim: int                  # given, not hidden // heads
+    head_dim: int                  # given, not hidden // heads; the scores'
     layer_types: tuple             # one period: "sliding" | "full" each
     periods: int                   # periods kept (the cut in depth)
     window: int                    # of the "sliding" layers; counts itself
@@ -54,10 +81,18 @@ class DecoderArch:
     # share that lands on the experts held swings from 0.21 to 0.48 with
     # the seed (PERF.md section 6, PR 26).  At 1 it is 0.25 +- 0.01
     embed_std: float = 1.0
+    latent: Optional[Latent] = None    # None: grouped-query attention
+    rope_interleaved: bool = False     # rotary on pairs (2n, 2n + 1)
+    # layers BEFORE the periods whose MLP is one dense SwiGLU: (layers,
+    # width); of another parameter shape, so outside the scan
+    lead_dense: tuple = (0, 0)
+    shared_ffn: int = 0            # SwiGLU width every token passes beside
+    #                                its routed experts (0: none)
+    router: Router = Router()
 
     @property
     def layers(self) -> int:
-        return self.periods * len(self.layer_types)
+        return self.lead_dense[0] + self.periods * len(self.layer_types)
 
     def rope_of(self, layer_type: str) -> Rope:
         return dict(self.rope)[layer_type]
@@ -77,10 +112,26 @@ class DecoderArch:
         d["rope"] = tuple(
             (kind, Rope(r["theta"], None if r["yarn"] is None
                         else tuple(r["yarn"]))) for kind, r in d["rope"])
-        return cls(**{k: tup(v) if k != "rope" else v for k, v in d.items()})
+        # a manifest written before a field existed takes its default
+        if d.get("latent") is not None:
+            d["latent"] = Latent(**d["latent"])
+        if "router" in d:
+            d["router"] = Router(**d["router"])
+        records = ("rope", "latent", "router")
+        return cls(**{k: v if k in records else tup(v)
+                      for k, v in d.items()})
 
 
 _PERIOD = ("sliding", "sliding", "sliding", "full")
+# the scale a selection bias is drawn at (a recipe, not an equation: the
+# source gives neither the trained bias nor the rule that moved it): large
+# enough to change choices, so that a program which adds it to the weights
+# or drops it computes something else, small enough to leave the load
+# where the scores put it.  At the published widths (unit-RMS rows, router
+# at std 0.02: logits of std 0.9, 6 of 128) a bias at 0.005 changes 3.8% of
+# the choices, 0.002 1.9%, 0.01 8.0%, and the fullest held expert reads
+# 1.13 of the mean against 1.12 without it (numpy, PERF.md section 4)
+ROUTER_BIAS_STD = 0.005
 
 ARCHS = {
     # JetBrains/Mellum2-12B-A2.5B-Instruct, config.json (model_type
@@ -108,4 +159,34 @@ ARCHS = {
                                       1.2772588722239782)))),
         norm_eps=1e-6, vocab=1000, experts=8, experts_per_token=2,
         expert_ffn=32, experts_held=(0, 8)),
+    # kakaocorp/kanana-2-30b-a3b-instruct-2601, config.json (model_type
+    # deepseek_v3, q_lora_rank null): 48 layers, the first with a dense
+    # MLP of width 6144, then 47 of 128 routed experts (6 a token, sigmoid
+    # scores, a selection bias, weights renormalised and times 2.448) plus
+    # two shared experts; latent attention, 32 heads, scores 128 + 64
+    # wide, values 128, latent 512; vocabulary 128,256.  Held here, as one
+    # of the eight chips that share each layer of a deployment: the dense
+    # layer and the four after it, experts 0-15 of every sparse layer, rows
+    # 0-16,031 of embedding and head; the other 43 layers lie on further
+    # chips (benchmarks/configs/kanana2_30b_a3b.json, PERF.md 4)
+    "kanana2_30b_a3b": DecoderArch(
+        hidden=2048, heads=32, kv_heads=32, head_dim=192,
+        layer_types=("full",), periods=4, window=0,
+        rope=(("full", Rope(1000000.0)),), norm_eps=1e-6, vocab=16032,
+        experts=128, experts_per_token=6, expert_ffn=768,
+        experts_held=(0, 16),
+        published=(("layers", 48), ("experts", 128), ("vocab", 128256)),
+        latent=Latent(rank=512, nope=128, rope=64, v=128),
+        rope_interleaved=True, lead_dense=(1, 6144), shared_ffn=1536,
+        router=Router("sigmoid", True, 2.448, 1e-20, ROUTER_BIAS_STD)),
+    # the CPU tests' preset of the same block: one dense and two sparse
+    # layers, every expert held
+    "kanana2_tiny": DecoderArch(
+        hidden=64, heads=4, kv_heads=4, head_dim=24,
+        layer_types=("full",), periods=2, window=0,
+        rope=(("full", Rope(10000.0)),), norm_eps=1e-6, vocab=1000,
+        experts=8, experts_per_token=2, expert_ffn=32, experts_held=(0, 8),
+        latent=Latent(rank=32, nope=16, rope=8, v=16),
+        rope_interleaved=True, lead_dense=(1, 128), shared_ffn=64,
+        router=Router("sigmoid", True, 2.448, 1e-20, ROUTER_BIAS_STD)),
 }

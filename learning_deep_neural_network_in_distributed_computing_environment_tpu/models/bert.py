@@ -21,6 +21,7 @@ vocab 30522, max position 512.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -114,6 +115,52 @@ class SelfAttention(nn.Module):
             return y
         return y + self.param("out_bias", nn.initializers.zeros,
                               (d,)).astype(y.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Latent attention (MLA) without a query bottleneck, in the expanded
+    form that trains and prefills: ``q = x Wq`` as heads of ``nope + rope``;
+    ``(c, k_rope) = split(x Wkv_a)``; ``c = RMSNorm(c)``; ``c Wkv_b`` as
+    heads of ``nope + v``, split into each head's key part and its value;
+    rotary on each head's last ``rope`` query columns and on the ONE
+    ``k_rope``, which every head's key ends in.  Scores are ``nope + rope``
+    wide, values ``v`` wide: ``attend`` takes the two widths.  Causal, no
+    biases.  The rotary key is copied to the heads before the kernels (one
+    k operand, as a grouped-query call has).  The absorbed form that
+    decodes from a cache of latents is a serving matter and not here."""
+
+    num_heads: int
+    latent: Any                    # arch.Latent
+    rope_theta: float
+    rope_interleaved: bool = False
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+    attention_impl: str = "dense"
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.attention import attend, rope
+        m, h = self.latent, self.num_heads
+        b, l, d = x.shape
+        rotary = functools.partial(
+            rope, pos=jnp.arange(l), theta=self.rope_theta,
+            interleaved=self.rope_interleaved)
+        proj = functools.partial(nn.DenseGeneral, kernel_init=_init,
+                                 use_bias=False, dtype=self.dtype)
+        q = proj((h, m.nope + m.rope), name="q")(x)
+        with jax.named_scope("mla_latent"):
+            c = proj(m.rank + m.rope, name="kv_a")(x)
+            k_rope = rotary(c[..., None, m.rank:])           # [B, L, 1, rope]
+            c = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           name="kv_norm")(c[..., :m.rank])
+            kv = proj((h, m.nope + m.v), name="kv_b")(c)
+        q = jnp.concatenate([q[..., :m.nope], rotary(q[..., m.nope:])], -1)
+        k = jnp.concatenate(
+            [kv[..., :m.nope], jnp.broadcast_to(k_rope, (b, l, h, m.rope))],
+            -1)
+        out = attend(q, k, kv[..., m.nope:], impl=self.attention_impl,
+                     causal=True)
+        return proj(d, axis=(-2, -1), name="out")(out)
 
 
 class EncoderLayer(nn.Module):

@@ -1,7 +1,10 @@
 """The decoder-only LM that a ``models.arch.DecoderArch`` describes: one
-block definition, ``x + Attn_t(RMSNorm(x))`` then ``x + MoE(RMSNorm(x))``,
+block definition, ``x + Attn_t(RMSNorm(x))`` then ``x + F(RMSNorm(x))``,
 whose attention takes its kind ``t`` (window or full, and that kind's
-rotary parameters) from the layer's place in the period.
+rotary parameters) from the layer's place in the period, and is grouped-
+query or latent as the record says.  ``F`` is the routed experts, plus
+the shared experts every token passes where the record has them; in the
+record's leading dense layers it is one SwiGLU MLP.
 
 The stack is scanned a PERIOD at a time (``bert.apply_scanned_stack`` over
 ``_ScanPeriod``): the layers of a period share one parameter shape and
@@ -9,6 +12,10 @@ differ only in static arguments of attention, so a period is that many
 calls of the one block, named ``layer_<i>``, and the stacked ``layers``
 collection carries the periods on its leading axis.  Rematerialisation is
 per layer, inside the period: a period is the whole of a short stack.
+
+The leading dense layers are of another parameter shape than a period, so
+they run before the scan as plain modules ``lead_<i>``, rematerialised by
+the same policy.
 
 Untied head, no biases, no position table (rotary), no auxiliary loss.
 """
@@ -21,8 +28,12 @@ import flax.linen as nn
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+import jax
+
 from .arch import DecoderArch
-from .bert import SelfAttention, apply_scanned_stack, resolve_remat_policy
+from .bert import (LatentAttention, SelfAttention, apply_scanned_stack,
+                   resolve_remat_policy)
+from .llama import SwiGLU
 from .moe import RoutedExperts
 
 _init = nn.initializers.normal(stddev=0.02)
@@ -33,6 +44,7 @@ class DecoderBlock(nn.Module):
     layer_type: str                # "sliding" | "full"
     dtype: Any = jnp.float32
     attention_impl: str = "dense"
+    dense_ffn: int = 0             # > 0: one SwiGLU MLP of this width
 
     @nn.compact
     def __call__(self, x):
@@ -40,17 +52,42 @@ class DecoderBlock(nn.Module):
         norm = lambda name: nn.RMSNorm(epsilon=a.norm_eps, dtype=self.dtype,
                                        name=name)
         rope = a.rope_of(self.layer_type)
-        attn = SelfAttention(
-            a.heads, dtype=self.dtype, attention_impl=self.attention_impl,
-            causal=True, use_bias=False, num_kv_heads=a.kv_heads,
-            head_dim=a.head_dim, window=a.window_of(self.layer_type),
-            rope_theta=rope.theta, rope_yarn=rope.yarn, name="attn")
+        if a.latent:
+            attn = LatentAttention(
+                a.heads, a.latent, rope_theta=rope.theta,
+                rope_interleaved=a.rope_interleaved, norm_eps=a.norm_eps,
+                dtype=self.dtype, attention_impl=self.attention_impl,
+                name="attn")
+        else:
+            attn = SelfAttention(
+                a.heads, dtype=self.dtype,
+                attention_impl=self.attention_impl, causal=True,
+                use_bias=False, num_kv_heads=a.kv_heads, head_dim=a.head_dim,
+                window=a.window_of(self.layer_type), rope_theta=rope.theta,
+                rope_yarn=rope.yarn, name="attn")
         x = x + checkpoint_name(attn(norm("rms1")(x)), "attn_out")
-        f = RoutedExperts(a.experts, a.expert_ffn, a.experts_per_token,
-                          experts_held=a.experts_held, dtype=self.dtype,
-                          name="moe")(norm("rms2")(x))
+        h = norm("rms2")(x)
+        if self.dense_ffn:
+            f = SwiGLU(self.dense_ffn, dtype=self.dtype, name="mlp")(h)
+        else:
+            f = RoutedExperts(a.experts, a.expert_ffn, a.experts_per_token,
+                              experts_held=a.experts_held, dtype=self.dtype,
+                              router=a.router, name="moe")(h)
+            if a.shared_ffn:
+                with jax.named_scope("moe_shared"):
+                    f = f + SwiGLU(a.shared_ffn, dtype=self.dtype,
+                                   name="shared")(h)
         return checkpoint_name(x + checkpoint_name(f, "mlp_out"),
                                "block_out")
+
+
+def _block(layer_remat: Optional[str]):
+    """``DecoderBlock``, rematerialised per layer under a named policy."""
+    if not layer_remat:
+        return DecoderBlock
+    from . import checkpoint_policy
+    return nn.remat(DecoderBlock, prevent_cse=False,
+                    policy=checkpoint_policy(layer_remat))
 
 
 class _ScanPeriod(nn.Module):
@@ -64,11 +101,7 @@ class _ScanPeriod(nn.Module):
 
     @nn.compact
     def __call__(self, x, _):
-        block = DecoderBlock
-        if self.layer_remat:
-            from . import checkpoint_policy
-            block = nn.remat(DecoderBlock, prevent_cse=False,
-                             policy=checkpoint_policy(self.layer_remat))
+        block = _block(self.layer_remat)
         for i, kind in enumerate(self.arch.layer_types):
             x = block(self.arch, kind, dtype=self.dtype,
                       attention_impl=self.attention_impl,
@@ -100,11 +133,18 @@ class DecoderLM(nn.Module):
         x = nn.Embed(a.vocab, a.hidden,
                      embedding_init=nn.initializers.normal(a.embed_std),
                      dtype=self.dtype, name="tok_emb")(input_ids)
+        layer_remat = resolve_remat_policy(False, self.remat_policy)
+        lead_layers, lead_width = a.lead_dense
+        for i in range(lead_layers):
+            x = _block(layer_remat)(
+                a, a.layer_types[0], dtype=self.dtype,
+                attention_impl=self.attention_impl, dense_ffn=lead_width,
+                name=f"lead_{i}")(x)
         x = apply_scanned_stack(
             _ScanPeriod, x, num_layers=a.periods, pp_size=1,
             pipeline_axis=None, num_microbatches=0, train=train,
             arch=a, dtype=self.dtype, attention_impl=self.attention_impl,
-            layer_remat=resolve_remat_policy(False, self.remat_policy))
+            layer_remat=layer_remat)
         x = nn.RMSNorm(epsilon=a.norm_eps, dtype=self.dtype, name="rms_f")(x)
         return nn.Dense(a.vocab, use_bias=False, kernel_init=_init,
                         dtype=self.dtype, name="lm_head")(x)

@@ -40,6 +40,39 @@ from .bert import SelfAttention
 _init = nn.initializers.normal(stddev=0.02)
 
 
+def swiglu(x, ffn_dim: int, *, dtype=jnp.float32, tp_size: int = 1,
+           model_axis: Optional[str] = None):
+    """``ffn_out(silu(ffn_in(x)) * ffn_up(x))``, no biases, column- then
+    row-parallel under a model axis.  Called inside a module's compact
+    ``__call__``: the three ``Dense`` layers become that module's own
+    (``LlamaBlock`` keeps them beside its norms; ``SwiGLU`` below is the
+    same MLP as a submodule)."""
+    if ffn_dim % tp_size:
+        raise ValueError(
+            f"ffn_dim {ffn_dim} not divisible by tp_size {tp_size} "
+            "(column-parallel SwiGLU)")
+    f_in = copy_to_tp_region(x, model_axis)
+    gate = nn.Dense(ffn_dim // tp_size, use_bias=False, kernel_init=_init,
+                    dtype=dtype, name="ffn_in")(f_in)
+    up = nn.Dense(ffn_dim // tp_size, use_bias=False, kernel_init=_init,
+                  dtype=dtype, name="ffn_up")(f_in)
+    f = nn.Dense(x.shape[-1], use_bias=False, kernel_init=_init, dtype=dtype,
+                 name="ffn_out")(nn.silu(gate) * up)
+    return reduce_from_tp_region(f, model_axis)
+
+
+class SwiGLU(nn.Module):
+    """``swiglu`` as a submodule of its own (``models/decoder.py``: a dense
+    layer's MLP, a sparse layer's shared experts)."""
+
+    ffn_dim: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        return swiglu(x, self.ffn_dim, dtype=self.dtype)
+
+
 class LlamaBlock(nn.Module):
     """Pre-norm decoder block: x + attn(rms1(x)); x + swiglu(rms2(x))."""
 
@@ -82,21 +115,8 @@ class LlamaBlock(nn.Module):
                        model_axis=self.model_axis, name="moe")(
                            f, train=train, aux_scale=aux_scale)
         else:
-            if self.ffn_dim % self.tp_size:
-                raise ValueError(
-                    f"ffn_dim {self.ffn_dim} not divisible by tp_size "
-                    f"{self.tp_size} (column-parallel SwiGLU)")
-            f_in = copy_to_tp_region(f, self.model_axis)
-            gate = nn.Dense(self.ffn_dim // self.tp_size, use_bias=False,
-                            kernel_init=_init, dtype=self.dtype,
-                            name="ffn_in")(f_in)
-            up = nn.Dense(self.ffn_dim // self.tp_size, use_bias=False,
-                          kernel_init=_init, dtype=self.dtype,
-                          name="ffn_up")(f_in)
-            f = nn.Dense(x.shape[-1], use_bias=False, kernel_init=_init,
-                         dtype=self.dtype,
-                         name="ffn_out")(nn.silu(gate) * up)
-            f = reduce_from_tp_region(f, self.model_axis)
+            f = swiglu(f, self.ffn_dim, dtype=self.dtype,
+                       tp_size=self.tp_size, model_axis=self.model_axis)
         f = checkpoint_name(f, "mlp_out")
         return checkpoint_name(x + f, "block_out")
 

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from .arch import Router
+
 _init = nn.initializers.normal(stddev=0.02)
 
 
@@ -211,7 +213,10 @@ def routed_apply(toks, expert_idx, weights, first, held: int, expert_fn):
 class RoutedExperts(nn.Module):
     """Sparse SwiGLU experts as deployed: ``top_k`` of ``num_experts`` a
     token, weights renormalised over the chosen, no capacity, no dropped
-    token, no auxiliary loss.
+    token, no auxiliary loss.  ``router`` (``arch.Router``) is the rule
+    that scores and chooses; its selection bias, where it has one, is a
+    parameter that enters the choice alone: it gets no gradient, so the
+    optimizer leaves it to the bit.
 
     ``experts_held = (first, count)`` makes this one expert-parallel
     rank's layer: the router scores all ``num_experts``, the top-k and the
@@ -226,6 +231,7 @@ class RoutedExperts(nn.Module):
     top_k: int
     experts_held: Optional[tuple] = None   # (first, count); None: all
     dtype: Any = jnp.float32
+    router: Router = Router()
 
     @nn.compact
     def __call__(self, x):
@@ -233,13 +239,26 @@ class RoutedExperts(nn.Module):
         b, t, h = x.shape
         first, held = self.experts_held or (0, self.num_experts)
         toks = x.reshape(b * t, h)
+        rule = self.router
         with jax.named_scope("moe_route"):
             logits = nn.Dense(self.num_experts, use_bias=False,
                               dtype=jnp.float32, kernel_init=_init,
                               name="gate")(toks.astype(jnp.float32))
-            weights, idx = lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                     self.top_k)
-            weights = weights / weights.sum(-1, keepdims=True)
+            scores = {"softmax": lambda z: jax.nn.softmax(z, axis=-1),
+                      "sigmoid": jax.nn.sigmoid}[rule.score](logits)
+            if rule.select_bias:
+                bias = self.param(
+                    "select_bias", nn.initializers.normal(rule.bias_std),
+                    (self.num_experts,), jnp.float32)
+                _, idx = lax.top_k(scores + bias, self.top_k)
+                weights = jnp.take_along_axis(scores, idx, axis=-1)
+            else:
+                weights, idx = lax.top_k(scores, self.top_k)
+            # (a rule without eps or scale traces to the program it had)
+            total = weights.sum(-1, keepdims=True)
+            weights = weights / (total + rule.eps if rule.eps else total)
+            if rule.scale != 1.0:
+                weights = weights * rule.scale
         w1, w3 = (self.param(name, _init, (held, h, self.ffn_dim)).astype(
             self.dtype) for name in ("w1", "w3"))
         w2 = self.param("w2", _init, (held, self.ffn_dim, h)).astype(
@@ -259,6 +278,13 @@ class RoutedExperts(nn.Module):
         return out.reshape(b, t, h)
 
 
+def _expert_stack(names: list) -> bool:
+    """A leaf under a ``moe`` module that carries the experts on an axis:
+    not the router's matrix, not its selection bias."""
+    return ("moe" in names and "gate" not in names
+            and names[-1] != "select_bias")
+
+
 def with_expert_overlay(specs_fn, *, axis: str = "expert"):
     """Wrap a PartitionSpec-tree builder (e.g. ``bert.tp_param_specs`` /
     ``bert.pp_tp_param_specs``) so MoE expert-stack leaves additionally
@@ -273,7 +299,7 @@ def with_expert_overlay(specs_fn, *, axis: str = "expert"):
 
         def fix(path, leaf_spec):
             names = [getattr(p_, "key", str(p_)) for p_ in path]
-            if "moe" not in names or "gate" in names:
+            if not _expert_stack(names):
                 return leaf_spec
             i = 1 if "layers" in names else 0
             parts = list(leaf_spec)
@@ -302,7 +328,7 @@ def ep_param_specs(params, axis: str = "expert"):
 
     def spec(path, leaf):
         names = [getattr(p_, "key", str(p_)) for p_ in path]
-        if "moe" in names and "gate" not in names:
+        if _expert_stack(names):
             if "layers" in names:
                 return P(None, axis, *([None] * (leaf.ndim - 2)))
             return P(axis, *([None] * (leaf.ndim - 1)))
@@ -321,7 +347,7 @@ def pp_ep_param_specs(params, *, pipe_axis: str = "pipe",
 
     def spec(path, leaf):
         names = [getattr(p_, "key", str(p_)) for p_ in path]
-        expert = "moe" in names and "gate" not in names
+        expert = _expert_stack(names)
         if "layers" in names:
             if expert:
                 return P(pipe_axis, axis, *([None] * (leaf.ndim - 2)))

@@ -58,8 +58,9 @@ def rope_frequencies(head_dim: int, theta: float,
 
 
 def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float = 10000.0,
-         yarn: Optional[tuple] = None):
-    """Rotary position embedding, rotate-half convention.
+         yarn: Optional[tuple] = None, interleaved: bool = False):
+    """Rotary position embedding, rotate-half convention; ``interleaved``:
+    on the pairs ``(x_2n, x_2n+1)`` instead of ``(x_n, x_n+half)``.
 
     ``x`` [B, L, H, Dh], ``pos`` [L] absolute token positions.  Angles are
     computed in f32 (bf16 positions lose integer precision past 256) and
@@ -74,6 +75,11 @@ def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float = 10000.0,
     sin = jnp.sin(ang)[None, :, None, :]
     if scale != 1.0:
         cos, sin = cos * scale, sin * scale
+    if interleaved:
+        pairs = x.reshape(*x.shape[:-1], half, 2).astype(jnp.float32)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate(
@@ -108,7 +114,9 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           mask: Optional[jnp.ndarray] = None,
                           causal: bool = False,
                           window: Optional[int] = None) -> jnp.ndarray:
-    """[B, Lq, H, D] x [B, Lk, KV, D] -> [B, Lq, H, D]; softmax in fp32.
+    """[B, Lq, H, D] x [B, Lk, KV, D] -> [B, Lq, H, Dv]; softmax in fp32.
+    ``v`` may be of another width than ``q`` and ``k`` (latent attention):
+    the scale is the scores' width, the output has the values'.
 
     KV == H is plain multi-head attention; KV < H (divisible) is
     grouped-query attention, computed with grouped einsums so the K/V
@@ -138,7 +146,7 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     b, h, lq, lk = w.shape
     wg = w.astype(v.dtype).reshape(b, h // rep, rep, lq, lk)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", wg, v)
-    return out.reshape(b, lq, h, d)
+    return out.reshape(b, lq, h, v.shape[-1])
 
 
 def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -147,7 +155,8 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
            causal: bool = False,
            window: Optional[int] = None) -> jnp.ndarray:
     """``window`` (with ``causal``): a sliding window, query i attends keys
-    ``i - window < j <= i``; dense and flash take it."""
+    ``i - window < j <= i``; dense and flash take it, and values of
+    another width than the scores'."""
     if impl == "dense":
         return dot_product_attention(q, k, v, mask, causal=causal,
                                      window=window)
@@ -159,6 +168,10 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             raise NotImplementedError(
                 f"{impl} attention has no sliding window; use dense or "
                 "flash")
+        if v.shape[-1] != q.shape[-1]:
+            raise NotImplementedError(
+                f"{impl} attention has one width for q, k and v; use dense "
+                "or flash for latent attention")
         if axis_name is None:
             raise ValueError(f"{impl} attention requires axis_name (the mesh "
                              "axis the sequence is sharded over)")

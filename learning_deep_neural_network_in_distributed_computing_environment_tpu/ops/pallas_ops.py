@@ -407,8 +407,10 @@ def _walks(ni, nk, bq, bk, causal, window):
 
 
 def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
+    # two widths (latent attention): q and k carry the scores' ``d``, v and
+    # the output ``dv``; the scale is the scores'
     b, lq, h, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[-1]
     # K/V may carry fewer heads (grouped-query attention): the grid still
     # runs over the FULL query-head count, and the K/V block specs map
     # query head h to its group h // rep — the kernel body is unchanged and
@@ -431,10 +433,15 @@ def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
               window=window)
     kernel = (functools.partial(_flash_kernel, **kw) if with_lse
               else functools.partial(_fwd_kernel_nolse, **kw))
-    o_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0),
-                          memory_space=pltpu.VMEM)
-    out_shape = [jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma)]
-    out_specs = [o_spec]
+    row = lambda m: pl.BlockSpec((1, 1, bq, m),
+                                 lambda b_, h_, i, j: (b_, h_, i, 0),
+                                 memory_space=pltpu.VMEM)
+    col = lambda m: pl.BlockSpec((1, 1, bk, m),
+                                 lambda b_, h_, i, j: (b_, h_ // rep,
+                                                       keys.loaded(i, j), 0),
+                                 memory_space=pltpu.VMEM)
+    out_shape = [jax.ShapeDtypeStruct((b, h, lq, dv), q.dtype, vma=vma)]
+    out_specs = [row(dv)]
     if with_lse:
         out_shape.append(jax.ShapeDtypeStruct(
             (b, h, lq, LANES), jnp.float32, vma=vma))
@@ -445,22 +452,12 @@ def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
         kernel,
         out_shape=out_shape,
         grid=grid,
-        in_specs=[
-            o_spec,
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // rep,
-                                               keys.loaded(i, j), 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // rep,
-                                               keys.loaded(i, j), 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[row(d), col(d), col(dv)],
         out_specs=out_specs,
         scratch_shapes=[] if _one_block(causal, ni, nk) else [
             pltpu.VMEM((bq, 1), jnp.float32),    # running max m
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom l
-            pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),   # output accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -557,9 +554,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     def _step(cols, pieces):
         if not pieces:
             if not acc:     # keys past the last query: nothing reaches them
-                zero = jnp.zeros((cols.stop - cols.start, dk_ref.shape[-1]),
-                                 dk_ref.dtype)
-                dk_ref[0, 0, cols, :] = dv_ref[0, 0, cols, :] = zero
+                for ref in (dk_ref, dv_ref):
+                    ref[0, 0, cols, :] = jnp.zeros(
+                        (cols.stop - cols.start, ref.shape[-1]), ref.dtype)
             return
         k = k_ref[0, 0, cols, :]
         v = v_ref[0, 0, cols, :]
@@ -594,7 +591,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, window=None):
     (the FlashAttention-2 construction: recompute p from q, k and the saved
     log-sum-exp, accumulate dq / dk / dv per block pair)."""
     b, lq, h, d = q.shape
-    lk, kv = k.shape[1], k.shape[2]
+    lk, kv, dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = h // kv             # queries per K/V head (1 = MHA, >1 = GQA)
     bq, bk = _block_size(lq, BQ), _block_size(lk, BK)
     ni, nk = lq // bq, lk // bk
@@ -634,7 +631,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, window=None):
                           bq=bq, bk=bk, causal=causal, window=window),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma),
         grid=(b, h, ni, keys.span),
-        in_specs=[row(d), col(d), col(d), row(d), row(LANES), row(LANES)],
+        in_specs=[row(d), col(d), col(dv), row(dv), row(LANES), row(LANES)],
         out_specs=row(d),
         scratch_shapes=[] if _one_block(causal, ni, nk) else [
             pltpu.VMEM((bq, d), jnp.float32)],
@@ -648,22 +645,22 @@ def _flash_backward(q, k, v, o, lse, g, causal, window=None):
         out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype, vma=vma),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype, vma=vma)],
         grid=(b, kv, nk, nqw * rep),
-        in_specs=[rowT(d), colT(d), colT(d), rowT(d), rowT(LANES),
+        in_specs=[rowT(d), colT(d), colT(dv), rowT(dv), rowT(LANES),
                   rowT(LANES)],
-        out_specs=[colT(d), colT(d)],
+        out_specs=[colT(d), colT(dv)],
         scratch_shapes=[] if _one_block(causal, ni, nk, rep) else [
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32)],
+            pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=params, interpret=_interpret(), name="flash_dkv",
     )(qt, kt, vt, gt, lse, delta)
     return (dqt.transpose(0, 2, 1, 3), dkt.transpose(0, 2, 1, 3),
             dvt.transpose(0, 2, 1, 3))
 
 
-def _supported(q, k) -> bool:
+def _supported(q, k, v) -> bool:
     return (_block_size(q.shape[1], BQ) is not None
             and _block_size(k.shape[1], BK) is not None
-            and q.shape[-1] <= 256
+            and q.shape[-1] <= 256 and v.shape[-1] <= 256
             and q.shape[2] % k.shape[2] == 0)
 
 
@@ -755,7 +752,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     mask: Optional[jnp.ndarray] = None,
                     causal: bool = False,
                     window: Optional[int] = None) -> jnp.ndarray:
-    """[B, L, H, D] flash attention (K/V may carry fewer heads — GQA);
+    """[B, L, H, D] flash attention (K/V may carry fewer heads — GQA; V and
+    the output may be of another width than Q and K — latent attention);
     dense fallback off the fast path, logged once per shape.  ``window``
     (causal only): query i attends keys ``i - window < j <= i``."""
     from .attention import dot_product_attention
@@ -772,11 +770,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         _log_fallback("arbitrary masks are not tiled (use causal=True for "
                       "autoregressive masking)", q)
         return dense()
-    if not _supported(q, k):
+    if not _supported(q, k, v):
         _log_fallback(
             "shape outside tiling constraints (needs a 128-multiple block "
-            "dividing both sequence lengths, head_dim <= 256, and query "
-            "heads divisible by kv heads)", q)
+            "dividing both sequence lengths, the scores' and the values' "
+            "widths <= 256, and query heads divisible by kv heads)", q)
         return dense()
     if _interpret() and in_shard_map:
         # expected on the CPU test mesh, not a perf surprise: no warning

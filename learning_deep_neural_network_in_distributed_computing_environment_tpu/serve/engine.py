@@ -321,6 +321,17 @@ def manifest_num_classes(path: str) -> Optional[int]:
 def model_from_metadata(meta: dict):
     """Rebuild the serving model from a checkpoint's manifest metadata."""
     name = meta.get("model", "")
+    arch = meta.get("arch") or {}
+    missing = [what for what, has in (
+        ("latent attention", arch.get("latent")),
+        ("routed experts", arch.get("experts")),
+        ("sliding-window attention", "sliding" in arch.get("layer_types", ())))
+        if has]
+    if missing:
+        raise ValueError(
+            f"checkpoint was trained with --model {name!r}, whose "
+            f"{', '.join(missing)} the paged decode path (models/decode.py) "
+            "does not compute: it is not served yet")
     if not name.startswith(("gpt", "llama")):
         raise ValueError(
             f"checkpoint was trained with --model {name!r}; serving "

@@ -42,12 +42,12 @@ def batches():
     return ids, labels
 
 
-def _program_steps(params, ids, labels, lr):
+def _program_steps(params, ids, labels, lr, name="mellum2_tiny"):
     """The program's model under the reference's recipe: value_and_grad of
     the masked mean cross-entropy, optax's Adam, three steps."""
     import optax
     from learning_deep_neural_network_in_distributed_computing_environment_tpu.train import softmax_cross_entropy
-    model = get_model("mellum2_tiny", num_classes=1000, scan_layers=True,
+    model = get_model(name, num_classes=1000, scan_layers=True,
                       remat_policy="everything")
     tx = optax.scale_by_adam()
 

@@ -84,6 +84,30 @@ def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
     assert pallas_ops.GRID_COUNTS == {(8192, 8192, True, window): grid}
 
 
+def test_latent_attention_lowers_for_v5e(one_chip, monkeypatch):
+    """kanana2_train_8k's attention call: L = 8192, 32 heads, scores 192
+    wide (a lane tile and a half) and values 128, causal, the rotary key
+    already copied to the heads: three Mosaic calls whose operand counts
+    the accepted ``kernels/flash_*.json`` pick, on the full layer's walk."""
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
+    monkeypatch.setattr(pallas_ops, "GRID_COUNTS", {})
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    loss = lambda q, k, v: pallas_ops._flash(q, k, v, True).astype(
+        jnp.float32).sum()
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        sds(1, 8192, 32, 192), sds(1, 8192, 32, 192),
+        sds(1, 8192, 32, 128)).compile()
+    calls = [line.strip() for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert sorted(kernel_of(line, load_kernels()) for line in calls) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+    assert pallas_ops.TILE_COUNTS == {(8192, 8192, True, None):
+                                      (528, 1024, 32)}
+    assert pallas_ops.GRID_COUNTS == {(8192, 8192, True, None):
+                                      ((64, 36), (64, 36), 64)}
+
+
 def test_grouped_expert_products_lower_for_v5e(one_chip, monkeypatch):
     """The routed layer's three products and their gradients at the
     published widths, over the worst-case 65,536 rows: 2 forward products

@@ -249,21 +249,26 @@ class TestOldCallsUnchanged:
     the parent commit (549092b) by the same code; a PR that changes these
     kernels on purpose reads new ones.  A causal call of many blocks with
     no window lowers to what it lowered to before the grid followed the
-    window (ISSUE 27): its pin was read off that parent (f6f7aa9)."""
+    window (ISSUE 27): its pin was read off that parent (f6f7aa9).  A call
+    with one width for q, k and v lowers to what it lowered to before the
+    kernels took the values' width apart from the scores' (ISSUE 30): the
+    window call's pin was read off that parent (0e9225a), the others
+    stand."""
 
-    @pytest.mark.parametrize("shape,causal,pin", [
-        ((4, 1024, 12, 12, 64), True, "1fea3930ac522bc2"),    # gpt2_small
-        ((16, 512, 12, 12, 64), False, "9504b7eee0c4fe0c"),   # bert_base
-        # mellum2_12b_a2p5b's full layer
-        ((1, 8192, 32, 4, 128), True, "bb8152b91081eff4"),
+    @pytest.mark.parametrize("shape,causal,window,pin", [
+        ((4, 1024, 12, 12, 64), True, None, "1fea3930ac522bc2"),  # gpt2_small
+        ((16, 512, 12, 12, 64), False, None, "9504b7eee0c4fe0c"),  # bert_base
+        # mellum2_12b_a2p5b's full layer and its sliding one
+        ((1, 8192, 32, 4, 128), True, None, "bb8152b91081eff4"),
+        ((1, 8192, 32, 4, 128), True, 1024, "9962667657642d8d"),
     ])
-    def test_jaxpr_hash(self, monkeypatch, shape, causal, pin):
+    def test_jaxpr_hash(self, monkeypatch, shape, causal, window, pin):
         import hashlib
         import re
         monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
         b, l, h, kv, d = shape
         loss = lambda q, k, v: pallas_ops.flash_attention(
-            q, k, v, causal=causal).astype(jnp.float32).sum()
+            q, k, v, causal=causal, window=window).astype(jnp.float32).sum()
         sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
         text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
             sds(b, l, h, d), sds(b, l, kv, d), sds(b, l, kv, d)))
