@@ -184,7 +184,7 @@ def check_flash_kernels() -> None:
             f"flash fell back to dense: {pallas_ops._FALLBACK_LOGGED}")
     say_flash_tiles()
     grid = pallas_ops.GRID_COUNTS[(8192, 8192, True, 1024)]
-    if grid != ((16, 15), (16, 15), 64):
+    if grid != (16, 15, 64):
         raise AssertionError(f"the window call's grid does not follow its "
                              f"window: {grid}")
 
@@ -192,8 +192,8 @@ def check_flash_kernels() -> None:
 def say_flash_tiles() -> None:
     """The kernels' tile registry: a causal shape of more than one sub-tile
     must skip some and mask some, any other shape must visit them all
-    unmasked; every block that holds work is a grid step of forward / dq
-    and of dkv."""
+    unmasked; every block that holds work is a grid step of the one walk
+    that the forward and the backward kernel share."""
     from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import (
         pallas_ops,
     )
@@ -201,10 +201,10 @@ def say_flash_tiles() -> None:
             pallas_ops.TILE_COUNTS.items(), key=str):
         causal = key[2]
         say(pallas_ops.tiles_line(key))
-        (walked, work), (walked_t, work_t), _ = pallas_ops.GRID_COUNTS[key]
+        walked, work, blocks = pallas_ops.GRID_COUNTS[key]
         if ((visited < total) != (causal and total > 1)
                 or (masked > 0) != causal
-                or not work == work_t <= min(walked, walked_t)):
+                or not 0 < work <= min(walked, blocks)):
             raise AssertionError(f"wrong for this shape: "
                                  f"{pallas_ops.tiles_line(key)}")
 

@@ -7,19 +7,26 @@ onto the MXU/VMEM model.  The K/V loop is the innermost GRID dimension
 HBM->VMEM copies against compute; the online-softmax state (m, l, acc)
 lives in VMEM scratch and persists across that grid dimension.  That
 dimension spans the key blocks one query block's rows of the mask can
-need, not all of them (``_walks``): step ``jj`` of query block ``i``
+need, not all of them (``_key_walk``): step ``jj`` of query block ``i``
 stands for key block ``first(i) + jj``, so a window-1024 call at
 L = 8192 walks 2 steps a query block where the sequence has 8 key
-blocks (the dK/dV kernel the same down a key block's column).  Matmul
+blocks (the backward kernel walks the same steps).  Matmul
 inputs stay in the incoming dtype (bf16 on TPU) with float32 MXU
 accumulation — casting inputs to f32 first would halve MXU throughput.
 
-Differentiable via ``custom_vjp`` with BLOCKWISE backward kernels
-(FlashAttention-2 construction): the forward additionally stores the
+Differentiable via ``custom_vjp`` with ONE blockwise backward kernel
+(FlashAttention-2's arithmetic): the forward additionally stores the
 per-row log-sum-exp (lane-broadcast, [B, H, L, 128]); the backward
-recomputes softmax probabilities per block pair from (q, k, lse) and runs
-two passes — a dQ kernel (K/V innermost) and a dK/dV kernel (Q innermost)
-— so training never materializes an L x L score matrix either.
+recomputes softmax probabilities per block pair from (q, k, lse) ONCE and
+feeds dQ, dK and dV from them — 5 products a visited sub-tile (S, dP, dQ,
+dV, dK) where a dQ pass and a dK/dV pass pay 3 + 4 — so training never
+materializes an L x L score matrix either.  Its grid is the forward's
+(K/V innermost, a query group's members apart): dQ accumulates across the
+key walk as the forward's output does, dK and dV in f32 VMEM scratch of a
+K/V head's WHOLE key length, written once when the head's sweep ends.  No
+partial sum leaves VMEM: the fused backward that was tried before and
+deleted (PR 21; d611cc2) wrote each block pair's dq as an f32 partial to
+HBM and summed the partials in XLA, and was 40% slower for it.
 
 Causal calls do work only on the causal triangle: grid blocks above the
 diagonal are skipped (where a window leaves fewer blocks a row than the
@@ -73,6 +80,9 @@ def _interpret() -> bool:
 
 
 LANES = 128  # lane padding for per-row (lse/delta) tensors, TPU tile width
+# Mosaic's default scoped-VMEM limit on a v5e: what one block pair's walk
+# (operand blocks double-buffered, the f32 score-sized values) fits under
+_STEP_VMEM = 16 * 2 ** 20
 ALL = slice(None)
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
@@ -140,33 +150,17 @@ def _slabs(n0, n1, n2, n3, unit, base):
     return pieces
 
 
-def _block_groups(bq, bk, tq, tk, off, by_key=False, window=None):
+def _block_groups(bq, bk, tq, tk, off, window=None):
     """The pieces of a block that the mask leaves, as static slices.
 
     A list of ``(rows, [(cols, hi, lo), ...])``: per query sub-tile its key
     slab on the window's lower edge, its unmasked slab and its slab on the
-    diagonal (``by_key``: per key sub-tile its query slab on the diagonal,
-    its unmasked one and the one on the window's edge; the pair then reads
-    ``(cols, [(rows, hi, lo), ...])``).  ``hi`` and ``lo`` are the piece's
-    own offsets for ``_scores``, each ``None`` where that edge needs no
-    mask; a sub-tile that sees nothing keeps its entry, with no pieces."""
+    diagonal.  ``hi`` and ``lo`` are the piece's own offsets for
+    ``_scores``, each ``None`` where that edge needs no mask; a sub-tile
+    that sees nothing keeps its entry, with no pieces."""
     plan = tile_plan(bq, bk, tq, tk, off, window=window)
     under = lambda o: None if window is None else o - window
     groups = []
-    if by_key:
-        for c in range(bk // tk):
-            # down a key column the diagonal comes first, the window's
-            # edge second: rows before ``vis`` do not see the column yet,
-            # rows from ``end`` on have left it behind
-            vis, full, lo, end = (sum(p[n] <= c for p in plan)
-                                  for n in (3, 2, 1, 0))
-            pieces = _slabs(vis, full, lo, end, tq,
-                            lambda v, c=c: off + v * tq - c * tk)
-            groups.append((slice(c * tk, (c + 1) * tk),
-                           [(s, o if first else None,
-                             under(o) if second else None)
-                            for s, first, second, o in pieces]))
-        return groups
     for a, row in enumerate(plan):
         # along a query row the window's edge comes first, the diagonal
         # second
@@ -200,17 +194,16 @@ def _inside(off, bq, bk, window):
     return below if window is None else below & (off < window - bq + 1)
 
 
-def _visit(i, j, *, ni, nk, bq, bk, causal, body, by_key=False, window=None,
-           live=None):
-    """Run ``body(major, pieces)`` over the part of grid block (i, j) that
-    the mask leaves.  Not causal, or wholly inside the mask: the block in
-    one unmasked piece.  Crossed by the diagonal or by the window's lower
-    edge: sub-tiled, unrolled from the static geometry.  Wholly above the
-    one or under the other: nothing.  ``live`` (traced; ``_walks``) is
-    false on a grid step past its row's or column's last block, whose
-    (i, j) names no block and whose offset may still read as a crossing
-    one: nothing there either."""
-    whole = lambda: body(ALL, [(ALL, None, None)])
+def _visit(i, j, *, ni, nk, bq, bk, causal, body, window=None, live=None):
+    """Run ``body(groups)`` (``_block_groups``' list) on the part of grid
+    block (i, j) that the mask leaves.  Not causal, or wholly inside the
+    mask: the block in one unmasked piece.  Crossed by the diagonal or by
+    the window's lower edge: sub-tiled, from the static geometry.  Wholly
+    above the one or under the other: nothing.  ``live`` (traced;
+    ``_key_walk``) is false on a grid step past its row's last block,
+    whose (i, j) names no block and whose offset may still read as a
+    crossing one: nothing there either."""
+    whole = lambda: body([(ALL, [(ALL, None, None)])])
     if not causal:
         return whole()
     static = ni * nk == 1       # the one block: i = j = 0, nothing to test
@@ -219,10 +212,8 @@ def _visit(i, j, *, ni, nk, bq, bk, causal, body, by_key=False, window=None,
     if not static and (window is None or window > bq + bk - 2):
         when(_inside(off, bq, bk, window))(whole)
     for o in _crossing_offsets(ni, nk, bq, bk, window):
-        def crossed(o=o):
-            for major, pieces in _block_groups(bq, bk, *_tiles(bq, bk), o,
-                                               by_key, window):
-                body(major, pieces)
+        crossed = lambda o=o: body(_block_groups(bq, bk, *_tiles(bq, bk), o,
+                                                 window))
         crossed() if static else when(off == o)(crossed)
 
 
@@ -256,7 +247,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
     # (sequence, head_dim) pair, not (head, head_dim).  ``state`` is the
     # (m, l, acc) scratch that carries the online softmax across the K/V
     # grid dimension; a ``_one_block`` call has none
-    keys, _ = _walks(ni, nk, bq, bk, causal, window)
+    keys = _key_walk(ni, nk, bq, bk, causal, window)
     i = pl.program_id(2)
     jj = pl.program_id(3)
     j, live = keys.block(i, jj)
@@ -313,7 +304,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
         acc_ref[rows, :] = acc_ref[rows, :] * corr + acc
         m_ref[rows, :] = m
 
-    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
+    def _block(groups):
+        for rows, pieces in groups:
+            _step(rows, pieces)
+
+    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_block,
            window=window, live=live)
 
     if state:
@@ -343,67 +338,51 @@ def _key_bounds(i, nk, bq, bk, window, xp=jnp):
     return first, last
 
 
-def _query_bounds(j, ni, bq, bk, window, xp=jnp):
-    """First and last query block that reaches key block ``j``."""
-    first = xp.minimum((j * bk) // bq, ni - 1)
-    last = ni - 1 if window is None else xp.minimum(
-        (j * bk + bk + window - 2) // bq, ni - 1)
-    return first, last
-
-
 class _Walk(NamedTuple):
-    """One kernel's innermost grid dimension.  ``span``: its extent.
-    ``block(major, step)``: for the kernel body, the block that grid step
-    stands for and whether it is one its row (its column) has (``None``:
-    every step is).  ``loaded(major, step)``: for the block specs' index
-    maps, the block the step loads; a step that the mask skips loads the
-    nearest block its row (its column) needs, which is the one already
-    there or the next one wanted, so a skipped step moves no data.
-    ``held``: per row (column) the blocks its steps stand for, static, for
-    the counter."""
+    """The kernels' innermost grid dimension: the key blocks of a query
+    block.  ``span``: its extent.  ``block(i, step)``: for the kernel
+    body, the key block that grid step stands for and whether it is one
+    query block ``i``'s row has (``None``: every step is).  ``loaded(i,
+    step)``: for the block specs' index maps, the block the step loads; a
+    step that the mask skips loads the nearest block its row needs, which
+    is the one already there or the next one wanted, so a skipped step
+    moves no data.  ``held``: per row the blocks its steps stand for,
+    static, for the counter."""
     span: int
     block: Callable
     loaded: Callable
     held: list
 
 
-def _walk(n, n_major, bounds):
-    """The walk along ``n`` blocks for each of ``n_major`` rows (columns)
-    with ``bounds(major) -> (first, last)``: as many steps as the longest
-    run ``first..last`` has, step ``s`` standing for block ``first + s``.
-    Where some row needs all ``n`` the steps are the blocks themselves."""
-    runs = list(zip(*(np.broadcast_to(x, (n_major,)).tolist()
-                      for x in bounds(np.arange(n_major), xp=np))))
+def _key_walk(ni, nk, bq, bk, causal, window):
+    """The walk over key blocks for a query block: forward ``(b, h, ni,
+    span)``, backward ``(b, kv, rep, ni, span)``.  As many steps as the
+    longest run ``first..last`` of key blocks any query block's rows of
+    the mask reach, step ``s`` standing for block ``first + s``.  Where
+    some row needs all ``nk`` (no window), with one block, or with no
+    mask, the steps are the blocks themselves."""
+    if not causal or ni * nk == 1:
+        return _Walk(nk, lambda i, step: (step, None), lambda i, step: step,
+                     [range(nk)] * ni)
+    bounds = functools.partial(_key_bounds, nk=nk, bq=bq, bk=bk,
+                               window=window)
+    runs = list(zip(*(np.broadcast_to(x, (ni,)).tolist()
+                      for x in bounds(np.arange(ni), xp=np))))
     span = max(1 + max(b - a for a, b in runs), 1)
-    whole = span == n
+    whole = span == nk
     held = [range(0 if whole else a, min(a + span, b + 1)) for a, b in runs]
 
-    def block(major, step):
+    def block(i, step):
         if whole:
             return step, None
-        first, last = bounds(major)
+        first, last = bounds(i)
         return first + step, first + step <= last
 
-    def loaded(major, step):
-        first, last = bounds(major)
+    def loaded(i, step):
+        first, last = bounds(i)
         return jnp.clip(step if whole else first + step, first, last)
 
     return _Walk(span, block, loaded, held)
-
-
-def _walks(ni, nk, bq, bk, causal, window):
-    """``(keys, queries)``: the walk over key blocks for a query block
-    (forward and dQ, grid ``(b, h, ni, keys.span)``) and over query blocks
-    for a key block (dK/dV, ``queries.span`` steps a group member).  One
-    block, or no mask: every step its own block."""
-    if not causal or ni * nk == 1:
-        own = lambda major, step: (step, None), lambda major, step: step
-        return (_Walk(nk, *own, [range(nk)] * ni),
-                _Walk(ni, *own, [range(ni)] * nk))
-    return (_walk(nk, ni, functools.partial(_key_bounds, nk=nk, bq=bq, bk=bk,
-                                            window=window)),
-            _walk(ni, nk, functools.partial(_query_bounds, ni=ni, bq=bq,
-                                            bk=bk, window=window)))
 
 
 def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
@@ -421,7 +400,7 @@ def _flash_forward(q, k, v, causal=False, with_lse=False, window=None):
     bq, bk = _block_size(lq, BQ), _block_size(lk, BK)
     scale = 1.0 / (d ** 0.5)
     ni, nk = lq // bq, lk // bk
-    keys, _ = _walks(ni, nk, bq, bk, causal, window)
+    keys = _key_walk(ni, nk, bq, bk, causal, window)
     grid = (b, h, ni, keys.span)
     _log_tiles(lq, lk, bq, bk, causal, window)
     # [B, L, H, D] -> [B, H, L, D]: the kernel tiles over (seq, head_dim)
@@ -477,119 +456,103 @@ def _p_ds(q, k, v, do, lse, delta, scale, hi, lo):
     return p, p * (_dot(do, v, _NT) - delta) * scale
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
-                   *acc, scale: float, ni: int, nk: int, bq: int, bk: int,
-                   causal: bool, window: Optional[int] = None):
-    """dQ pass: grid (b, h, iq, jk), K/V innermost (the key blocks query
-    block iq can need: ``_walks``); accumulates
-    dq_i = sum_j ds_ij k_j with ds = p * (do v^T - delta) * scale, in the
-    f32 scratch ``acc`` (a ``_one_block`` call has none)."""
-    keys, _ = _walks(ni, nk, bq, bk, causal, window)
-    i = pl.program_id(2)
-    jj = pl.program_id(3)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, dk_ref,
+                dv_ref, *acc, scale: float, ni: int, nk: int, rep: int,
+                bq: int, bk: int, causal: bool, window: Optional[int] = None):
+    """The backward pass in one kernel: grid (b, kv_head, member, iq, jk),
+    K/V innermost (the key blocks query block iq can need: ``_key_walk``).
+    p and ds = p * (do v^T - delta) * scale are computed once a piece and
+    feed all three gradients: dq_i = sum_j ds_ij k_j in the ``(bq, d)`` f32
+    scratch that lives across jk; dv_j = sum_i p_ij^T do_i and dk_j = sum_i
+    ds_ij^T q_i in f32 scratch of the WHOLE key length (``[nk, bk, d]``,
+    indexed by the walked block), which lives across the head's whole
+    sweep, the ``rep`` query heads that share the K/V head included
+    (member slow, query block fast: the grouped dk / dv come out summed
+    over their query group), and is cast into the whole-sequence output
+    blocks at the head's last step.  Nothing partial leaves VMEM.  A
+    ``_one_block`` call has no scratch: dq's rows and the key sub-tiles'
+    sums are written straight out."""
+    keys = _key_walk(ni, nk, bq, bk, causal, window)
+    member, i, jj = (pl.program_id(n) for n in (2, 3, 4))
     j, live = keys.block(i, jj)
+    tk = _tiles(bq, bk)[1]
+    row_end = jj == keys.span - 1
 
     if acc:
-        acc_ref, = acc
+        dq_acc, dk_acc, dv_acc = acc
 
         @pl.when(jj == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def _init_row():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _step(rows, pieces):
-        if not pieces:
-            return
-        q = q_ref[0, 0, rows, :]
-        do = do_ref[0, 0, rows, :]
-        lse = lse_ref[0, 0, rows, :1]                    # [rows, 1]
-        delta = dl_ref[0, 0, rows, :1]
-        parts = []
-        for cols, hi, lo in pieces:
-            k = k_ref[0, 0, cols, :]
-            _, ds = _p_ds(q, k, v_ref[0, 0, cols, :], do, lse, delta,
-                          scale, hi, lo)
-            parts.append(_dot(ds.astype(k.dtype), k, _NN))   # [rows, d]
-        dq = functools.reduce(jnp.add, parts)
-        if acc:
-            acc_ref[rows, :] += dq
-        else:
-            dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
-
-    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
-           window=window, live=live)
-
-    if acc:
-        @pl.when(jj == keys.span - 1)
-        def _finish():
-            dq_ref[0, 0, :, :] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                    dk_ref, dv_ref, *acc, scale: float, ni: int, nk: int,
-                    rep: int, bq: int, bk: int, causal: bool,
-                    window: Optional[int] = None):
-    """dK/dV pass: grid (b, kv_head, jk, it), Q innermost; accumulates
-    dv_j = sum_i p^T do_i and dk_j = sum_i ds^T q_i in the f32 scratch
-    ``acc`` (a ``_one_block`` call has none).
-
-    Grouped-query attention folds the ``rep`` query heads sharing each
-    K/V head into the innermost grid dim: it = member * nqw + iq (member
-    slow, Q block fast, ``nqw`` the query blocks a key block can need:
-    ``_walks``); the dk/dv accumulators run over all of it, so the
-    grouped dk/dv gradients come out summed over their query group without
-    ever materializing per-query-head dk/dv."""
-    _, queries = _walks(ni, nk, bq, bk, causal, window)
-    j = pl.program_id(2)
-    it = pl.program_id(3)
-    i, live = queries.block(j, it % queries.span if rep > 1 else it)
-
-    if acc:
-        dk_acc, dv_acc = acc
-
-        @pl.when(it == 0)
-        def _init():
+        @pl.when((member == 0) & (i == 0) & (jj == 0))
+        def _init_head():
+            # keys that no query reaches (lq < lk) keep these zeros
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _step(cols, pieces):
-        if not pieces:
-            if not acc:     # keys past the last query: nothing reaches them
-                for ref in (dk_ref, dv_ref):
-                    ref[0, 0, cols, :] = jnp.zeros(
-                        (cols.stop - cols.start, ref.shape[-1]), ref.dtype)
-            return
-        k = k_ref[0, 0, cols, :]
-        v = v_ref[0, 0, cols, :]
-        parts = []
-        for rows, hi, lo in pieces:
+    def _block(groups):
+        # {first key of a sub-tile: its f32 sum over the block's query
+        # sub-tiles}, carried as values through the unrolled sub-tiles
+        dk, dv = {}, {}
+
+        def add(sums, cols, x):
+            start, stop, _ = cols.indices(bk)
+            for c in range(start, stop, tk):
+                part = x[c - start:c - start + tk]
+                sums[c] = sums[c] + part if c in sums else part
+
+        for rows, pieces in groups:
+            if not pieces:
+                continue
             q = q_ref[0, 0, rows, :]
             do = do_ref[0, 0, rows, :]
-            p, ds = _p_ds(q, k, v, do, lse_ref[0, 0, rows, :1],
-                          dl_ref[0, 0, rows, :1], scale, hi, lo)
-            parts.append((_dot(p.astype(do.dtype), do, _TN),     # [cols, d]
-                          _dot(ds.astype(q.dtype), q, _TN)))
-        dv, dk = (functools.reduce(jnp.add, x) for x in zip(*parts))
-        if acc:
-            dv_acc[cols, :] += dv
-            dk_acc[cols, :] += dk
-        else:
-            dk_ref[0, 0, cols, :] = dk.astype(dk_ref.dtype)
-            dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
+            lse = lse_ref[0, 0, rows, :1]                    # [rows, 1]
+            delta = dl_ref[0, 0, rows, :1]
+            parts = []
+            for cols, hi, lo in pieces:
+                k = k_ref[0, 0, cols, :]
+                p, ds = _p_ds(q, k, v_ref[0, 0, cols, :], do, lse, delta,
+                              scale, hi, lo)
+                ds = ds.astype(k.dtype)
+                parts.append(_dot(ds, k, _NN))               # [rows, d]
+                add(dv, cols, _dot(p.astype(do.dtype), do, _TN))
+                add(dk, cols, _dot(ds, q, _TN))              # [cols, d]
+            dq = functools.reduce(jnp.add, parts)
+            if acc:
+                dq_acc[rows, :] += dq
+            else:
+                dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+        for c in range(0, bk, tk):
+            at = slice(c, c + tk)
+            if not acc:     # keys past the last query: nothing reaches them
+                for ref, sums in ((dk_ref, dk), (dv_ref, dv)):
+                    ref[0, 0, at, :] = sums.get(c, jnp.zeros(
+                        (tk, ref.shape[-1]), jnp.float32)).astype(ref.dtype)
+            elif c in dk:
+                dk_acc[j, at, :] += dk[c]
+                dv_acc[j, at, :] += dv[c]
 
-    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_step,
-           by_key=True, window=window, live=live)
+    _visit(i, j, ni=ni, nk=nk, bq=bq, bk=bk, causal=causal, body=_block,
+           window=window, live=live)
 
     if acc:
-        @pl.when(it == queries.span * rep - 1)
-        def _finish():
-            dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
-            dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
+        @pl.when(row_end)
+        def _finish_row():
+            dq_ref[0, 0, :, :] = dq_acc[...].astype(dq_ref.dtype)
+
+        @pl.when((member == rep - 1) & (i == ni - 1) & row_end)
+        def _finish_head():
+            for n in range(nk):
+                at = slice(n * bk, (n + 1) * bk)
+                dk_ref[0, 0, at, :] = dk_acc[n].astype(dk_ref.dtype)
+                dv_ref[0, 0, at, :] = dv_acc[n].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, window=None):
     """Blockwise flash backward: O(L) memory, no L x L score materialization
     (the FlashAttention-2 construction: recompute p from q, k and the saved
-    log-sum-exp, accumulate dq / dk / dv per block pair)."""
+    log-sum-exp, accumulate dq / dk / dv per block pair), one Mosaic call."""
     b, lq, h, d = q.shape
     lk, kv, dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = h // kv             # queries per K/V head (1 = MHA, >1 = GQA)
@@ -603,55 +566,42 @@ def _flash_backward(q, k, v, o, lse, g, causal, window=None):
                        ot.astype(jnp.float32))
     delta = jnp.broadcast_to(delta[..., None], (b, h, lq, LANES))
     vma = jax.typeof(qt).vma
-    keys, queries = _walks(ni, nk, bq, bk, causal, window)
-    nqw = queries.span
-    row = lambda m: pl.BlockSpec((1, 1, bq, m),
-                                 lambda b_, h_, i, j: (b_, h_, i, 0),
-                                 memory_space=pltpu.VMEM)
-    col = lambda m: pl.BlockSpec((1, 1, bk, m),
-                                 lambda b_, h_, i, j: (b_, h_ // rep,
-                                                       keys.loaded(i, j), 0),
-                                 memory_space=pltpu.VMEM)
-    # dkv grid (b, kv_head, j, it) with it = member * nqw + iq: per-q-head
-    # operands map query head g * rep + it // nqw; K/V-side blocks map the
-    # group head directly (with rep == 1 these reduce to the plain maps)
-    rowT = lambda m: pl.BlockSpec(
-        (1, 1, bq, m),
-        lambda b_, g, j, it: (b_, g * rep + it // nqw,
-                              queries.loaded(j, it % nqw), 0),
+    keys = _key_walk(ni, nk, bq, bk, causal, window)
+    # grid (b, kv_head, member, iq, jk): the query side's blocks are those
+    # of query head g * rep + member, the K/V side's the group's own
+    row = lambda m: pl.BlockSpec(
+        (1, 1, bq, m), lambda b_, g, m_, i, j: (b_, g * rep + m_, i, 0),
         memory_space=pltpu.VMEM)
-    colT = lambda m: pl.BlockSpec((1, 1, bk, m),
-                                  lambda b_, g, j, it: (b_, g, j, 0),
-                                  memory_space=pltpu.VMEM)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-
-    dqt = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, ni=ni, nk=nk,
+    col = lambda m: pl.BlockSpec(
+        (1, 1, bk, m), lambda b_, g, m_, i, j: (b_, g, keys.loaded(i, j), 0),
+        memory_space=pltpu.VMEM)
+    # dk / dv leave as blocks of the whole sequence, one a K/V head
+    whole = lambda m: pl.BlockSpec(
+        (1, 1, lk, m), lambda b_, g, m_, i, j: (b_, g, 0, 0),
+        memory_space=pltpu.VMEM)
+    held = not _one_block(causal, ni, nk, rep)
+    # what the walk of one block pair took under the default limit, and on
+    # top of it what is held for a whole head: the two f32 sums and the two
+    # output blocks (double-buffered), their lanes in 128s
+    vmem = _STEP_VMEM + held * lk * (4 + 2 * q.dtype.itemsize) * sum(
+        -(-m // LANES) * LANES for m in (d, dv))
+    dqt, dkt, dvt = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, ni=ni, nk=nk, rep=rep,
                           bq=bq, bk=bk, causal=causal, window=window),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma),
-        grid=(b, h, ni, keys.span),
-        in_specs=[row(d), col(d), col(dv), row(dv), row(LANES), row(LANES)],
-        out_specs=row(d),
-        scratch_shapes=[] if _one_block(causal, ni, nk) else [
-            pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=params, interpret=_interpret(), name="flash_dq",
-    )(qt, kt, vt, gt, lse, delta)
-
-    dkt, dvt = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, ni=ni, nk=nk,
-                          rep=rep, bq=bq, bk=bk, causal=causal,
-                          window=window),
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype, vma=vma),
+        out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype, vma=vma),
+                   jax.ShapeDtypeStruct(kt.shape, k.dtype, vma=vma),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype, vma=vma)],
-        grid=(b, kv, nk, nqw * rep),
-        in_specs=[rowT(d), colT(d), colT(dv), rowT(dv), rowT(LANES),
-                  rowT(LANES)],
-        out_specs=[colT(d), colT(dv)],
-        scratch_shapes=[] if _one_block(causal, ni, nk, rep) else [
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32)],
-        compiler_params=params, interpret=_interpret(), name="flash_dkv",
+        grid=(b, kv, rep, ni, keys.span),
+        in_specs=[row(d), col(d), col(dv), row(dv), row(LANES), row(LANES)],
+        out_specs=[row(d), whole(d), whole(dv)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                        pltpu.VMEM((nk, bk, d), jnp.float32),
+                        pltpu.VMEM((nk, bk, dv), jnp.float32)] if held else [],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=_interpret(), name="flash_bwd",
     )(qt, kt, vt, gt, lse, delta)
     return (dqt.transpose(0, 2, 1, 3), dkt.transpose(0, 2, 1, 3),
             dvt.transpose(0, 2, 1, 3))
@@ -681,8 +631,8 @@ def _log_fallback(reason: str, q) -> None:
 
 
 TILE_COUNTS: dict = {}   # (lq, lk, causal, window) -> (visited, total, masked)
-# same key -> ((walked, with work), the same for dkv, blocks of the square):
-# grid steps a query head, counted as the kernels walk them
+# same key -> (walked, with work, blocks of the square): grid steps a query
+# head, as the forward and the backward kernel both walk them
 GRID_COUNTS: dict = {}
 
 
@@ -703,32 +653,32 @@ def _log_tiles(lq: int, lk: int, bq: int, bk: int, causal: bool,
               for i in range(ni) for j in range(nk)}
     visited, masked = map(sum, zip(*counts.values()))
     TILE_COUNTS[key] = (visited, (lq // tq) * (lk // tk), masked)
-    keys, queries = _walks(ni, nk, bq, bk, causal, window)
-    # a step holds work where it stands for a block its row (column) has
-    # and the mask leaves some of that block
-    work = lambda walk, at: sum(
-        counts[at(major, block)][0] > 0
-        for major, blocks in enumerate(walk.held) for block in blocks)
+    keys = _key_walk(ni, nk, bq, bk, causal, window)
+    # a step holds work where it stands for a block its row has and the
+    # mask leaves some of that block
     GRID_COUNTS[key] = (
-        (ni * keys.span, work(keys, lambda i, j: (i, j))),
-        (nk * queries.span, work(queries, lambda j, i: (i, j))), ni * nk)
+        ni * keys.span,
+        sum(counts[i, j][0] > 0 for i, row in enumerate(keys.held)
+            for j in row), ni * nk)
     import logging
     logging.getLogger(__name__).info(tiles_line(key))
 
 
 def tiles_line(key: tuple) -> str:
     """``flash tiles L=1024 causal: visited 10/16, masked 4; grid steps a
-    head 1 of 1 walked, 1 with work (dkv 1 of 1, 1)``; a windowed shape
-    reads ``L=8192 causal window 1024: ...``"""
+    head 1 of 1 walked, 1 with work, forward and backward (5 products a
+    visited sub-tile backward, not 7)``; a windowed shape reads ``L=8192
+    causal window 1024: ...``"""
     lq, lk, causal, window = key
     mask = "causal" if causal else "full"
     if window is not None:
         mask += f" window {window}"
-    (walked, work), (walked_t, work_t), blocks = GRID_COUNTS[key]
+    walked, work, blocks = GRID_COUNTS[key]
     return ("flash tiles L=%s %s: visited %d/%d, masked %d; grid steps a "
-            "head %d of %d walked, %d with work (dkv %d of %d, %d)" % (
+            "head %d of %d walked, %d with work, forward and backward (5 "
+            "products a visited sub-tile backward, not 7)" % (
                 lq if lq == lk else f"{lq}x{lk}", mask, *TILE_COUNTS[key],
-                walked, blocks, work, walked_t, blocks, work_t))
+                walked, blocks, work))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -222,7 +222,13 @@ def measure_step_time(model, variables, sample_batch: np.ndarray,
     # train_global with a run-specific model, so caching buys nothing
     # graftlint: disable=R2 -- intentional single probe compile per run
     fn = jax.jit(fwd_bwd)
-    x = jnp.asarray(sample_batch)
+    # the probe times ONE device.  A replicated state (dense sync) hands it
+    # worker 0's row still laid over every chip, and a jit over several
+    # devices is a partitioned program, which a Mosaic kernel refuses
+    # ("cannot be automatically partitioned"): commit the inputs to this
+    # process's first device
+    params, rest, x = jax.device_put((params, rest, jnp.asarray(sample_batch)),
+                                     jax.local_devices()[0])
     jax.block_until_ready(fn(params, rest, x))  # compile
     t0 = time.perf_counter()
     for _ in range(num_batches):

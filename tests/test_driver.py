@@ -160,3 +160,42 @@ class TestCompileCacheTelemetry:
         assert res["compile_cache"]["enabled"] is False
         assert res["compile_cache"]["hits"] >= 0
         assert res["compile_cache"]["misses"] >= 0
+
+
+def test_probe_commits_its_inputs_to_one_device(devices, monkeypatch):
+    """Under dense sync the probe is handed worker 0's row of a replicated
+    state, still laid over every chip.  A jit over several devices is a
+    partitioned program, and a Mosaic kernel in it is refused at lowering
+    ("cannot be automatically partitioned": ``chip_smoke.py`` on four
+    chips, phase ``sync_twins``; interpret mode on the CPU cannot show
+    it), so the probe, which times one device, puts its inputs there."""
+    import flax.linen as nn
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu import probe
+
+    class Model(nn.Module):
+        @nn.compact
+        def __call__(self, x, train=False):
+            return nn.Dense(4)(x)
+
+    model = Model()
+    x = np.ones((2, 8), np.float32)
+    variables = jax.device_put(
+        model.init(jax.random.key(0), x),
+        NamedSharding(Mesh(np.array(devices[:4]), ("data",)), P()))
+    assert all(len(a.sharding.device_set) == 4
+               for a in jax.tree.leaves(variables))
+    seen, jit = [], jax.jit
+
+    def spying_jit(fn):
+        def call(*args):
+            seen.append({d for a in jax.tree.leaves(args)
+                         for d in a.sharding.device_set})
+            return jit(fn)(*args)
+        return call
+
+    monkeypatch.setattr(probe.jax, "jit", spying_jit)
+    assert probe.measure_step_time(model, variables, x, num_batches=2) > 0
+    assert seen == [{jax.local_devices()[0]}] * 3

@@ -107,7 +107,6 @@ class TestFlashCausalTiles:
         assert len(plan) == bq // tile
         assert pallas_ops.tile_counts(plan) == (visited, masked)
 
-    @pytest.mark.parametrize("by_key", [False, True])
     @pytest.mark.parametrize("bq,bk,tq,tk,off,window", [
         (1024, 1024, 256, 256, 0, None), (512, 512, 128, 128, 0, None),
         (256, 384, 128, 128, 128, None), (256, 384, 128, 128, -128, None),
@@ -119,9 +118,8 @@ class TestFlashCausalTiles:
         (512, 512, 128, 128, 0, 16),         # both edges in one sub-tile
         (256, 256, 128, 128, 256, 300),      # the edge alone, shifted
         (256, 384, 128, 128, 128, 130)])
-    def test_block_groups_cover_the_triangle_once(self, pallas_ops, by_key,
-                                                  bq, bk, tq, tk, off,
-                                                  window):
+    def test_block_groups_cover_the_triangle_once(self, pallas_ops, bq, bk,
+                                                  tq, tk, off, window):
         """Every visible (query, key) pair lies in exactly one piece, no
         unmasked piece holds a hidden pair, and a masked piece's own
         offsets reproduce the block's mask."""
@@ -130,10 +128,9 @@ class TestFlashCausalTiles:
         if window is not None:
             visible &= c > r - window
         seen = np.zeros((bq, bk), int)
-        for major, pieces in pallas_ops._block_groups(bq, bk, tq, tk, off,
-                                                      by_key, window):
-            for minor, hi, lo in pieces:
-                rows, cols = (minor, major) if by_key else (major, minor)
+        for rows, pieces in pallas_ops._block_groups(bq, bk, tq, tk, off,
+                                                     window):
+            for cols, hi, lo in pieces:
                 n, m = seen[rows, cols].shape
                 own = np.ones((n, m), bool)
                 if hi is not None:
@@ -144,11 +141,14 @@ class TestFlashCausalTiles:
                 seen[rows, cols] += own
         np.testing.assert_array_equal(seen, visible.astype(int))
 
-    @pytest.mark.parametrize("l,counts", [(512, (3, 4, 2)),
+    @pytest.mark.parametrize("l,counts", [(256, (1, 1, 1)),
+                                          (512, (3, 4, 2)),
                                           (1024, (10, 16, 4))])
     def test_causal_mha_matches_dense(self, pallas_ops, l, counts):
-        """d = 64, one block a head: more than one sub-tile visited, at
-        least one skipped and one masked."""
+        """d = 64, one block a head: one sub-tile (L = 256), then more than
+        one visited, at least one skipped and one masked.  The one backward
+        kernel writes dq's rows straight out and carries dk and dv over the
+        query sub-tiles as values."""
         _flash_vs_dense(*_qkv(l=l, h=2, d=64, b=1, seed=l), causal=True)
         assert pallas_ops.TILE_COUNTS == {(l, l, True, None): counts}
 
@@ -165,19 +165,88 @@ class TestFlashCausalTiles:
         _flash_vs_dense(*_qkv(l=512, h=2, d=64, b=1, seed=5), causal=False)
         assert pallas_ops.TILE_COUNTS == {(512, 512, False, None): (4, 4, 0)}
 
-    @pytest.mark.parametrize("lq,lk,counts", [
-        (512, 512, (10, 16, 4)),     # lq = 2 * BQ: grid skip + tile skip
-        (256, 512, (3, 8, 2)),       # lq != lk: top-left aligned mask
-        (768, 768, (21, 36, 6))])    # three blocks a side
+    @pytest.mark.parametrize("lq,lk,counts,h,kv,d,dv", [
+        (512, 512, (10, 16, 4), 2, 2, 64, 64),   # grid skip + tile skip
+        (256, 512, (3, 8, 2), 2, 2, 64, 64),     # lq != lk: top-left aligned
+        (768, 768, (21, 36, 6), 2, 2, 64, 64),   # three blocks a side
+        # the backward kernel's dk / dv live across a whole K/V head: summed
+        # over the group's members (rep = 2) in the same sweep
+        (768, 768, (21, 36, 6), 4, 2, 64, 64),
+        (256, 512, (3, 8, 2), 4, 2, 64, 64),     # ... key blocks no query has
+        (512, 512, (10, 16, 4), 2, 2, 192, 128),  # two widths
+        (768, 768, (21, 36, 6), 4, 1, 192, 128),  # ... and a group of four
+        (512, 256, (7, 8, 2), 4, 2, 64, 64),      # queries past the last key
+    ])
     def test_grid_skip_and_tile_skip_together(self, pallas_ops, monkeypatch,
-                                              lq, lk, counts):
+                                              lq, lk, counts, h, kv, d, dv):
         monkeypatch.setattr(pallas_ops, "BQ", 256)
         monkeypatch.setattr(pallas_ops, "BK", 256)
         monkeypatch.setattr(pallas_ops, "TILE", 128)
-        q, _, _ = _qkv(l=lq, h=2, d=64, b=1, seed=lq)
-        _, k, v = _qkv(l=lk, h=2, d=64, b=1, seed=lk + 1)
+        q, _, _ = _qkv(l=lq, h=h, d=d, b=1, seed=lq)
+        _, k, _ = _qkv(l=lk, h=kv, d=d, b=1, seed=lk + 1)
+        _, _, v = _qkv(l=lk, h=kv, d=dv, b=1, seed=lk + 1)
         _flash_vs_dense(q, k, v, causal=True)
         assert pallas_ops.TILE_COUNTS == {(lq, lk, True, None): counts}
+
+    # (b, l, h, kv, d, dv, window) of the cells' calls
+    CELL_CALLS = {"gpt2s_train_1k": (4, 1024, 12, 12, 64, 64, None),
+                  "mellum2 full": (1, 8192, 32, 4, 128, 128, None),
+                  "mellum2 sliding": (1, 8192, 32, 4, 128, 128, 1024),
+                  "kanana2": (1, 8192, 32, 32, 192, 128, None)}
+
+    @staticmethod
+    def _grad_jaxpr(pallas_ops, monkeypatch, shape):
+        monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+        b, l, h, kv, d, dv, window = shape
+        loss = lambda q, k, v: pallas_ops.flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32).sum()
+        sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        return jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+            sds(b, l, h, d), sds(b, l, kv, d), sds(b, l, kv, dv)).jaxpr
+
+    @pytest.mark.parametrize("cell,grid", [
+        ("gpt2s_train_1k", (4, 12, 1, 1, 1)), ("mellum2 full", (1, 4, 8, 8, 8)),
+        ("mellum2 sliding", (1, 4, 8, 8, 2)), ("kanana2", (1, 32, 1, 8, 8))])
+    def test_gradient_is_two_kernels_and_no_partial_sum(
+            self, pallas_ops, monkeypatch, cell, grid):
+        """``jax.grad`` of a flash call holds exactly two ``pallas_call``s,
+        the forward and the one backward, and no reduction but the
+        backward's ``delta = rowsum(do * o)``: dq, dk and dv leave the
+        kernel whole (the fused backward that was closed wrote dq as f32
+        partials a key block and summed them in XLA)."""
+        shape = self.CELL_CALLS[cell]
+        eqns = self._grad_jaxpr(pallas_ops, monkeypatch, shape).eqns
+        calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls] == ["flash_fwd",
+                                                     "flash_bwd"]
+        assert tuple(calls[1].params["grid_mapping"].grid) == grid
+        outs = [v.aval.shape for v in calls[1].outvars]
+        b, l, h, kv, d, dv, _ = shape
+        assert outs == [(b, h, l, d), (b, kv, l, d), (b, kv, l, dv)]
+        after = eqns[eqns.index(calls[1]) + 1:]
+        assert {e.primitive.name for e in after} <= {"transpose"}
+        reductions = [e for e in eqns if e.primitive.name.startswith(
+            ("reduce", "dot_general", "cumsum"))]
+        assert [e.outvars[0].aval.shape for e in reductions[-1:]] == [
+            (b, h, l)]                                  # delta, before
+
+    @pytest.mark.parametrize("cell,held", [
+        ("gpt2s_train_1k", 0),                       # one block: no state
+        ("mellum2 full", 2 * 8192 * 128 * (4 + 2 * 2)),
+        ("mellum2 sliding", 2 * 8192 * 128 * (4 + 2 * 2)),
+        ("kanana2", 8192 * (256 + 128) * (4 + 2 * 2))])    # 192 lanes -> 256
+    def test_backward_asks_for_the_vmem_its_shape_needs(
+            self, pallas_ops, monkeypatch, cell, held):
+        """Mosaic's default 16 MiB for the walk of one block pair and, for
+        a call that holds state, the K/V head's two f32 sums and its two
+        whole-sequence bf16 output blocks twice (double-buffered): by
+        hand."""
+        call = [e for e in self._grad_jaxpr(
+            pallas_ops, monkeypatch, self.CELL_CALLS[cell]).eqns
+                if e.primitive.name == "pallas_call"][1]
+        params = call.params["compiler_params"]["mosaic_tpu"]
+        assert params.vmem_limit_bytes == 16 * 2 ** 20 + held
+        assert params.vmem_limit_bytes < 48 * 2 ** 20   # of a core's 128
 
 
 class TestGPT:

@@ -35,7 +35,7 @@ def one_chip():
 @pytest.mark.parametrize("b,lq,lk,h,kv,causal", [
     (4, 1024, 1024, 12, 12, True),     # gpt2s_train_1k: one block a head
     (1, 4096, 4096, 12, 12, True),     # grid skip + tile skip
-    (1, 2048, 2048, 16, 4, True),      # GQA: the group folded into dkv's grid
+    (1, 2048, 2048, 16, 4, True),      # GQA: the group's members a grid dim
     (2, 512, 1024, 4, 4, True),        # one block, keys past the last query
     (16, 512, 512, 12, 12, False),     # bert / vit: the untiled body
 ])
@@ -48,23 +48,30 @@ def test_flash_kernels_lower_for_v5e(one_chip, monkeypatch, b, lq, lk, h, kv,
         jnp.float32).sum()
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
         sds(b, lq, h, 64), sds(b, lk, kv, 64), sds(b, lk, kv, 64)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    # the forward and the one backward kernel
+    assert compiled.as_text().count("tpu_custom_call") == 2
     (visited, total, masked), = pallas_ops.TILE_COUNTS.values()
     assert (visited < total, masked > 0) == (causal, causal)
 
 
 @pytest.mark.parametrize("window,counts,grid", [
-    (1024, (150, 1024, 60), ((16, 15), (16, 15), 64)),
-    (None, (528, 1024, 32), ((64, 36), (64, 36), 64))])
+    (1024, (150, 1024, 60), (16, 15, 64)),
+    (None, (528, 1024, 32), (64, 36, 64))])
 def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
                                          counts, grid):
     """mellum2_train_8k's two attention calls: L = 8192, 32 query and 4
     key-value heads of width 128, the window of 1024 or none; the first
     calls through the blocks that carry their softmax state, on a grid of
-    the 2 blocks a row its window leaves.  The benchmark's picker names
-    each compiled call from its text (operand count and output kind), so
-    a change of a kernel's signature fails here and not as a metric
-    missing on the chip."""
+    the 2 blocks a row its window leaves.  Two Mosaic calls since ISSUE 31:
+    the forward and the one backward kernel, which holds a K/V head's dk
+    and dv sums for the whole sequence in VMEM (that it compiles at the
+    published shape is the test of its VMEM budget).  The benchmark's
+    picker names each compiled call from its text (operand count and
+    output kind): the accepted ``kernels/flash_*.json`` read the backward
+    (six operands, a tuple out) as ``flash_dkv`` and find no ``flash_dq``,
+    so the three flash rooflines read ``None`` until a ``benchmark`` PR
+    adds ``kernels/flash_bwd.json``; what that PR has to change fails here
+    and not as a metric missing on the chip."""
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
     monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
     monkeypatch.setattr(pallas_ops, "GRID_COUNTS", {})
@@ -75,11 +82,11 @@ def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
         sds(1, 8192, 32, 128), sds(1, 8192, 4, 128),
         sds(1, 8192, 4, 128)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
     calls = [line.strip() for line in text.splitlines()
              if "tpu_custom_call" in line]
     assert sorted(kernel_of(line, load_kernels()) for line in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd"]
+        "flash_dkv", "flash_fwd"]
     assert pallas_ops.TILE_COUNTS == {(8192, 8192, True, window): counts}
     assert pallas_ops.GRID_COUNTS == {(8192, 8192, True, window): grid}
 
@@ -87,8 +94,10 @@ def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
 def test_latent_attention_lowers_for_v5e(one_chip, monkeypatch):
     """kanana2_train_8k's attention call: L = 8192, 32 heads, scores 192
     wide (a lane tile and a half) and values 128, causal, the rotary key
-    already copied to the heads: three Mosaic calls whose operand counts
-    the accepted ``kernels/flash_*.json`` pick, on the full layer's walk."""
+    already copied to the heads: two Mosaic calls, which the accepted
+    ``kernels/flash_*.json`` read as ``flash_fwd`` and ``flash_dkv`` (the
+    one backward kernel: 24 MiB of a head's sums and output blocks beside
+    its walk), on the full layer's walk."""
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
     monkeypatch.setattr(pallas_ops, "TILE_COUNTS", {})
     monkeypatch.setattr(pallas_ops, "GRID_COUNTS", {})
@@ -101,11 +110,11 @@ def test_latent_attention_lowers_for_v5e(one_chip, monkeypatch):
     calls = [line.strip() for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line]
     assert sorted(kernel_of(line, load_kernels()) for line in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd"]
+        "flash_dkv", "flash_fwd"]
     assert pallas_ops.TILE_COUNTS == {(8192, 8192, True, None):
                                       (528, 1024, 32)}
     assert pallas_ops.GRID_COUNTS == {(8192, 8192, True, None):
-                                      ((64, 36), (64, 36), 64)}
+                                      (64, 36, 64)}
 
 
 def test_grouped_expert_products_lower_for_v5e(one_chip, monkeypatch):

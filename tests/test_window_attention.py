@@ -4,7 +4,8 @@ windowed kernels against ``dot_product_attention`` with the explicit mask
 call against a count by hand, and the YaRN frequencies against the
 formula.  The grid that walks only the blocks a window leaves (ISSUE 27):
 the same comparison where it shrinks, its step counts by hand, and the
-three ``pallas_call`` grids read out of the jaxpr."""
+two ``pallas_call`` grids (forward, the one backward: ISSUE 31) read out of
+the jaxpr."""
 
 import math
 
@@ -70,53 +71,60 @@ class TestWindowedFlash:
     @pytest.mark.parametrize("lq,lk,window,grid", [
         # 256-wide blocks.  L = 1024, window 256: a row needs its own block
         # and the one before, 2 of 4; row 0 has one, so 8 walked, 7 with work
-        (1024, 1024, 256, ((8, 7), (8, 7), 16)),
+        (1024, 1024, 256, (8, 7, 16)),
         # window 300 reaches a third block (300 - 1 > 256): rows 0..4 have
         # 1, 2, 3, 3, 3 blocks
-        (1280, 1280, 300, ((15, 12), (15, 12), 25)),
+        (1280, 1280, 300, (15, 12, 25)),
         # an edge inside a sub-tile: the blocks are window 256's
-        (1024, 1024, 200, ((8, 7), (8, 7), 16)),
+        (1024, 1024, 200, (8, 7, 16)),
         # one sub-tile of window: still the block before, for its last keys
-        (1024, 1024, 128, ((8, 7), (8, 7), 16)),
+        (1024, 1024, 128, (8, 7, 16)),
         # more keys than queries (top-left aligned): rows 0, 1 have 1, 2 of
-        # the 4 key blocks; key block 0 has both query blocks, so each of
-        # the 4 columns walks 2, and key blocks 2 and 3 have no query
-        (512, 1024, 256, ((4, 3), (8, 3), 8)),
+        # the 4 key blocks; key blocks 2 and 3 have no query, and the
+        # backward's dk / dv there are the zeros its sums start from
+        (512, 1024, 256, (4, 3, 8)),
+        # more queries than keys: rows 0..3 have key blocks 0, 0-1, 1-2, 2
+        # (the last row's own block 3 is not there): 2 steps a row
+        (1024, 768, 257, (8, 6, 12)),
     ])
     def test_shrunk_grid_matches_dense(self, small_blocks, lq, lk, window,
                                        grid):
         """Where the window leaves a row fewer blocks than the sequence
         has, the innermost grid dimension is the most any row needs: GQA
         8:1, forward, dq and the grouped dk / dv against the dense mask,
-        and the walked / with-work grid steps a head by hand.  Every block
-        holds random data, the last key block's column and block 0's row
-        too: a step past the end of either clamps to them, and an
-        unguarded one would add them a second time."""
+        and the walked / with-work grid steps a head by hand (one walk:
+        the backward kernel's grid is the forward's with the group's
+        members apart).  Every block holds random data, block 0's row and
+        the last key block too: a step past a row's end clamps to them,
+        and an unguarded one would add them a second time."""
         _assert_matches_dense(lq, lk, window)
         assert small_blocks.GRID_COUNTS == {(lq, lk, True, window): grid}
 
     def test_a_step_past_the_last_block_does_nothing(self, small_blocks):
-        """dkv at the last key block's column: with L = 1024, window 256,
-        step (j = 3, iqq = 1) names query block 4, which is not there; its
-        offset 4 x 256 - 3 x 256 = 256 is the window's crossing offset, and
-        the index map has clamped the step to query block 3.  The guard is
-        ``live``: without it dk / dv of the last 256 keys gain block 3's
-        share twice.  The same for the forward at block 0's row were its
-        offset not above the diagonal."""
-        _, queries = small_blocks._walks(4, 4, 256, 256, True, 256)
-        i, live = queries.block(jnp.int32(3), jnp.int32(1))
-        assert (int(i), bool(live)) == (4, False)
-        assert int(queries.loaded(jnp.int32(3), jnp.int32(1))) == 3
-        assert (4 * 256 - 3 * 256) in small_blocks._crossing_offsets(
-            4, 4, 256, 256, 256)
-        q, k, v = _qkv(1024, 8, 1, 128, seed=11)
+        """The last query block's row where the keys end before the queries
+        do: with 1024 queries, 768 keys and window 257, step (i = 3, jj = 1)
+        names key block 3, which is not there; its offset 3 x 256 - 3 x 256
+        = 0 is the diagonal's crossing offset, and the index map has
+        clamped the step to key block 2.  The guard is ``live``: without
+        it the backward adds block 2's share to dq's last 256 rows a second
+        time and indexes its dk / dv sums past their end; the forward the
+        same for its softmax state."""
+        keys = small_blocks._key_walk(4, 3, 256, 256, True, 257)
+        j, live = keys.block(jnp.int32(3), jnp.int32(1))
+        assert (int(j), bool(live)) == (3, False)
+        assert int(keys.loaded(jnp.int32(3), jnp.int32(1))) == 2
+        assert 0 in small_blocks._crossing_offsets(4, 3, 256, 256, 257)
+        q, _, _ = _qkv(1024, 8, 1, 128, seed=11)
+        _, k, v = _qkv(768, 8, 1, 128, seed=12)
         grads = lambda fn: jax.jit(jax.grad(
-            lambda *a: (fn(*a) ** 2).sum(), argnums=(1, 2)))(q, k, v)
+            lambda *a: (fn(*a) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
         flash = grads(lambda q, k, v: pallas_ops.flash_attention(
-            q, k, v, causal=True, window=256))
+            q, k, v, causal=True, window=257))
         dense = grads(lambda q, k, v: dot_product_attention(
-            q, k, v, mask=causal_mask(1024, 1024, window=256)))
-        for a, b in zip(flash, dense):
+            q, k, v, mask=causal_mask(1024, 768, window=257)))
+        np.testing.assert_allclose(flash[0][:, -256:], dense[0][:, -256:],
+                                   atol=2e-4)
+        for a, b in zip(flash[1:], dense[1:]):
             np.testing.assert_allclose(a[:, -256:], b[:, -256:], atol=2e-4)
 
     def test_window_of_the_whole_sequence_is_the_causal_call(self,
@@ -154,25 +162,26 @@ class TestWindowedFlash:
             (8192, 8192, True, 1024): (150, 1024, 60)}
         # the grid: a query block needs its own key block and the one
         # before, 2 steps a row for the sequence's 8; block 0's row has no
-        # block before it, so 8 x 2 = 16 walked of 64 and 15 with work; the
-        # same down the columns, where the last key block has no query
-        # block after it
+        # block before it, so 8 x 2 = 16 walked of 64 and 15 with work, by
+        # the forward and by the one backward kernel alike, which pays 5
+        # products a visited sub-tile (S, dP, dQ, dV, dK) where the two
+        # kernels it replaced paid 3 + 4
         assert pallas_ops.GRID_COUNTS == {
-            (8192, 8192, True, 1024): ((16, 15), (16, 15), 64)}
+            (8192, 8192, True, 1024): (16, 15, 64)}
         assert pallas_ops.tiles_line((8192, 8192, True, 1024)) == (
             "flash tiles L=8192 causal window 1024: visited 150/1024, "
-            "masked 60; grid steps a head 16 of 64 walked, 15 with work "
-            "(dkv 16 of 64, 15)")
+            "masked 60; grid steps a head 16 of 64 walked, 15 with work, "
+            "forward and backward (5 products a visited sub-tile backward, "
+            "not 7)")
         pallas_ops._log_tiles(8192, 8192, 1024, 1024, True, None)
         assert pallas_ops.TILE_COUNTS[(8192, 8192, True, None)] == (
             528, 1024, 32)
         # no window: the last row needs every block, the triangle cannot be
         # made a rectangle: 64 walked, 8 x 9 / 2 = 36 with work
         assert pallas_ops.GRID_COUNTS[(8192, 8192, True, None)] == (
-            (64, 36), (64, 36), 64)
-        assert pallas_ops.tiles_line((8192, 8192, True, None)).endswith(
-            "grid steps a head 64 of 64 walked, 36 with work "
-            "(dkv 64 of 64, 36)")
+            64, 36, 64)
+        assert "; grid steps a head 64 of 64 walked, 36 with work, " in (
+            pallas_ops.tiles_line((8192, 8192, True, None)))
 
 
 def _pallas_grids(jaxpr) -> dict:
@@ -190,16 +199,14 @@ def _pallas_grids(jaxpr) -> dict:
 
 class TestGridOfTheBenchmarkCall:
     @pytest.mark.parametrize("window,grids", [
-        (1024, {"flash_fwd": (1, 32, 8, 2), "flash_dq": (1, 32, 8, 2),
-                "flash_dkv": (1, 4, 8, 16)}),
-        (None, {"flash_fwd": (1, 32, 8, 8), "flash_dq": (1, 32, 8, 8),
-                "flash_dkv": (1, 4, 8, 64)}),
+        (1024, {"flash_fwd": (1, 32, 8, 2), "flash_bwd": (1, 4, 8, 8, 2)}),
+        (None, {"flash_fwd": (1, 32, 8, 8), "flash_bwd": (1, 4, 8, 8, 8)}),
     ])
     def test_grids_in_the_jaxpr(self, monkeypatch, window, grids):
         """mellum2_train_8k's two attention calls, (1, 8192, 32 / 4, 128):
         under the window of 1024 the innermost grid dimension is 2 key
-        blocks a query block (dkv: 2 query blocks for each of the 8 group
-        members), with no window the whole 8 (8 x 8)."""
+        blocks a query block, with no window the whole 8; the backward
+        walks them for each of a K/V head's 8 group members in turn."""
         monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
         loss = lambda q, k, v: pallas_ops.flash_attention(
             q, k, v, causal=True, window=window).astype(jnp.float32).sum()
@@ -242,36 +249,45 @@ class TestYarn:
 
 
 class TestOldCallsUnchanged:
-    """A causal call of one block and a call with no mask lower to the
-    kernels they lowered to before the window came (ISSUE 26): the jaxpr of
-    forward and both gradients, kernel bodies, grids and index maps
-    included, source locations left out, hashed.  The pins were read off
-    the parent commit (549092b) by the same code; a PR that changes these
-    kernels on purpose reads new ones.  A causal call of many blocks with
-    no window lowers to what it lowered to before the grid followed the
-    window (ISSUE 27): its pin was read off that parent (f6f7aa9).  A call
-    with one width for q, k and v lowers to what it lowered to before the
-    kernels took the values' width apart from the scores' (ISSUE 30): the
-    window call's pin was read off that parent (0e9225a), the others
-    stand."""
+    """The forward of the cells' calls lowers to the kernel it lowered to
+    before the window came (ISSUE 26), before the grid followed the window
+    (ISSUE 27), before the values' width came apart from the scores'
+    (ISSUE 30) and before the backward became one kernel (ISSUE 31, which
+    does not touch it): the jaxpr of the training forward (output and
+    log-sum-exp), kernel body, grid and index maps included, source
+    locations left out, hashed; the pins were read off ISSUE 31's parent
+    (f071189) by the same code.  The gradient's pin, forward and the one
+    backward kernel together, was read off ISSUE 31's own tree: a PR that
+    changes these kernels on purpose reads new ones."""
 
-    @pytest.mark.parametrize("shape,causal,window,pin", [
-        ((4, 1024, 12, 12, 64), True, None, "1fea3930ac522bc2"),  # gpt2_small
-        ((16, 512, 12, 12, 64), False, None, "9504b7eee0c4fe0c"),  # bert_base
+    @pytest.mark.parametrize("shape,causal,window,forward,gradient", [
+        ((4, 1024, 12, 12, 64), True, None,           # gpt2_small
+         "8b639f80719e7aaa", "952b9a9fb73283e6"),
+        ((16, 512, 12, 12, 64), False, None,          # bert_base
+         "52ae05b93d47e435", "14f757da4df6ca08"),
         # mellum2_12b_a2p5b's full layer and its sliding one
-        ((1, 8192, 32, 4, 128), True, None, "bb8152b91081eff4"),
-        ((1, 8192, 32, 4, 128), True, 1024, "9962667657642d8d"),
+        ((1, 8192, 32, 4, 128), True, None,
+         "c9b2c56cb6f91c9d", "43596a360eaaa1bf"),
+        ((1, 8192, 32, 4, 128), True, 1024,
+         "7a8d6e41f4a86bef", "031029a5240aefe2"),
     ])
-    def test_jaxpr_hash(self, monkeypatch, shape, causal, window, pin):
+    def test_jaxpr_hash(self, monkeypatch, shape, causal, window, forward,
+                        gradient):
         import hashlib
         import re
         monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
         b, l, h, kv, d = shape
+        sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        args = sds(b, l, h, d), sds(b, l, kv, d), sds(b, l, kv, d)
+
+        def pin(fn):
+            text = str(jax.make_jaxpr(fn)(*args))
+            text = re.sub(r"/\S*pallas_ops\.py\S*", "",
+                          re.sub(r" at /[^\s\]]*", "", text))
+            return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+        assert pin(lambda q, k, v: pallas_ops._flash_forward(
+            q, k, v, causal, with_lse=True, window=window)) == forward
         loss = lambda q, k, v: pallas_ops.flash_attention(
             q, k, v, causal=causal, window=window).astype(jnp.float32).sum()
-        sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
-        text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
-            sds(b, l, h, d), sds(b, l, kv, d), sds(b, l, kv, d)))
-        text = re.sub(r"/\S*pallas_ops\.py\S*", "", re.sub(r" at /[^\s\]]*",
-                                                          "", text))
-        assert hashlib.sha256(text.encode()).hexdigest()[:16] == pin
+        assert pin(jax.grad(loss, (0, 1, 2))) == gradient
