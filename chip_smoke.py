@@ -149,6 +149,8 @@ def check_flash_kernels() -> None:
             "gqa L=2048 16/4 (llama_gqa4)": (1, 2048, 16, 4, 64, None),
             "gqa L=8192 32/4 d=128 window 1024 (mellum2_train_8k's)":
                 (1, 8192, 32, 4, 128, 1024),
+            "gqa L=8192 32/4 d=128 window 2048 (trinity_mini_train_8k's)":
+                (1, 8192, 32, 4, 128, 2048),
             "mla L=8192 32 heads, scores 192 values 128 (kanana2_train_8k's)":
                 (1, 8192, 32, 32, (192, 128), None)}.items():
         d, dv = d if isinstance(d, tuple) else (d, d)
@@ -183,10 +185,13 @@ def check_flash_kernels() -> None:
         raise AssertionError(
             f"flash fell back to dense: {pallas_ops._FALLBACK_LOGGED}")
     say_flash_tiles()
-    grid = pallas_ops.GRID_COUNTS[(8192, 8192, True, 1024)]
-    if grid != (16, 15, 64):
-        raise AssertionError(f"the window call's grid does not follow its "
-                             f"window: {grid}")
+    # a window of one key block leaves a row 2 blocks, one of two 3 (a
+    # crossed one, one wholly inside the band, the diagonal's)
+    for window, want in ((1024, (16, 15, 64)), (2048, (24, 21, 64))):
+        grid = pallas_ops.GRID_COUNTS[(8192, 8192, True, window)]
+        if grid != want:
+            raise AssertionError(f"the window {window} call's grid does not "
+                                 f"follow its window: {grid}")
 
 
 def say_flash_tiles() -> None:

@@ -48,13 +48,18 @@ class Router:
     (``softmax`` | ``sigmoid``), the top k taken on ``score + bias`` where
     ``select_bias`` (the weights stay the scores without it), the chosen
     weights over ``their sum + eps`` and times ``scale``.  ``bias_std``:
-    the scale the bias is drawn at (it has no gradient and no rule moves
-    it here, so what it is drawn as is what it stays)."""
+    the scale the bias is drawn at.  It has no gradient; ``bias_step`` is
+    the speed of the rule that moves it once an optimizer step, from the
+    step's count ``n_e`` of the pairs that chose each expert (DeepSeek-V3's
+    balancing without an auxiliary loss, ``train.move_select_bias``): ``d_e
+    = bias_step * sign(mean(n) - n_e)``, ``b_e += d_e - mean(d)``.  At 0
+    no rule moves it, and what it is drawn as is what it stays."""
     score: str = "softmax"
     select_bias: bool = False
     scale: float = 1.0
     eps: float = 0.0
     bias_std: float = 0.0
+    bias_step: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,7 +71,8 @@ class DecoderArch:
     layer_types: tuple             # one period: "sliding" | "full" each
     periods: int                   # periods kept (the cut in depth)
     window: int                    # of the "sliding" layers; counts itself
-    rope: tuple                    # ((layer type, Rope), ...)
+    rope: tuple                    # ((layer type, Rope), ...); a kind
+    #                                that is not listed has no rotary
     norm_eps: float
     vocab: int                     # rows of embedding and head HELD
     experts: int                   # the router's width: all experts
@@ -89,13 +95,21 @@ class DecoderArch:
     shared_ffn: int = 0            # SwiGLU width every token passes beside
     #                                its routed experts (0: none)
     router: Router = Router()
+    qk_norm: bool = False          # RMSNorm over each head's q and k, one
+    #                                learned head_dim-wide scale each
+    attn_gate: bool = False        # sigmoid(x Wg) times attention's output,
+    #                                a head at a time, before the projection
+    sandwich_norm: bool = False    # a norm on each sublayer's OUTPUT too,
+    #                                before the residual add: four a block
+    embed_scale: float = 1.0       # the embedding's rows times this (muP:
+    #                                sqrt(hidden))
 
     @property
     def layers(self) -> int:
         return self.lead_dense[0] + self.periods * len(self.layer_types)
 
-    def rope_of(self, layer_type: str) -> Rope:
-        return dict(self.rope)[layer_type]
+    def rope_of(self, layer_type: str) -> Optional[Rope]:
+        return dict(self.rope).get(layer_type)
 
     def window_of(self, layer_type: str) -> Optional[int]:
         return self.window if layer_type == "sliding" else None
@@ -189,4 +203,49 @@ ARCHS = {
         latent=Latent(rank=32, nope=16, rope=8, v=16),
         rope_interleaved=True, lead_dense=(1, 128), shared_ffn=64,
         router=Router("sigmoid", True, 2.448, 1e-20, ROUTER_BIAS_STD)),
+    # arcee-ai/Trinity-Mini, config.json (model_type afmoe): 32 layers =
+    # 8 periods of (3 sliding, 1 full), window 2048, rotary on the sliding
+    # layers alone; attention with RMSNorm on every head's q and k and a
+    # sigmoid gate on its output; a norm before AND after each sublayer;
+    # the embedding times sqrt(2048) (mup_enabled); layers 0-1 a dense MLP
+    # of 6144, then 128 routed experts (8 a token, sigmoid scores, weights
+    # renormalised and times 2.826) plus one shared expert, and a selection
+    # bias that a rule moves every step (load_balance_coeff 0.001);
+    # vocabulary 200,192.  Held here, as one of the sixteen chips that share
+    # each layer of a deployment: layer 0 (the two leading dense layers
+    # count once) and one period of sparse layers, experts 0-7 of every
+    # sparse layer, rows 0-25,023 of embedding and head (the vocabulary
+    # eight ways, each eighth on two chips); the other layers lie on
+    # further chips (benchmarks/configs/trinity_mini_26b_a3b.json, PERF.md 4)
+    "trinity_mini_26b_a3b": DecoderArch(
+        hidden=2048, heads=32, kv_heads=4, head_dim=128,
+        layer_types=_PERIOD, periods=1, window=2048,
+        rope=(("sliding", Rope(10000.0)),), norm_eps=1e-5, vocab=25024,
+        experts=128, experts_per_token=8, expert_ffn=1024,
+        experts_held=(0, 8),
+        published=(("layers", 32), ("dense_layers", 2), ("experts", 128),
+                   ("vocab", 200192)),
+        # the embedding's rows at 0.25, times sqrt(2048) = 11.3 RMS beside
+        # sublayer outputs that the block's second and fourth norm hold at 1:
+        # the 11 : 1 of the two configurations above, for their reason.  At
+        # 0.02 (0.9 RMS) the share on the held experts read 0.64 to 1.93 of
+        # 8 / 128 by layer and seed and the fullest expert 7 to 11 times the
+        # mean; at 0.25 0.94 to 1.12 and 1.7 to 2.1 (PERF.md section 6, PR 32)
+        embed_std=0.25, lead_dense=(1, 6144), shared_ffn=1024,
+        router=Router("sigmoid", True, 2.826, 1e-20, ROUTER_BIAS_STD,
+                      bias_step=0.001),
+        qk_norm=True, attn_gate=True, sandwich_norm=True,
+        embed_scale=2048 ** 0.5),
+    # the CPU tests' preset of the same block: one dense layer and two
+    # periods, every expert held
+    "trinity_tiny": DecoderArch(
+        hidden=64, heads=4, kv_heads=2, head_dim=32,
+        layer_types=_PERIOD, periods=2, window=16,
+        rope=(("sliding", Rope(10000.0)),), norm_eps=1e-5, vocab=1000,
+        experts=8, experts_per_token=2, expert_ffn=32, experts_held=(0, 8),
+        embed_std=0.02, lead_dense=(1, 128), shared_ffn=32,
+        router=Router("sigmoid", True, 2.826, 1e-20, ROUTER_BIAS_STD,
+                      bias_step=0.001),
+        qk_norm=True, attn_gate=True, sandwich_norm=True,
+        embed_scale=64 ** 0.5),
 }

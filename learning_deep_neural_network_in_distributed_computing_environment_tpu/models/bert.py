@@ -51,6 +51,11 @@ class SelfAttention(nn.Module):
     head_dim: Optional[int] = None  # given (None: hidden // num_heads)
     window: Optional[int] = None    # causal sliding window, itself counted
     rope_yarn: Optional[tuple] = None  # ops.attention.rope_frequencies
+    qk_norm: Optional[float] = None  # eps: RMSNorm over each head's q and
+    #                                  k before the rotary, one learned
+    #                                  head_dim-wide scale each
+    gate: bool = False              # sigmoid(x Wg), a value a head and
+    #                                  column, times attention's output
 
     @nn.compact
     def __call__(self, x, mask=None):
@@ -88,6 +93,11 @@ class SelfAttention(nn.Module):
                                   name="qkv")(x_in)
             q, k, v = (qkv[..., 0, :, :], qkv[..., 1, :, :],
                        qkv[..., 2, :, :])
+        if self.qk_norm is not None:
+            with jax.named_scope("qk_norm"):
+                q, k = (nn.RMSNorm(epsilon=self.qk_norm, dtype=self.dtype,
+                                   name=name)(t)
+                        for name, t in (("q_norm", q), ("k_norm", k)))
         if self.rope_theta is not None:
             from jax import lax
             from ..ops.attention import rope
@@ -107,6 +117,11 @@ class SelfAttention(nn.Module):
         out = attend(q, k, v, mask=mask, impl=self.attention_impl,
                      axis_name=self.axis_name, causal=self.causal,
                      window=self.window)
+        if self.gate:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(nn.DenseGeneral(
+                    (h_local, head_dim), kernel_init=_init, use_bias=False,
+                    dtype=self.dtype, name="gate")(x_in))
         y = nn.DenseGeneral(d, axis=(-2, -1), kernel_init=_init,
                             use_bias=False, dtype=self.dtype,
                             name="out")(out)

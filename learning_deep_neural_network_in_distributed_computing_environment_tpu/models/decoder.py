@@ -1,10 +1,13 @@
 """The decoder-only LM that a ``models.arch.DecoderArch`` describes: one
 block definition, ``x + Attn_t(RMSNorm(x))`` then ``x + F(RMSNorm(x))``,
 whose attention takes its kind ``t`` (window or full, and that kind's
-rotary parameters) from the layer's place in the period, and is grouped-
-query or latent as the record says.  ``F`` is the routed experts, plus
-the shared experts every token passes where the record has them; in the
-record's leading dense layers it is one SwiGLU MLP.
+rotary parameters, or none) from the layer's place in the period, and is
+grouped-query (with a norm on q and k and a gate on its output where the
+record says so) or latent.  ``F`` is the routed experts, plus the shared
+experts every token passes where the record has them; in the record's
+leading dense layers it is one SwiGLU MLP.  Under ``sandwich_norm`` each
+sublayer's output passes a norm of its own before the add: ``x +
+RMSNorm(Attn_t(RMSNorm(x)))``, four norms a block.
 
 The stack is scanned a PERIOD at a time (``bert.apply_scanned_stack`` over
 ``_ScanPeriod``): the layers of a period share one parameter shape and
@@ -51,8 +54,12 @@ class DecoderBlock(nn.Module):
         a = self.arch
         norm = lambda name: nn.RMSNorm(epsilon=a.norm_eps, dtype=self.dtype,
                                        name=name)
-        rope = a.rope_of(self.layer_type)
+        post = lambda name, y: norm(name)(y) if a.sandwich_norm else y
+        rope = a.rope_of(self.layer_type)      # None: no rotary on this kind
         if a.latent:
+            if a.qk_norm or a.attn_gate:
+                raise ValueError("latent attention takes no qk_norm and no "
+                                 "attn_gate")
             attn = LatentAttention(
                 a.heads, a.latent, rope_theta=rope.theta,
                 rope_interleaved=a.rope_interleaved, norm_eps=a.norm_eps,
@@ -63,9 +70,12 @@ class DecoderBlock(nn.Module):
                 a.heads, dtype=self.dtype,
                 attention_impl=self.attention_impl, causal=True,
                 use_bias=False, num_kv_heads=a.kv_heads, head_dim=a.head_dim,
-                window=a.window_of(self.layer_type), rope_theta=rope.theta,
-                rope_yarn=rope.yarn, name="attn")
-        x = x + checkpoint_name(attn(norm("rms1")(x)), "attn_out")
+                window=a.window_of(self.layer_type),
+                rope_theta=rope and rope.theta, rope_yarn=rope and rope.yarn,
+                qk_norm=a.norm_eps if a.qk_norm else None, gate=a.attn_gate,
+                name="attn")
+        x = x + checkpoint_name(
+            post("rms1_post", attn(norm("rms1")(x))), "attn_out")
         h = norm("rms2")(x)
         if self.dense_ffn:
             f = SwiGLU(self.dense_ffn, dtype=self.dtype, name="mlp")(h)
@@ -77,8 +87,8 @@ class DecoderBlock(nn.Module):
                 with jax.named_scope("moe_shared"):
                     f = f + SwiGLU(a.shared_ffn, dtype=self.dtype,
                                    name="shared")(h)
-        return checkpoint_name(x + checkpoint_name(f, "mlp_out"),
-                               "block_out")
+        return checkpoint_name(
+            x + checkpoint_name(post("rms2_post", f), "mlp_out"), "block_out")
 
 
 def _block(layer_remat: Optional[str]):
@@ -133,6 +143,8 @@ class DecoderLM(nn.Module):
         x = nn.Embed(a.vocab, a.hidden,
                      embedding_init=nn.initializers.normal(a.embed_std),
                      dtype=self.dtype, name="tok_emb")(input_ids)
+        if a.embed_scale != 1.0:
+            x = x * jnp.asarray(a.embed_scale, x.dtype)
         layer_remat = resolve_remat_policy(False, self.remat_policy)
         lead_layers, lead_width = a.lead_dense
         for i in range(lead_layers):

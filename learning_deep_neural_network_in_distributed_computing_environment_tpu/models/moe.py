@@ -45,6 +45,11 @@ from jax.ad_checkpoint import checkpoint_name
 from .arch import Router
 
 _init = nn.initializers.normal(stddev=0.02)
+# the ``counters`` key under which a routed layer whose router has a
+# ``bias_step`` sows how many (token, choice) pairs chose each of ALL its
+# experts: a vector a layer, which the training step takes out of the
+# counters and moves the layer's ``select_bias`` by
+CHOICE_COUNTS = "choice_counts"
 
 
 class MoEFFN(nn.Module):
@@ -216,7 +221,10 @@ class RoutedExperts(nn.Module):
     token, no auxiliary loss.  ``router`` (``arch.Router``) is the rule
     that scores and chooses; its selection bias, where it has one, is a
     parameter that enters the choice alone: it gets no gradient, so the
-    optimizer leaves it to the bit.
+    optimizer leaves it to the bit.  Where the rule has a ``bias_step`` the
+    layer also sows, under ``CHOICE_COUNTS``, the pairs that chose each of
+    the ``num_experts`` experts, held here or not: what the step's rule
+    moves the bias by (``train.move_select_bias``).
 
     ``experts_held = (first, count)`` makes this one expert-parallel
     rank's layer: the router scores all ``num_experts``, the top-k and the
@@ -252,6 +260,10 @@ class RoutedExperts(nn.Module):
                     (self.num_experts,), jnp.float32)
                 _, idx = lax.top_k(scores + bias, self.top_k)
                 weights = jnp.take_along_axis(scores, idx, axis=-1)
+                if rule.bias_step:
+                    self.sow("counters", CHOICE_COUNTS, (
+                        idx[..., None] == jnp.arange(self.num_experts)
+                    ).sum((0, 1), dtype=jnp.float32))
             else:
                 weights, idx = lax.top_k(scores, self.top_k)
             # (a rule without eps or scale traces to the program it had)

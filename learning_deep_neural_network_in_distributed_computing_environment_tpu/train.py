@@ -61,6 +61,7 @@ from . import probe as probe_lib
 from .config import Config
 from .data.augment import augment_batch
 from .mesh import DATA_AXIS, SLICE_AXIS
+from .models.moe import CHOICE_COUNTS
 from .spans import span
 
 log = logging.getLogger(__name__)
@@ -230,13 +231,48 @@ def rank0_variables(state: "TrainState", *, params_template=None,
 
 def _mean_by_name(collection: dict) -> dict:
     """{name: scalar} of a sown collection: every value sown under one
-    name (one a layer, stacked under a scan) averaged."""
+    name (one a layer, stacked under a scan) averaged.  The routed layers'
+    per-expert choice counts are no such scalar: they come out whole, as
+    ``{CHOICE_COUNTS: {the layer's path: counts}}``, for the step's rule
+    (``move_select_bias``), which takes them off again."""
     from flax.traverse_util import flatten_dict
     by_name: dict = {}
+    counts: dict = {}
     for path, sown in flatten_dict(dict(collection)).items():
+        if path[-1] == CHOICE_COUNTS:
+            counts[path[:-1]], = sown
+            continue
         by_name.setdefault(path[-1], []).extend(
             jnp.mean(v) for v in sown)
-    return {name: jnp.mean(jnp.stack(vs)) for name, vs in by_name.items()}
+    out = {name: jnp.mean(jnp.stack(vs)) for name, vs in by_name.items()}
+    return dict(out, **{CHOICE_COUNTS: counts}) if counts else out
+
+
+def _select_biases(params: PyTree) -> list:
+    """The routed layers' selection biases, in the tree's order."""
+    return [leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(params)[0]
+            if getattr(path[-1], "key", None) == "select_bias"]
+
+
+def move_select_bias(params: PyTree, counts: dict, step: float) -> PyTree:
+    """The rule that moves a router's selection bias, once an optimizer
+    step (DeepSeek-V3's balancing without an auxiliary loss; ``step`` is
+    ``arch.Router.bias_step``): with ``n_e`` the (token, choice) pairs of
+    this step that chose expert e of a layer, ``d_e = step * sign(mean(n) -
+    n_e)`` and ``b_e += d_e - mean(d)``: an expert that got more than its
+    share is chosen a little less readily next step.  ``counts`` is
+    ``{the layer's path: n}`` as the routed layers sowed it, over ALL the
+    router's experts (a stacked layer's ``n`` carries the stack's axis, as
+    its bias does).  No gradient and no Adam moment is involved."""
+    def move(path, leaf):
+        names = tuple(getattr(p, "key", str(p)) for p in path)
+        if names[-1] != "select_bias" or names[:-1] not in counts:
+            return leaf
+        n = counts[names[:-1]]
+        d = step * jnp.sign(n.mean(-1, keepdims=True) - n)
+        return leaf + d - d.mean(-1, keepdims=True)
+    return jax.tree_util.tree_map_with_path(move, params)
 
 
 def steplr(lr0: float, gamma: float, step_size: int, epoch: jnp.ndarray):
@@ -502,6 +538,11 @@ class LocalSGDEngine:
         self.labelled_rows_head = (
             bool(getattr(tm, "labelled_rows_head", ()))
             and not self._inner_axes)
+        # the speed of the rule that moves a routed layer's selection bias
+        # after every optimizer step (``move_select_bias``), from the
+        # model's record; 0: the model has no such rule
+        arch = getattr(tm, "arch", None)
+        self.router_bias_step = arch.router.bias_step if arch else 0.0
         # Microbatch gradient accumulation (ISSUE 3): K > 1 scans the
         # step's batch in K slices with an fp32 gradient carry — bounded
         # activation memory, unchanged effective batch/optimizer/sync
@@ -1736,6 +1777,9 @@ class LocalSGDEngine:
             micro, zeros, (xs, ys, ms))
         loss, correct, total = losses.sum(), corrects.sum(), totals.sum()
         counters = jax.tree_util.tree_map(lambda c: c.mean(0), counters)
+        if CHOICE_COUNTS in counters:      # counts add up over the slices
+            counters[CHOICE_COUNTS] = jax.tree_util.tree_map(
+                lambda c: c * k, counters[CHOICE_COUNTS])
         # batch_stats pass through unchanged: accumulation is gated to
         # models without BatchNorm (driver validates), so the tree is
         # empty and the step's _tree_where keeps it as-is
@@ -1786,6 +1830,13 @@ class LocalSGDEngine:
             updates, new_opt = self.tx.update(grads, opt_state, params)
             new_params = optax.apply_updates(
                 params, jax.tree_util.tree_map(lambda u: -lr * u, updates))
+            # the one change to a parameter that is no optimizer's: the
+            # routers' selection biases follow this step's loads (masked
+            # below like every other change of the state)
+            counts = counters.pop(CHOICE_COUNTS, None)
+            if counts:
+                new_params = move_select_bias(new_params, counts,
+                                              self.router_bias_step)
             # fully-masked (padding) steps leave everything untouched —
             # including the carried last-real-batch grads, so gradients
             # mode aggregates each worker's stale last REAL gradient
@@ -1866,6 +1917,16 @@ class LocalSGDEngine:
                         (params, batch_stats, opt_state, rng, lr,
                          zero_grads),
                         (x, y, m))
+                if self.router_bias_step:
+                    # how far this local epoch's steps moved the selection
+                    # biases, net: mean |after - before| over layers and
+                    # experts, as a row key beside the per-step counters
+                    moved = jnp.mean(jnp.stack([
+                        jnp.abs(after - before).mean() for before, after
+                        in zip(_select_biases(carry[0]),
+                               _select_biases(params))]))
+                    counters = dict(counters, select_bias_moved=(
+                        jnp.broadcast_to(moved, losses.shape)))
                 # reference per-epoch scalars: loss = mean over real batches
                 # (trainer.py:220), accuracy = 100*correct/total (:221)
                 real_step = (totals > 0).astype(jnp.float32)

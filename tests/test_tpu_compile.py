@@ -56,7 +56,11 @@ def test_flash_kernels_lower_for_v5e(one_chip, monkeypatch, b, lq, lk, h, kv,
 
 @pytest.mark.parametrize("window,counts,grid", [
     (1024, (150, 1024, 60), (16, 15, 64)),
-    (None, (528, 1024, 32), (64, 36, 64))])
+    (None, (528, 1024, 32), (64, 36, 64)),
+    # trinity_mini_train_8k's sliding call (ISSUE 32): the same operands
+    # under a window of two key blocks, the first call whose rows hold a
+    # block wholly inside the band between a crossed one and the diagonal's
+    (2048, (252, 1024, 56), (24, 21, 64))])
 def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
                                          counts, grid):
     """mellum2_train_8k's two attention calls: L = 8192, 32 query and 4

@@ -62,6 +62,16 @@ class TestWindowedFlash:
         (512, 300, (10, 16, 7)),     # edge across grid blocks
         (512, 16, (7, 16, 7)),       # both edges in the diagonal's sub-tile
         (768, 512, (20, 36, 8)),    # three blocks a side, one skipped
+        # a window of TWO key blocks (ISSUE 32: the benchmark's window 2048
+        # at 1024-wide blocks): rows 2 and 3 have all three kinds of block,
+        # crossed by the window's edge, WHOLLY INSIDE the band, on the
+        # diagonal.  By hand, sub-tile (a, b) is visited where 0 <= a - b <=
+        # 4: 1 + 2 + 3 + 4 + 5 x 4 = 30 of 64; masked the 8 on the diagonal
+        # and the 4 with a - b = 4, which the edge cuts
+        (1024, 512, (30, 64, 12)),
+        # the same with the edge inside a sub-tile: a - b <= 5, 33 visited;
+        # the edge cuts the 4 sub-tiles at a - b = 4 and the 3 at 5
+        (1024, 600, (33, 64, 15)),
     ])
     def test_forward_and_gradients_match_dense(self, small_blocks, l, window,
                                                counts):
@@ -86,6 +96,9 @@ class TestWindowedFlash:
         # more queries than keys: rows 0..3 have key blocks 0, 0-1, 1-2, 2
         # (the last row's own block 3 is not there): 2 steps a row
         (1024, 768, 257, (8, 6, 12)),
+        # a window of two key blocks: rows 0..4 have 1, 2, 3, 3, 3 blocks,
+        # the middle one of three wholly inside the band
+        (1280, 1280, 512, (15, 12, 25)),
     ])
     def test_shrunk_grid_matches_dense(self, small_blocks, lq, lk, window,
                                        grid):
@@ -182,6 +195,18 @@ class TestWindowedFlash:
             64, 36, 64)
         assert "; grid steps a head 64 of 64 walked, 36 with work, " in (
             pallas_ops.tiles_line((8192, 8192, True, None)))
+        # trinity_mini_train_8k's sliding call, window 2048 (ISSUE 32): 8
+        # blocks on the diagonal, 10 sub-tiles each, 4 masked; 7 blocks one
+        # under it WHOLLY INSIDE the band, 16 each, none masked; 6 blocks
+        # two under it, which the window's edge crosses from corner to
+        # corner, 10 each, 4 masked: 80 + 112 + 60 = 252 visited, 32 + 24 =
+        # 56 masked.  A query block needs three key blocks, rows 0 and 1
+        # have 1 and 2: 8 x 3 = 24 walked, 1 + 2 + 6 x 3 = 21 with work
+        pallas_ops._log_tiles(8192, 8192, 1024, 1024, True, 2048)
+        assert pallas_ops.TILE_COUNTS[(8192, 8192, True, 2048)] == (
+            252, 1024, 56)
+        assert pallas_ops.GRID_COUNTS[(8192, 8192, True, 2048)] == (
+            24, 21, 64)
 
 
 def _pallas_grids(jaxpr) -> dict:
@@ -201,6 +226,8 @@ class TestGridOfTheBenchmarkCall:
     @pytest.mark.parametrize("window,grids", [
         (1024, {"flash_fwd": (1, 32, 8, 2), "flash_bwd": (1, 4, 8, 8, 2)}),
         (None, {"flash_fwd": (1, 32, 8, 8), "flash_bwd": (1, 4, 8, 8, 8)}),
+        # trinity_mini_train_8k's sliding call: 3 key blocks a query block
+        (2048, {"flash_fwd": (1, 32, 8, 3), "flash_bwd": (1, 4, 8, 8, 3)}),
     ])
     def test_grids_in_the_jaxpr(self, monkeypatch, window, grids):
         """mellum2_train_8k's two attention calls, (1, 8192, 32 / 4, 128):
@@ -215,6 +242,34 @@ class TestGridOfTheBenchmarkCall:
             sds(1, 8192, 32, 128), sds(1, 8192, 4, 128),
             sds(1, 8192, 4, 128))
         assert _pallas_grids(closed.jaxpr) == grids
+
+
+class TestPeriodAgainstDense:
+    @pytest.mark.parametrize("name", ["mellum2_tiny", "trinity_tiny"])
+    def test_a_period_of_window_and_full_layers(self, name):
+        """A whole model of (sliding, sliding, sliding, full) periods with
+        the flash kernels against the same parameters under dense attention
+        with the explicit masks: ``mellum2_tiny`` has a rotary on both kinds
+        of layer, ``trinity_tiny`` on the sliding layers and NONE on the
+        full one (and q / k norms and a gate around the call).  Float32, so
+        what is left is the kernels' order of summation over 8 or 9
+        layers."""
+        from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import get_model
+        ids = jax.random.randint(jax.random.key(0), (2, 128), 0, 1000)
+        dense, flash = (get_model(name, num_classes=1000, scan_layers=True,
+                                  attention_impl=impl)
+                        for impl in ("dense", "flash"))
+        params = jax.jit(dense.init)(jax.random.key(1), ids)["params"]
+        before = set(pallas_ops._FALLBACK_LOGGED)
+        loss = lambda m: lambda p: (m.apply({"params": p}, ids) ** 2).mean()
+        (lf, gf), (ld, gd) = (jax.jit(jax.value_and_grad(loss(m)))(params)
+                              for m in (flash, dense))
+        assert set(pallas_ops._FALLBACK_LOGGED) == before, "fell back"
+        np.testing.assert_allclose(lf, ld, rtol=1e-5)
+        for a, b in zip(jax.tree_util.tree_leaves(gf),
+                        jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(a, b, atol=1e-5 + 1e-3 * float(
+                jnp.abs(b).max()))
 
 
 class TestYarn:
