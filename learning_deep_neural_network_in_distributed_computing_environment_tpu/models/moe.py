@@ -3,8 +3,9 @@ mesh axis.
 
 Beyond-reference capability (the reference is data-parallel only,
 SURVEY.md 2.3).  Two routed layers over ONE dispatch (``routed_apply``:
-the (token, expert) pairs sorted by expert, grouped products over the
-groups, a gather back; no ``[tokens, E, C]`` tensor):
+the (token, expert) pairs sorted by expert, grouped products over a row
+buffer sized by the share of the experts held here, a scatter-add back;
+no ``[tokens, E, C]`` tensor):
 
 - ``RoutedExperts``: sparse SwiGLU experts as deployed, top-k of E with
   renormalised weights, no capacity and no dropped token, told which
@@ -34,7 +35,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -123,17 +124,19 @@ class MoEFFN(nn.Module):
         # b2 is scaled so the cross-shard psum below adds it exactly once
         b2_scale = 1.0 / self.tp_size if self.model_axis is not None else 1.0
 
-        def experts(rows, sizes, expert_of_row):
+        def experts(params, rows, sizes, expert_of_row):
             from ..ops.grouped_matmul import grouped_matmul
+            w1, b1, w2, b2 = params
             e_row = jnp.minimum(expert_of_row, e_local - 1)
-            h1 = nn.gelu(
-                grouped_matmul(rows, w1.astype(self.dtype), sizes)
-                + b1.astype(self.dtype)[e_row], approximate=False)
-            return (grouped_matmul(h1, w2.astype(self.dtype), sizes)
-                    + b2_scale * b2.astype(self.dtype)[e_row])
+            h1 = nn.gelu(grouped_matmul(rows, w1, sizes) + b1[e_row],
+                         approximate=False)
+            return grouped_matmul(h1, w2, sizes) + b2_scale * b2[e_row]
 
-        out, _ = routed_apply(toks.astype(self.dtype), expert_idx[:, None],
-                              (gate * keep)[:, None], first, e_local, experts)
+        out, _ = routed_apply(
+            toks.astype(self.dtype), expert_idx[:, None],
+            (gate * keep)[:, None], first, e_local,
+            Experts(experts, tuple(p.astype(self.dtype)
+                                   for p in (w1, b1, w2, b2)), e))
         reduce_axes = tuple(a for a in (self.expert_axis, self.model_axis)
                             if a is not None)
         if reduce_axes:
@@ -142,77 +145,230 @@ class MoEFFN(nn.Module):
 
 
 # ----------------------------------------------------------------------
-# Routed experts without a capacity: sort, grouped products, gather back
+# Routed experts without a capacity: sort, grouped products over a row
+# buffer sized by the held share, a scatter-add back
 # ----------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _spread(toks, order, inv, owned, k):
-    """[N, H] tokens -> [M, H] rows in expert order: sorted row r is the
-    token of flat (token, choice) pair ``order[r]``.  The transpose is a
-    gather too (``inv`` is the sort's inverse), with the rows of pairs
-    that no held expert owns selected away: they were never written."""
-    return toks[jnp.minimum(order // k, toks.shape[0] - 1)]
+# rows of buffer for each row the held share of the router expects: the
+# cells' sparse layers read 0.94-1.12 of that share (PERF.md section 6,
+# PR 32 and PR 33); what does not fit goes through the body again
+HEADROOM = 1.25
 
 
-def _spread_fwd(toks, order, inv, owned, k):
-    return _spread(toks, order, inv, owned, k), (inv, owned, toks.shape[0])
+class Experts(NamedTuple):
+    """The experts ``routed_apply`` sends rows through.  ``apply(params,
+    rows [C, H], sizes [held], expert_of_row [C]) -> [C, H]`` runs grouped
+    products (``ops.grouped_matmul``) over rows sorted by expert; it reads
+    its matrices from ``params`` and closes over no array, because the
+    dispatch states its own derivative and hands ``params`` their
+    gradient.  ``router_width`` is the number of experts the router chooses
+    among, held here or not."""
+    apply: Callable
+    params: Any
+    router_width: int
 
 
-def _spread_bwd(k, res, g):
-    inv, owned, n = res
-    back = jnp.where(owned[:, None], g[inv], 0)[:n * k]
-    return back.reshape(n, k, -1).sum(1), None, None, None
+def _tile_up(rows: int) -> int:
+    from ..ops.grouped_matmul import TM
+    return -(-rows // TM) * TM if rows >= TM else -(-rows // 8) * 8
 
 
-_spread.defvjp(_spread_fwd, _spread_bwd)
+def row_buffer(pairs: int, held: int, router_width: int):
+    """``(m_pad, c)`` for ``pairs`` (token, choice) pairs a step: the worst
+    case, every pair on a held expert, and the rows of the buffer the
+    layer computes on, ``HEADROOM`` times the held share of the pairs and
+    never more than the worst case; both rounded up to the kernels' row
+    tile.  ``c == m_pad`` where every expert is held."""
+    m_pad = _tile_up(pairs)
+    return m_pad, min(m_pad, _tile_up(
+        math.ceil(HEADROOM * pairs * held / router_width)))
 
 
-@jax.custom_vjp
-def _collect(rows, inv, order):
-    """[M, H] rows in expert order -> [M, H] in flat pair order; the
-    transpose is the gather by ``order``."""
-    return rows[inv]
+def overflow_chunks(sizes, c: int):
+    """How many further passes over the ``c``-row buffer the counted rows
+    need after the first: 0 where they fit."""
+    return jnp.maximum(-(-sizes.sum() // c) - 1, 0)
 
 
-_collect.defvjp(lambda rows, inv, order: (rows[inv], order),
-                lambda order, g: (g[order], None, None))
+def _zeros(shape, dtype, like):
+    """Zeros that vary over the mesh axes ``like`` varies over (a loop's
+    carry has to have its body's type inside ``shard_map``)."""
+    from ..ops.grouped_matmul import vary_alike
+    return vary_alike(jnp.zeros(shape, dtype), like)[0]
 
 
-def routed_apply(toks, expert_idx, weights, first, held: int, expert_fn):
+def _take(a, idx):
+    return a.at[idx].get(mode="promise_in_bounds")
+
+
+class _Chunk(NamedTuple):
+    pair: Any      # [c] the flat (token, choice) pair of each row
+    tok: Any       # [c] its token
+    valid: Any     # [c] the row is a pair of a held expert
+    sizes: Any     # [held] each group's rows inside this window
+    expert: Any    # [c] each row's expert (``held`` past the last group)
+    weight: Any    # [c] float32, the pair's weight
+
+
+def _chunk(i, weights, order, sizes, expert_of_row) -> _Chunk:
+    """Chunk ``i`` of the sorted pairs: rows ``[i * c, (i + 1) * c)``, row
+    ``i`` of ``order`` and ``expert_of_row`` [chunks, c].  The groups'
+    overlaps with the window are its group sizes, so within it the rows
+    past ``sum(sizes)`` again belong to none."""
+    n, k = weights.shape
+    c = order.shape[1]
+    start = i * c
+    pair = order[i]
+    ends = jnp.cumsum(sizes)
+    inside = lambda r: jnp.clip(r - start, 0, c)
+    return _Chunk(
+        pair, jnp.minimum(pair // k, n - 1),
+        start + jnp.arange(c, dtype=jnp.int32) < ends[-1],
+        inside(ends) - inside(ends - sizes), expert_of_row[i],
+        _take(weights.reshape(-1), jnp.minimum(pair, n * k - 1)))
+
+
+def _combine(acc, ch: _Chunk, y):
+    """Add the chunk's weighted rows into their tokens, in float32.  The
+    kernels never wrote the rows of no group: selected, not multiplied."""
+    y = y.astype(jnp.float32) * ch.weight[:, None]
+    return acc.at[ch.tok].add(jnp.where(ch.valid[:, None], y, 0))
+
+
+def _overflow_loop(order, sizes, body, carry):
+    """``body(i, carry)`` over the chunks after the first that hold rows:
+    a trip count from the counted sizes, and no loop at all where one
+    chunk is the whole order."""
+    if order.shape[0] == 1:
+        return carry
+    return lax.fori_loop(1, overflow_chunks(sizes, order.shape[1]) + 1, body,
+                         carry)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(apply, toks, weights, params, order, sizes, expert_of_row):
+    """The routed layer on a buffer of ``c`` rows, ``order`` and
+    ``expert_of_row`` being [chunks, c]: chunk 0 of the sorted pairs
+    straight-line, the chunks after it in a loop that runs as often as
+    ``sizes`` says, which is not at all where the rows fit.  A loop of a
+    dynamic trip count has no reverse-mode derivative, so the layer states
+    its own: the backward walks the same chunks, chunk 0 from the forward's
+    residuals and the others recomputed."""
+    return _dispatch_fwd(apply, toks, weights, params, order, sizes,
+                         expert_of_row)[0]
+
+
+def _dispatch_fwd(apply, toks, weights, params, order, sizes, expert_of_row):
+    ints = (order, sizes, expert_of_row)
+    ch = _chunk(0, weights, *ints)
+    # named activation "moe_dispatch" (ISSUE 15): the tokens in expert
+    # order, the residual a save_names: / offload_names: policy may pin
+    rows = checkpoint_name(_take(toks, ch.tok), "moe_dispatch")
+    y, pull = jax.vjp(lambda p, r: apply(p, r, ch.sizes, ch.expert),
+                      params, rows)
+    acc = _combine(_zeros(toks.shape, jnp.float32, toks), ch, y)
+
+    def more(i, acc):
+        ch = _chunk(i, weights, *ints)
+        return _combine(acc, ch, apply(params, _take(toks, ch.tok),
+                                       ch.sizes, ch.expert))
+
+    acc = _overflow_loop(order, sizes, more, acc)
+    return acc.astype(toks.dtype), (pull, y, toks, weights, params, ints)
+
+
+def _dispatch_bwd(apply, res, g):
+    pull, y0, toks, weights, params, ints = res
+    n, k = weights.shape
+
+    def back(ch: _Chunk, y, pull, d_toks, d_pairs):
+        """One chunk's gradient with respect to ``params``, and its part
+        added into the tokens' [N, H] and the flat pairs' weights' [N * k],
+        both float32."""
+        g_rows = _take(g, ch.tok).astype(jnp.float32)
+        d_y = jnp.where(ch.valid[:, None], g_rows * ch.weight[:, None], 0)
+        d_params, d_rows = pull(d_y.astype(y.dtype))
+        d_w = (g_rows * y.astype(jnp.float32)).sum(-1)
+        return (d_params,
+                d_toks.at[ch.tok].add(jnp.where(
+                    ch.valid[:, None], d_rows.astype(jnp.float32), 0)),
+                d_pairs.at[ch.pair].add(jnp.where(ch.valid, d_w, 0),
+                                        mode="drop"))
+
+    def more(i, grads):
+        ch = _chunk(i, weights, *ints)
+        y, pull = jax.vjp(lambda p, r: apply(p, r, ch.sizes, ch.expert),
+                          params, _take(toks, ch.tok))
+        d_params, *rest = back(ch, y, pull, *grads[1:])
+        return (jax.tree_util.tree_map(jnp.add, grads[0], d_params), *rest)
+
+    d_params, d_toks, d_pairs = _overflow_loop(*ints[:2], more, back(
+        _chunk(0, weights, *ints), y0, pull,
+        _zeros(toks.shape, jnp.float32, toks),
+        _zeros((n * k,), jnp.float32, toks)))
+    return (d_toks.astype(toks.dtype),
+            d_pairs.reshape(n, k).astype(weights.dtype), d_params,
+            None, None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def routed_apply(toks, expert_idx, weights, first, held: int,
+                 experts: Experts):
     """Send every (token, chosen expert) pair whose expert is one of the
-    ``held`` experts from ``first`` on through ``expert_fn`` and add the
+    ``held`` experts from ``first`` on through ``experts`` and add the
     results back into the tokens, weighted.  No capacity and no dropped
-    pair: the rows are sorted by expert, ``expert_fn(rows [M, H], sizes
-    [held], expert_of_row [M])`` runs grouped products over them
-    (``ops.grouped_matmul``) and the rows come back by a gather.  M is the
-    worst case, every pair on a held expert, rounded up to the kernels' row
-    tile; pairs of experts held elsewhere sort behind the last group, where
-    the kernels write nothing, and are selected away before use.
+    pair, for any routing: the pairs are sorted by expert and the held
+    ones, which sort first, go through ``experts.apply`` a buffer of ``C``
+    rows at a time and come back by a scatter-add in float32.  ``C``
+    (``row_buffer``) follows the share of the router's experts held here,
+    with ``HEADROOM``: one pass where the router is near balance, and as
+    many more as the counted rows need where it is not (``_dispatch``).
+    Where every expert is held ``C`` is the worst case ``m_pad``, every
+    pair, and no loop is built.  Only the sort's int32 vectors have
+    ``m_pad`` entries.  Within a buffer the rows past the last group are
+    never written by the kernels and are selected away before use.
 
     ``toks`` [N, H], ``expert_idx`` / ``weights`` [N, k].  Returns ``(out
     [N, H], sizes [held])``: the held experts' part of the layer's sum and
     the rows each of them got."""
-    from ..ops.grouped_matmul import TM, vary_alike
+    from ..ops.grouped_matmul import vary_alike
     n, k = expert_idx.shape
     m = n * k
-    m_pad = -(-m // TM) * TM if m >= TM else -(-m // 8) * 8
+    m_pad, c = row_buffer(m, held, experts.router_width)
     local = expert_idx.reshape(-1).astype(jnp.int32) - first
-    owned = (local >= 0) & (local < held)
-    key = jnp.pad(jnp.where(owned, local, held), (0, m_pad - m),
-                  constant_values=held)
-    owned = jnp.pad(owned, (0, m_pad - m))
+    key = jnp.pad(jnp.where((local >= 0) & (local < held), local, held),
+                  (0, m_pad - m), constant_values=held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inv = jnp.zeros(m_pad, jnp.int32).at[order].set(
-        jnp.arange(m_pad, dtype=jnp.int32), unique_indices=True)
-    sizes = (key[:, None] == jnp.arange(held)[None]).sum(0, dtype=jnp.int32)
-    # named activation "moe_dispatch" (ISSUE 15): the tokens in expert
-    # order, the residual a save_names: / offload_names: policy may pin
-    toks, order, inv, owned = vary_alike(toks, order, inv, owned)
-    rows = checkpoint_name(_spread(toks, order, inv, owned, k),
-                           "moe_dispatch")
-    y = _collect(*vary_alike(expert_fn(rows, sizes, key[order]), inv, order))
-    y = jnp.where(owned[:, None], y, 0)[:m].reshape(n, k, -1)
-    return (y * weights[..., None].astype(y.dtype)).sum(1), sizes
+    sizes = (jnp.arange(held)[:, None] == key[None]).sum(1, dtype=jnp.int32)
+    # [chunks, c] windows of the sorted order; the last may reach past
+    # m_pad, where no group has rows
+    past = -m_pad % c
+    expert_of_row = jnp.pad(key[order], (0, past),
+                            constant_values=held).reshape(-1, c)
+    order = jnp.pad(order, (0, past), constant_values=m_pad).reshape(-1, c)
+    leaves, tree = jax.tree_util.tree_flatten(experts.params)
+    toks, weights, order, sizes, expert_of_row, *leaves = vary_alike(
+        toks, weights.astype(jnp.float32), order, sizes, expert_of_row,
+        *leaves)
+    out = _dispatch(experts.apply, toks, weights,
+                    jax.tree_util.tree_unflatten(tree, leaves), order, sizes,
+                    expert_of_row)
+    return out, sizes
+
+
+# jitted so that every call site (chunk 0, the overflow loops, their
+# derivatives, the layers of a scanned period) shares one trace and one
+# lowered function: a Pallas call is traced anew wherever it is called,
+# and that tracing is set-up time on the chip's host (PERF.md, PR 33)
+@jax.jit
+def _swiglu_experts(params, rows, sizes, _):
+    from ..ops.grouped_matmul import grouped_matmul
+    w1, w3, w2 = params
+    gate = grouped_matmul(rows, w1, sizes)
+    up = grouped_matmul(rows, w3, sizes)
+    return grouped_matmul(nn.silu(gate) * up, w2, sizes)
 
 
 class RoutedExperts(nn.Module):
@@ -243,7 +399,6 @@ class RoutedExperts(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.grouped_matmul import grouped_matmul
         b, t, h = x.shape
         first, held = self.experts_held or (0, self.num_experts)
         toks = x.reshape(b * t, h)
@@ -276,17 +431,16 @@ class RoutedExperts(nn.Module):
         w2 = self.param("w2", _init, (held, self.ffn_dim, h)).astype(
             self.dtype)
 
-        def experts(rows, sizes, _):
-            gate = grouped_matmul(rows, w1, sizes)
-            up = grouped_matmul(rows, w3, sizes)
-            return grouped_matmul(nn.silu(gate) * up, w2, sizes)
-
-        out, sizes = routed_apply(toks.astype(self.dtype), idx, weights,
-                                  first, held, experts)
+        out, sizes = routed_apply(
+            toks.astype(self.dtype), idx, weights, first, held,
+            Experts(_swiglu_experts, (w1, w3, w2), self.num_experts))
         rows = sizes.sum().astype(jnp.float32)
         self.sow("counters", "expert_rows", rows)
         self.sow("counters", "expert_load_max_over_mean",
                  sizes.max() * held / jnp.maximum(rows, 1.0))
+        self.sow("counters", "expert_overflow_chunks", overflow_chunks(
+            sizes, row_buffer(idx.size, held, self.num_experts)[1]).astype(
+                jnp.float32))
         return out.reshape(b, t, h)
 
 

@@ -192,8 +192,10 @@ class TestRoutedExperts:
 
     def test_softmax_rule_is_the_parents_program(self):
         """``mellum2_12b_a2p5b``'s routed layer and the tiny preset's whole
-        model trace to the jaxprs they traced to before the router's rule
-        became data (read off the parent, 0e9225a, by this code)."""
+        model trace to one program whatever the rule's defaults are spelt
+        as: the jaxprs they traced to before the router's rule became data
+        (0e9225a), read again by this code at ISSUE 33, whose row buffer
+        changes every routed layer's program."""
         layer = RoutedExperts(64, 896, 8, experts_held=(0, 16),
                               dtype=jnp.bfloat16)
         x = jax.ShapeDtypeStruct((1, 512, 2304), jnp.bfloat16)
@@ -201,7 +203,7 @@ class TestRoutedExperts:
             jax.random.key(0), jnp.zeros(x.shape, x.dtype))["params"])
         assert _jaxpr_pin(jax.grad(lambda p, x: layer.apply(
             {"params": p}, x).astype(jnp.float32).sum(), (0, 1)),
-            params, x) == "6bed96ac086bf799"
+            params, x) == "bf35b2a9b528ec55"
         model = get_model("mellum2_tiny", num_classes=1000, scan_layers=True,
                           remat_policy="everything")
         ids = jax.ShapeDtypeStruct((2, 64), jnp.int32)
@@ -209,7 +211,7 @@ class TestRoutedExperts:
             jax.random.key(0), jnp.zeros((2, 64), jnp.int32))["params"])
         assert _jaxpr_pin(jax.grad(lambda p, i: model.apply(
             {"params": p}, i, train=True).sum()), mp, ids) == \
-            "dedaffc704490c02"
+            "9a7f31164e44f516"
 
 
 def _jaxpr_pin(fn, *args) -> str:

@@ -167,10 +167,11 @@ def jaxpr_pins(engine, params, x):
 # without the marker, and BertForMLM on the full path, lower
 # ``_loss_and_metrics`` and ``eval_step`` to what they lowered to before
 # the row buffer came.  A PR that changes these programs on purpose reads
-# new pins.
+# new pins (ISSUE 33 did for ``mellum2_tiny``: the routed layer's own row
+# buffer changes every sparse model's program).
 @pytest.mark.parametrize("name,model_kw,gathered,pins", [
     ("gpt_tiny", {"max_len": 64}, None, ("c57bfdbd21618319", "a1ab63707a8bd3de")),
-    ("mellum2_tiny", {"num_classes": 1000}, None, ("7028348e38f7f187", "cd173dc0e54c3637")),
+    ("mellum2_tiny", {"num_classes": 1000}, None, ("3e29d7343587c5c0", "b2860b11533c0216")),
     ("bert_tiny", {"max_len": 64}, False, ("0c671134bb022f23", "449a1135f0dcdc7a")),
 ])
 def test_unmarked_programs_are_the_parents(devices, name, model_kw, gathered,

@@ -417,3 +417,131 @@ class TestDriverMoEOneF1B:
         # sparsely-updated embedding rows (see test_pp.py's 1f1b_sp
         # leaf-aware bounds)
         _assert_params_close(onef, gpipe, atol=5e-3)
+
+
+# ----------------------------------------------------------------------
+# The dispatch both routed layers share, on its row buffer (ISSUE 33)
+# ----------------------------------------------------------------------
+
+N_TOK, TOP_K, HID, FFN = 64, 2, 32, 48     # 128 pairs a step
+
+
+def _routing(case: str, width: int, first: int, held: int, c: int):
+    """``expert_idx`` [N_TOK, TOP_K] for a case, and the rows it puts on
+    the ``held`` experts from ``first`` on."""
+    rng = np.random.default_rng(5)
+    away = np.array([e for e in range(width)
+                     if not first <= e < first + held])
+    if case == "seeded":
+        idx = np.argsort(rng.normal(size=(N_TOK, width)), -1)[:, :TOP_K]
+    elif case == "every_pair_held":
+        idx = first + rng.integers(0, held, size=(N_TOK, TOP_K))
+    else:
+        rows = {"none_held": 0, "rows_fill_buffer": c,
+                "rows_one_over": c + 1, "all_experts_held": 0}[case]
+        idx = rng.choice(away, size=N_TOK * TOP_K) if len(away) else \
+            rng.integers(0, width, size=N_TOK * TOP_K)
+        here = rng.choice(N_TOK * TOP_K, size=rows, replace=False)
+        idx[here] = first + rng.integers(0, held, size=rows)
+        idx = idx.reshape(N_TOK, TOP_K)
+    on_held = int(((idx >= first) & (idx < first + held)).sum())
+    return jnp.asarray(idx, jnp.int32), on_held
+
+
+def _per_token_loop(toks, idx, weights, first, params):
+    """The plain layer: every token's every choice in turn, through its
+    expert's three matrices if the expert is held, nothing shared with
+    the program."""
+    w1, w3, w2 = params
+    out = []
+    for t in range(toks.shape[0]):
+        acc = jnp.zeros(toks.shape[1], jnp.float32)
+        for j in range(idx.shape[1]):
+            e = int(idx[t, j]) - first
+            if 0 <= e < w1.shape[0]:
+                h = jax.nn.silu(toks[t] @ w1[e]) * (toks[t] @ w3[e])
+                acc = acc + weights[t, j] * (h @ w2[e])
+        out.append(acc)
+    return jnp.stack(out)
+
+
+def _has_loop(jaxpr) -> bool:
+    return any(eqn.primitive.name == "while" or any(
+        _has_loop(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
+class TestRowBuffer:
+    """``routed_apply`` computes on a buffer of ``C`` rows that follows the
+    held share of the router, and walks what does not fit: exact for every
+    routing, against the plain per-token loop, values and the gradients
+    with respect to the tokens, the weights and each expert matrix."""
+
+    def _inputs(self, held):
+        rng = np.random.default_rng(3)
+        f32 = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+        params = (0.2 * f32(held, HID, FFN), 0.2 * f32(held, HID, FFN),
+                  0.2 * f32(held, FFN, HID))
+        return (f32(N_TOK, HID), jnp.abs(f32(N_TOK, TOP_K)), params,
+                f32(N_TOK, HID))
+
+    @pytest.mark.parametrize("case,width,first,held", [
+        ("seeded", 8, 3, 1),               # a held share of 1/8
+        ("every_pair_held", 16, 4, 2),     # every chunk walked
+        ("none_held", 16, 4, 2),
+        ("rows_fill_buffer", 16, 4, 2),
+        ("rows_one_over", 16, 4, 2),
+        ("all_experts_held", 4, 0, 4),     # the worst case is the buffer
+    ])
+    def test_against_the_per_token_loop(self, assert_within_ulps, case,
+                                        width, first, held):
+        from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import moe
+        m_pad, c = moe.row_buffer(N_TOK * TOP_K, held, width)
+        assert m_pad == N_TOK * TOP_K
+        assert c == (m_pad if held == width else 24)
+        idx, on_held = _routing(case, width, first, held, c)
+        toks, weights, params, probe = self._inputs(held)
+
+        def program(toks, weights, params):
+            out, sizes = moe.routed_apply(
+                toks, idx, weights, first, held,
+                moe.Experts(moe._swiglu_experts, params, width))
+            return (out * probe).sum(), (out, sizes)
+
+        def plain(toks, weights, params):
+            out = _per_token_loop(toks, idx, weights, first, params)
+            return (out * probe).sum(), out
+
+        grads, (out, sizes) = jax.jit(jax.grad(
+            program, (0, 1, 2), has_aux=True))(toks, weights, params)
+        want_grads, want = jax.grad(plain, (0, 1, 2), has_aux=True)(
+            toks, weights, params)
+        # no pair dropped, and the overflow walked as often as it has to be
+        assert int(sizes.sum()) == on_held
+        walked = int(moe.overflow_chunks(sizes, c))
+        assert walked == {"seeded": 0, "every_pair_held": -(-m_pad // c) - 1,
+                          "none_held": 0, "rows_fill_buffer": 0,
+                          "rows_one_over": 1, "all_experts_held": 0}[case]
+        assert_within_ulps(out, want, 8)
+        for got, ref in zip(jax.tree_util.tree_leaves(grads),
+                            jax.tree_util.tree_leaves(want_grads)):
+            assert_within_ulps(got, ref, 16)
+        jaxpr = jax.make_jaxpr(jax.grad(program, (0, 1, 2), has_aux=True))(
+            toks, weights, params).jaxpr
+        assert _has_loop(jaxpr) == (held != width)
+
+    def test_the_same_step_twice_is_the_same_bits(self):
+        """The way back into the tokens is a scatter-add, in a fixed order:
+        one program run twice gives the same bits, overflow walked or not."""
+        from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import moe
+        toks, weights, params, probe = self._inputs(2)
+        for case in ("seeded", "every_pair_held"):
+            idx, _ = _routing(case, 16, 4, 2, 24)
+            step = jax.jit(jax.value_and_grad(lambda t, w, p: (
+                moe.routed_apply(t, idx, w, 4, 2, moe.Experts(
+                    moe._swiglu_experts, p, 16))[0] * probe).sum(),
+                (0, 1, 2)))
+            for a, b in zip(
+                    jax.tree_util.tree_leaves(step(toks, weights, params)),
+                    jax.tree_util.tree_leaves(step(toks, weights, params))):
+                np.testing.assert_array_equal(a, b)

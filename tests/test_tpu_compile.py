@@ -141,3 +141,41 @@ def test_grouped_expert_products_lower_for_v5e(one_chip, monkeypatch):
         sds(65536, 2304), sds(16, 2304, 896), sds(16, 2304, 896),
         sds(16, 896, 2304), sds(16, d=jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 8
+
+
+def test_routed_layer_on_its_row_buffer_lowers_for_v5e(one_chip, monkeypatch):
+    """trinity_mini_train_8k's routed layer (ISSUE 33), forward and
+    backward under ``jax.checkpoint``: 8 of 128 experts held, 8 a token,
+    8,192 tokens of 2,048.  Everything with 2,048 or 1,024 columns has the
+    buffer's 5,120 rows: no array of the worst case's 65,536 rows has more
+    than one column (the sort's int32 vectors do).  The grouped products,
+    chunk 0's and the overflow loop's, are the Mosaic calls of four
+    operands and one output that the accepted ``kernels/moe_gmm.json``
+    picks."""
+    import re
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import moe
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.models.arch import Router
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    assert moe.row_buffer(8192 * 8, 8, 128) == (65536, 5120)
+    layer = moe.RoutedExperts(128, 1024, 8, experts_held=(0, 8),
+                              dtype=jnp.bfloat16,
+                              router=Router(score="sigmoid"))
+    shaped = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+    x = shaped(jnp.zeros((1, 8192, 2048), jnp.bfloat16))
+    params = jax.tree_util.tree_map(shaped, jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), jnp.zeros(
+            x.shape, x.dtype))["params"]))
+    block = jax.checkpoint(lambda p, x: layer.apply(
+        {"params": p}, x, mutable=["counters"])[0])
+    text = jax.jit(jax.grad(lambda p, x: block(p, x).astype(
+        jnp.float32).sum(), (0, 1))).lower(params, x).compile().as_text()
+    assert not re.findall(r"\w+\[65536,\d+\]", text)
+    assert re.findall(r"bf16\[5120,2048\]", text)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    # chunk 0's 3 forward products, recomputed (the recomputed forward's
+    # overflow loop is dead code: the backward recomputes those chunks
+    # itself); the backward's 6 of chunk 0, and 3 + 6 in its loop
+    assert len(calls) == 18
+    assert {kernel_of(line, load_kernels()) for line in calls} == {"moe_gmm"}
