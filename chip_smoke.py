@@ -246,6 +246,7 @@ def check_training(results: dict, n_dev: int, *, flash: bool) -> None:
     say(f"compiled programs: {sorted(results['memory']['programs'])}, "
         f"{results['memory']['temp_bytes_total'] / 2**20:.0f} MiB temp; "
         f"compile cache {json.dumps(results['compile_cache'])}")
+    say_hbm_and_build(results)
     if flash:
         text = results["engine"].memory_programs()["round"].compiled.as_text()
         n_calls = text.count("tpu_custom_call")
@@ -257,6 +258,46 @@ def check_training(results: dict, n_dev: int, *, flash: bool) -> None:
             raise AssertionError(
                 f"flash fell back to dense: {pallas_ops._FALLBACK_LOGGED}")
         say_flash_tiles()
+
+
+def say_hbm_and_build(results: dict) -> None:
+    """The allocator's reading at each set-up phase and each round of the
+    call (the fullest chip; MiB in use / high mark), and round 0's build
+    in its three stages.  On a TPU every stamp must be there, the high
+    mark must never fall and the stages must add up to the build."""
+    import jax
+    mib = lambda b: f"{b / 2**20:.1f}"
+    rows = results["round_timings"]
+    hbm = results["memory"].get("hbm")
+    if hbm is None:
+        if jax.devices()[0].platform == "tpu":
+            raise AssertionError("the program read no allocator statistics")
+        say("hbm: this backend's allocator keeps no statistics")
+    else:
+        stamps = ([("on entry", hbm["on_entry"])]
+                  + [(p["phase"], p) for p in hbm["phases"]]
+                  + [(f"round {r}", row) for r, row in enumerate(rows)]
+                  + [("at end", hbm["at_end"])])
+        say("hbm MiB in use / peak (limit " + mib(hbm["limit_bytes"]) + "): "
+            + ", ".join(f"{label} {mib(s['hbm_in_use_bytes'])} / "
+                        f"{mib(s['hbm_peak_bytes'])}" for label, s in stamps)
+            + f"; largest allocation "
+              f"{mib(hbm['at_end'].get('largest_alloc_size', 0))}")
+        peaks = [s["hbm_peak_bytes"] for _, s in stamps]
+        # entry, set-up's five phases, a row a round, the end
+        if len(stamps) != 7 + len(rows) or peaks != sorted(peaks):
+            raise AssertionError(f"allocator stamps missing or the high "
+                                 f"mark fell: {stamps}")
+    row0 = rows[0]
+    parts = [row0[k] for k in ("build_trace_ms", "build_lower_ms",
+                               "build_compile_ms")]
+    say(f"round 0 built {row0['programs_built']} in {row0['build_ms']:.1f} "
+        "ms: trace {:.1f}, lower {:.1f}, compile-or-load {:.1f} ".format(
+            *parts)
+        + f"(cache hits {row0['build_cache_hits']}, misses "
+          f"{row0['build_cache_misses']})")
+    if not 0.95 * row0["build_ms"] <= sum(parts) <= row0["build_ms"] + 0.01:
+        raise AssertionError(f"the build's stages do not add up: {row0}")
 
 
 def state_leaves(state) -> list:

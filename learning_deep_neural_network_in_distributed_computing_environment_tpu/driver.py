@@ -55,6 +55,7 @@ from .mesh import (DATA_AXIS, EXPERT_AXIS, MODEL_AXIS, PIPE_AXIS,
                    SLICE_AXIS, build_mesh, initialize_distributed,
                    max_data_axis_size, resize_data_axis, world_size)
 from .models import get_model, is_attention_model, is_token_model
+from . import spans
 from .spans import span
 from .train import LocalSGDEngine, rank0_variables
 
@@ -236,6 +237,27 @@ def _measured_worker_walls(wall: float, n: int) -> np.ndarray:
             f"worker axis ({n}) not evenly divided by process count "
             f"({len(walls)}); per-process wall attribution would be wrong")
     return np.repeat(walls, per)
+
+
+def _last_peak_rise(hbm: dict, rows: list[dict]) -> str:
+    """The tail of the ``set-up:`` line: the last of the call's stamps
+    (entry, set-up's phases, the rounds' rows, in that order) at which
+    the allocator's high mark stood above the mark before it, and by how
+    much.  Nothing where no stamp carries a reading."""
+    marks = ([("on entry", hbm["on_entry"])]
+             + [(row["phase"], row) for row in hbm["phases"]]
+             + [(f"round {r}", row) for r, row in enumerate(rows)])
+    peaks = [(label, row["hbm_peak_bytes"]) for label, row in marks
+             if "hbm_peak_bytes" in row]
+    if not peaks:
+        return ""
+    tail = f"; HBM peak {peaks[-1][1] / 2**30:.3f} GiB"
+    for (_, before), (label, after) in reversed(
+            list(zip(peaks, peaks[1:]))):
+        if after > before:
+            return tail + (f", last raised at {label!r} by "
+                           f"{(after - before) / 2**20:.1f} MiB")
+    return tail + ", as on entry"
 
 
 def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
@@ -444,8 +466,29 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                           "quarantined_rounds": 0}
 
     # stamps between set-up's phases, which follow one another from here
-    # to the probe's end (results["setup_timings"])
+    # to the probe's end (results["setup_timings"]), and the allocator's
+    # reading at each (results["memory"]["hbm"]; nothing on a backend
+    # that keeps no statistics)
     t_setup = [time.perf_counter()]
+    hbm: dict[str, Any] = {"on_entry": {}, "phases": []}
+
+    def read_hbm(row: dict) -> dict | None:
+        """``spans.hbm`` over this process's chips of the mesh as it is
+        now (an elastic boundary rebuilds it)."""
+        return spans.hbm(row, [d for d in mesh.devices.flat
+                               if d.process_index == jax.process_index()])
+
+    def setup_stamp(phase: str) -> None:
+        # the reading first: its host time belongs to the phase it closes
+        # (a run without a checkpoint still reads restore_s 0.000)
+        row = {"phase": phase}
+        if read_hbm(row):
+            hbm["phases"].append(row)
+        t_setup.append(time.perf_counter())
+
+    entry_stats = read_hbm(hbm["on_entry"])
+    if entry_stats:
+        hbm["limit_bytes"] = int(entry_stats.get("bytes_limit", 0))
 
     # --- data ---------------------------------------------------------
     if datasets is None:
@@ -457,7 +500,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         trainset, valset, test = datasets
     num_classes = trainset.num_classes
     batch = cfg.batch_size
-    t_setup.append(time.perf_counter())     # data_s
+    setup_stamp("data")
 
     # --- model + engine -------------------------------------------------
     train_model = None
@@ -805,7 +848,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         if elastic_snapshot.params_template is not None:
             engine.params_template = elastic_snapshot.params_template
         state = engine.stage_state(elastic_snapshot.host_state)
-    t_setup.append(time.perf_counter())     # engine_s
+    setup_stamp("engine")
 
     # --- checkpoint engine + resume (beyond-reference; off when no dir) --
     # Opening the engine sweeps stale mid-write leftovers (.tmp files,
@@ -874,7 +917,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 num_slices=engine.n_slices)
             state = engine.refresh_buddy(state)
             log.info("resumed from %s at global epoch %d", latest, start_epoch)
-    t_setup.append(time.perf_counter())     # restore_s
+    setup_stamp("restore")
 
     # --- probe -> ratios -> initial partition ---------------------------
     if elastic_snapshot is None:
@@ -915,7 +958,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         rng.bit_generator.state = copy.deepcopy(elastic_snapshot.rng_state)
         log.info("continuing from membership snapshot: round %d, "
                  "workers %s", start_epoch, worker_ids)
-    t_setup.append(time.perf_counter())     # probe_s
+    setup_stamp("probe")
 
     # --- reference metric structures (trainer.py:13-25) -----------------
     results: dict[str, Any] = {
@@ -1237,6 +1280,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     if start_epoch < cfg.epochs_global:
         with span("setup.first_prep", setup_timings, "first_prep_s"):
             prep = make_prep(train_parts, val_parts)
+    setup_stamp("first_prep")
     t_ready = None
     # deep pipeline only: the round whose completion barrier was deferred
     inflight: list = []            # [(epoch, markers, t_disp, timing, steps)]
@@ -1323,6 +1367,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         with span("round.wait", timing_, "wait_ms", round=ep):
             jax.block_until_ready(markers)
         t_done = timing_["t_ready_s"] = time.perf_counter()
+        read_hbm(timing_)
         start = t_disp_ if t_done_prev[0] is None \
             else max(t_disp_, t_done_prev[0])
         t_done_prev[0] = t_done
@@ -1706,10 +1751,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 # which dispatch built a program (trace + lower +
                 # compile or cache load): round 0's as a rule, and any
                 # later one is "this round recompiled"
-                built = engine.take_builds()
-                timing["build_ms"] = round(
-                    sum((ms for _, ms in built), 0.0), 3)
-                timing["programs_built"] = [name for name, _ in built]
+                timing.update(probe_lib.fold_builds(engine.take_builds()))
                 if engine.last_sync_stats:
                     # static per-round sync telemetry (bytes on the wire,
                     # mode); the measured sync_ms joins after round_wait
@@ -1751,6 +1793,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                     if engine.last_sync_stats:
                         timing.update(engine.last_sync_stats)
                     t_ready = timing["t_ready_s"] = time.perf_counter()
+                    read_hbm(timing)
                     # the barrier round right after a deferred one also
                     # started computing only when its predecessor
                     # finished (same double-count hazard finish_inflight
@@ -1879,12 +1922,16 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         row0 = results["round_timings"][0]
         log.info("set-up: data %.3f s, engine %.3f s, restore %.3f s, "
                  "probe %.3f s, first prep %.3f s; the first round built "
-                 "%s in %.1f ms of a %.1f ms dispatch",
+                 "%s in %.1f ms (trace %.1f, lower %.1f, compile %.1f: %d "
+                 "cache hit(s), %d miss(es)) of a %.1f ms dispatch%s",
                  *(setup_timings[k] for k in (
                      "data_s", "engine_s", "restore_s", "probe_s",
                      "first_prep_s")),
-                 row0["programs_built"], row0["build_ms"],
-                 row0["stage_ms"])
+                 row0["programs_built"], *(row0[k] for k in (
+                     "build_ms", "build_trace_ms", "build_lower_ms",
+                     "build_compile_ms", "build_cache_hits",
+                     "build_cache_misses", "stage_ms")),
+                 _last_peak_rise(hbm, results["round_timings"]))
 
     # persistent-compile-cache effectiveness for THIS run (ROADMAP open
     # item): how many executable lookups the armed cache served vs compiled
@@ -1926,6 +1973,14 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
             or getattr(engine, "sim_staleness", 0) > 0):
         state = engine.drain_pending(state)
 
+    # the allocator once more, every number it gives, now that the call's
+    # last program has settled
+    hbm["at_end"] = {}
+    end_stats = read_hbm(hbm["at_end"])
+    if end_stats:
+        hbm["at_end"].update((k, int(v)) for k, v in end_stats.items()
+                             if isinstance(v, (int, float)))
+
     # compiled-memory observability (ISSUE 15): recorded like
     # sync_engine / sanitize — every run artifact carries XLA's
     # memory_analysis of every cached executable this run compiled
@@ -1939,7 +1994,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     results["memory"] = probe_lib.memory_report(
         engine.memory_programs(),
         state_bytes=engine.state_resident_bytes(state),
-        n_workers=n, sim=sim_on)
+        n_workers=n, sim=sim_on, hbm=hbm if end_stats else None)
     log.info(
         "compiled memory: %d program(s), %.2f MB temp total; per-worker "
         "resident state %.2f MB (+%.2f MB transient gather peak), "

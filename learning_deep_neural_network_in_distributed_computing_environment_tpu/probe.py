@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .spans import span
+from .xla_flags import compile_cache_counts
 
 
 # ----------------------------------------------------------------------
@@ -39,13 +40,17 @@ from .spans import span
 # CPU cannot see HBM walls, so memory must be a MEASURED, asserted
 # quantity on every compiled program: each engine wraps its cached jit
 # programs in a TrackedProgram, which compiles ahead-of-time on first
-# call (lower().compile() — the same one trace + one backend compile the
-# jit path would pay; verified against the compile-event counter) and
-# keeps the jax.stages.Compiled handle so ``memory_report`` can read
-# XLA's ``memory_analysis()`` (temp/argument/output/alias bytes) without
-# ever re-lowering.  Calls after the first dispatch straight on the
-# compiled executable — donation, shardings, and fp32 numerics are
-# bitwise those of the jit path (tests/test_remat_memory.py pins this).
+# call (trace().lower().compile(), the three stages apart — the same one
+# trace + one backend compile the jit path would pay; verified against
+# the compile-event counter) and keeps the jax.stages.Compiled handle so
+# ``memory_report`` can read XLA's ``memory_analysis()``
+# (temp/argument/output/alias bytes) without ever re-lowering.  Calls
+# after the first dispatch straight on the compiled executable —
+# donation, shardings, and fp32 numerics are bitwise those of the jit
+# path (tests/test_remat_memory.py pins this).  What the compiler counts
+# for ONE program is not what the device's allocator holds for the
+# process: ``memory_report`` carries the allocator's own readings beside
+# it (``hbm``; ``spans.hbm`` takes them).
 
 
 class TrackedProgram:
@@ -66,9 +71,18 @@ class TrackedProgram:
     ``_compile`` is the one site where a program is traced, lowered and
     compiled (or loaded from the compile cache).  It is a span under the
     owner's name for it (``build_span``; the round loop's engine says
-    ``round.build``) and appends ``(name, build_ms)`` to ``built`` — the
-    list an engine shares among its programs and the driver drains into
-    the row of the round whose dispatch built them.
+    ``round.build``) with one child span a stage (``.trace``: the host's
+    Python running the function into a jaxpr; ``.lower``: the jaxpr into
+    StableHLO; ``.compile``: the backend's compile, or the persistent
+    cache's load of an executable it already has) and appends ``(name,
+    row)`` to ``built`` — the list an engine shares among its programs
+    and the driver drains into the row of the round whose dispatch built
+    them.  ``row`` holds ``build_ms`` and its parts ``build_trace_ms``,
+    ``build_lower_ms``, ``build_compile_ms``, and what the persistent
+    cache said during the compile stage, ``build_cache_hits`` /
+    ``build_cache_misses`` (one hit and no miss: the stage was a load;
+    both zero: no cache is armed, or the program is one the cache does
+    not keep).
     """
 
     def __init__(self, name: str, fn, *, multi_shape: bool = False,
@@ -91,10 +105,20 @@ class TrackedProgram:
 
     def _compile(self, args, kwargs):
         row: dict = {}
-        with span(self._build_span, row, "build_ms", program=self.name):
-            comp = self._fn.lower(*args, **kwargs).compile()
+        stage, ids = self._build_span, {"program": self.name}
+        with span(stage, row, "build_ms", **ids):
+            with span(f"{stage}.trace", row, "build_trace_ms", **ids):
+                traced = self._fn.trace(*args, **kwargs)
+            with span(f"{stage}.lower", row, "build_lower_ms", **ids):
+                lowered = traced.lower()
+            cache0 = compile_cache_counts()
+            with span(f"{stage}.compile", row, "build_compile_ms", **ids):
+                comp = lowered.compile()
+            cache1 = compile_cache_counts()
+        row["build_cache_hits"] = cache1["hits"] - cache0["hits"]
+        row["build_cache_misses"] = cache1["misses"] - cache0["misses"]
         if self._built is not None:
-            self._built.append((self.name, row["build_ms"]))
+            self._built.append((self.name, row))
         return comp
 
     def __call__(self, *args, **kwargs):
@@ -122,6 +146,21 @@ class TrackedProgram:
         return [memory_analysis_row(c) for c in self.executables()]
 
 
+def fold_builds(built: list) -> dict:
+    """The row keys of a dispatch from the ``(name, row)`` pairs its
+    programs' builds left in ``built``: each duration and each cache
+    count summed over the programs, and their names.  No build: zeros
+    and ``[]``, which is what every round but the first reads."""
+    rows = [row for _, row in built]
+    out: dict = {key: round(sum((r[key] for r in rows), 0.0), 3)
+                 for key in ("build_ms", "build_trace_ms", "build_lower_ms",
+                             "build_compile_ms")}
+    for key in ("build_cache_hits", "build_cache_misses"):
+        out[key] = sum(r[key] for r in rows)
+    out["programs_built"] = [name for name, _ in built]
+    return out
+
+
 def memory_analysis_row(compiled) -> dict:
     """XLA's compiled-memory stats for one executable, as plain ints:
     ``temp_bytes`` (scratch + saved activations — the quantity the remat
@@ -140,11 +179,15 @@ def memory_analysis_row(compiled) -> dict:
 
 
 def memory_report(programs: dict, *, state_bytes: dict | None = None,
-                  n_workers: int = 1, sim: bool = False) -> dict:
+                  n_workers: int = 1, sim: bool = False,
+                  hbm: dict | None = None) -> dict:
     """The uniform ``results["memory"]`` row (ISSUE 15) — emitted on
     every run like ``sync_engine`` / ``sanitize``.
 
-    Two views of the same wall:
+    Two views of the same wall, and the wall's own reading where the
+    backend gives one (``hbm``: the allocator's statistics as the driver
+    stamped them at every set-up phase and at the call's end; the key is
+    absent where the backend reports none):
 
     - **compiled**: per-program ``memory_analysis()`` of every cached
       executable (``programs``: name -> TrackedProgram).  ``temp_bytes``
@@ -191,6 +234,8 @@ def memory_report(programs: dict, *, state_bytes: dict | None = None,
         # simulated run (N x per-worker by construction — the measured
         # form of the sim-lab N-ceiling)
         report["state_bytes_total"] = resident * int(n_workers)
+    if hbm:
+        report["hbm"] = hbm
     return report
 
 

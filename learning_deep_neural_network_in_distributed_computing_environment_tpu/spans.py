@@ -14,6 +14,12 @@ The key's suffix is its unit: ``_ms`` is milliseconds and ``_s`` seconds,
 both to three decimals.  Absolute stamps (``t_dispatch_s``,
 ``t_ready_s``, and those between set-up's phases) are the driver's own
 ``perf_counter`` readings, written beside the durations they bound.
+
+``hbm`` is the counter beside it: the device allocator's own statistics
+(``Device.memory_stats()``), written into the same rows at the same
+sites (``hbm_in_use_bytes``, ``hbm_peak_bytes``: whole bytes).  A reading
+is written only where the backend reports one: the CPU's allocator keeps
+no statistics, and a row of a CPU run has neither key.
 """
 
 from __future__ import annotations
@@ -22,6 +28,30 @@ import contextlib
 import time
 
 import jax
+
+
+def device_stats(device) -> dict | None:
+    """The allocator's statistics of one device, or ``None`` where the
+    backend keeps none (the CPU)."""
+    return device.memory_stats()
+
+
+def hbm(row: dict, devices) -> dict | None:
+    """Read the allocator of each of ``devices`` and write into ``row``
+    what the fullest chip holds now, ``hbm_in_use_bytes``
+    (``bytes_in_use``), and the highest mark any chip has reached since
+    the process started, ``hbm_peak_bytes`` (``peak_bytes_in_use``: the
+    allocator never lowers it).  Returns the statistics of the chip with
+    the highest mark, whole; where no device reports any it writes
+    nothing and returns ``None``.  One host call a device, nothing on
+    the device."""
+    stats = [s for s in map(device_stats, devices) if s]
+    if not stats:
+        return None
+    row["hbm_in_use_bytes"] = max(int(s["bytes_in_use"]) for s in stats)
+    fullest = max(stats, key=lambda s: s["peak_bytes_in_use"])
+    row["hbm_peak_bytes"] = int(fullest["peak_bytes_in_use"])
+    return fullest
 
 
 def _scaled(seconds: float, key: str) -> float:

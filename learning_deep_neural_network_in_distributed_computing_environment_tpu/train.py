@@ -559,8 +559,9 @@ class LocalSGDEngine:
         # stable label — probe.memory_report walks this registry into
         # the uniform results["memory"] row
         self._programs: dict[str, probe_lib.TrackedProgram] = {}
-        # (label, build_ms) of every program built since ``take_builds``
-        self._built: list[tuple[str, float]] = []
+        # (label, row of build durations) of every program built since
+        # ``take_builds``
+        self._built: list[tuple[str, dict]] = []
         self._spec = (P((SLICE_AXIS, DATA_AXIS)) if self.slice_axis
                       else P(DATA_AXIS))
         # --- round-sync engine selection (ISSUE 2 / ISSUE 13) ----------
@@ -1016,11 +1017,12 @@ class LocalSGDEngine:
             self._round_cache[key] = tp
         return tp
 
-    def take_builds(self) -> list[tuple[str, float]]:
-        """``(label, build_ms)`` of every program traced, lowered and
-        compiled (or loaded from the compile cache) since the last call:
-        the driver folds them into the row of the round whose dispatch
-        built them (``build_ms``, ``programs_built``)."""
+    def take_builds(self) -> list[tuple[str, dict]]:
+        """``(label, row)`` of every program traced, lowered and compiled
+        (or loaded from the compile cache) since the last call, ``row``
+        as ``probe.TrackedProgram._compile`` fills it: the driver folds
+        them into the row of the round whose dispatch built them
+        (``build_ms`` and its parts, ``programs_built``)."""
         built, self._built[:] = list(self._built), []
         return built
 

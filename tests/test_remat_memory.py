@@ -282,18 +282,18 @@ class TestTrackedProgram:
     def test_single_shape_compiles_once_and_tracks(self):
         calls = []
         inner = jax.jit(lambda a: a * 2)
-        orig_lower = inner.lower
+        orig_trace = inner.trace
 
-        def counting_lower(*a, **k):
+        def counting_trace(*a, **k):
             calls.append(1)
-            return orig_lower(*a, **k)
-        inner.lower = counting_lower
+            return orig_trace(*a, **k)
+        inner.trace = counting_trace
         tp = probe.TrackedProgram("p", inner)
         x = jnp.arange(4.0)
         assert np.array_equal(np.asarray(tp(x)), np.asarray(x) * 2)
         tp(x)
         tp(x)
-        assert len(calls) == 1          # one AOT lower+compile total
+        assert len(calls) == 1          # one AOT trace+lower+compile total
         rows = tp.memory_rows()
         assert len(rows) == 1
         for key in ("temp_bytes", "argument_bytes", "output_bytes",

@@ -1,8 +1,10 @@
 """The round loop's span primitive (ISSUE 24): what the rows of
 ``round_timings`` carry in each pipeline mode, that the stamps and the
 durations agree, which dispatch built a program, where the wait on the
-sync is timed, the host spans in the profiler's trace, and set-up's phases.  Counts and
-control flow only: nothing here is a time of a device."""
+sync is timed, the host spans in the profiler's trace, and set-up's phases;
+the allocator counter beside it and the build's three stages (ISSUE 34).
+Counts and control flow only: nothing here is a time of a device, and the
+allocator's numbers are a stub's."""
 
 import glob
 import re
@@ -23,8 +25,12 @@ from learning_deep_neural_network_in_distributed_computing_environment_tpu.confi
 from learning_deep_neural_network_in_distributed_computing_environment_tpu.driver import train_global
 
 ROUNDS = 4
+BUILD_PARTS = ("build_trace_ms", "build_lower_ms", "build_compile_ms")
+BUILD_COUNTS = ("build_cache_hits", "build_cache_misses")
 NEW_KEYS = ("t_dispatch_s", "t_ready_s", "wait_ms", "build_ms",
-            "programs_built")
+            "programs_built") + BUILD_PARTS + BUILD_COUNTS
+HBM_KEYS = ("hbm_in_use_bytes", "hbm_peak_bytes")
+PHASES = ["data", "engine", "restore", "probe", "first_prep"]
 KEPT_KEYS = ("stage_ms", "compute_ms", "fetch_ms", "assemble_ms",
              "sync_bytes", "ckpt_snapshot_ms", "ckpt_write_ms")
 SETUP_KEYS = ("data_s", "engine_s", "restore_s", "probe_s", "first_prep_s")
@@ -125,6 +131,149 @@ def test_round_0_alone_built_programs(run):
         assert re.match(rf"HloModule jit_localsgd_{label}\b", text)
 
 
+def test_round_0s_build_is_in_three_parts(run):
+    """trace + lower + compile-or-load are the build: each part a span's
+    duration, together the ``round.build`` span less the few lines between
+    them; a round that built nothing reads 0.0 in each."""
+    _, res, _ = run
+    rows = res["round_timings"]
+    parts = [rows[0][k] for k in BUILD_PARTS]
+    assert all(p >= 0.0 for p in parts)
+    assert sum(parts) <= rows[0]["build_ms"] + 0.002
+    assert sum(parts) == pytest.approx(rows[0]["build_ms"], rel=0.05)
+    # no compile cache is armed in the tests
+    assert [rows[0][k] for k in BUILD_COUNTS] == [0, 0]
+    for row in rows[1:]:
+        assert [row[k] for k in BUILD_PARTS] == [0.0] * 3
+        assert [row[k] for k in BUILD_COUNTS] == [0, 0]
+
+
+def test_a_backend_without_statistics_writes_no_reading(run):
+    """The CPU's ``memory_stats()`` is ``None``: no row has an allocator
+    key, ``results["memory"]`` has no ``hbm``, nothing else changes."""
+    assert jax.devices()[0].memory_stats() is None
+    _, res, _ = run
+    for row in res["round_timings"]:
+        assert not set(HBM_KEYS) & set(row)
+    assert "hbm" not in res["memory"]
+
+
+class FakeAllocator:
+    """Stands in for ``spans.device_stats`` on the eight devices of
+    ``mesh8``: ``bytes_in_use`` follows a script a reading, the high mark
+    never falls, device ``i`` holds ``i`` KiB more than device 0."""
+
+    def __init__(self):
+        self.calls = 0
+        self.peak: dict[int, int] = {}
+
+    def __call__(self, device):
+        reading, self.calls = self.calls // 8, self.calls + 1
+        in_use = (1 << 20) + 65536 * (reading % 7) + 1024 * device.id
+        peak = self.peak[device.id] = max(self.peak.get(device.id, 0),
+                                          in_use + 512)
+        return {"bytes_in_use": in_use, "peak_bytes_in_use": peak,
+                "bytes_limit": 1 << 34, "largest_alloc_size": 1 << 19,
+                "num_allocs": self.calls, "note": "not a number"}
+
+
+@pytest.fixture(scope="module")
+def hbm_run(mesh8):
+    """A call with two rounds in flight (both ``t_ready_s`` sites) under a
+    stubbed allocator: the stub is the test's, the program has no option."""
+    mp = pytest.MonkeyPatch()
+    fake = FakeAllocator()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        plain = train_global(cfg(), mesh=mesh8, progress=False)
+        mp.setattr(spans, "device_stats", fake)
+        res = train_global(cfg(), mesh=mesh8, progress=False)
+    finally:
+        mp.undo()
+    return res, fake, plain
+
+
+def test_every_row_and_every_phase_carries_the_allocators_reading(hbm_run):
+    res, fake, _ = hbm_run
+    rows = res["round_timings"]
+    hbm = res["memory"]["hbm"]
+    assert [p["phase"] for p in hbm["phases"]] == PHASES
+    stamps = [hbm["on_entry"]] + hbm["phases"] + rows + [hbm["at_end"]]
+    for stamp in stamps:
+        assert all(isinstance(stamp[k], int) for k in HBM_KEYS)
+        assert stamp["hbm_peak_bytes"] > stamp["hbm_in_use_bytes"]
+    peaks = [s["hbm_peak_bytes"] for s in stamps]
+    assert peaks == sorted(peaks)               # the mark never falls
+    assert hbm["limit_bytes"] == 1 << 34
+    # the end's reading holds every number the allocator gave, no more
+    assert hbm["at_end"]["largest_alloc_size"] == 1 << 19
+    assert hbm["at_end"]["num_allocs"] > 0 and "note" not in hbm["at_end"]
+    # one reading a device at entry, a phase, a round and the end
+    assert fake.calls == 8 * len(stamps)
+    # the fullest of the mesh's eight devices is the one recorded
+    assert all((s["hbm_in_use_bytes"] - (1 << 20)) % 65536 == 7 * 1024
+               for s in stamps)
+
+
+def test_the_rest_of_the_run_is_the_unstubbed_runs(hbm_run):
+    res, _, plain = hbm_run
+    assert res["all_epochs_losses"] == plain["all_epochs_losses"]
+    for row, twin in zip(res["round_timings"], plain["round_timings"]):
+        assert set(row) == set(twin) | set(HBM_KEYS)
+    assert set(res["memory"]) == set(plain["memory"]) | {"hbm"}
+
+
+class TestHbm:
+    """``spans.hbm`` on stand-in devices."""
+
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    def test_the_fullest_device_is_the_one_recorded(self):
+        devs = [self.Dev({"bytes_in_use": 10, "peak_bytes_in_use": 90}),
+                self.Dev({"bytes_in_use": 30, "peak_bytes_in_use": 70}),
+                self.Dev(None)]
+        row = {"compute_ms": 1.0}
+        fullest = spans.hbm(row, devs)
+        assert row == {"compute_ms": 1.0, "hbm_in_use_bytes": 30,
+                       "hbm_peak_bytes": 90}
+        assert fullest is devs[0].stats
+
+    def test_no_statistics_no_key(self):
+        row = {"compute_ms": 1.0}
+        assert spans.hbm(row, [self.Dev(None), self.Dev({})]) is None
+        assert spans.hbm(row, []) is None
+        assert row == {"compute_ms": 1.0}
+
+    def test_memory_report_carries_it_only_where_there_is_one(self):
+        assert "hbm" not in probe.memory_report({})
+        assert "hbm" not in probe.memory_report({}, hbm=None)
+        hbm = {"phases": [], "at_end": {"hbm_peak_bytes": 3}}
+        assert probe.memory_report({}, hbm=hbm)["hbm"] is hbm
+
+
+def test_the_setup_line_names_the_last_rise_of_the_peak():
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.driver import _last_peak_rise
+    gib = 2**30
+    hbm = {"on_entry": {"hbm_peak_bytes": gib}, "phases": [
+        {"phase": "data", "hbm_peak_bytes": gib},
+        {"phase": "engine", "hbm_peak_bytes": 3 * gib},
+        {"phase": "probe", "hbm_peak_bytes": 3 * gib + 2**21}]}
+    rows = [{"hbm_peak_bytes": 3 * gib + 2**21}, {"compute_ms": 1.0}]
+    assert _last_peak_rise(hbm, rows) == (
+        "; HBM peak 3.002 GiB, last raised at 'probe' by 2.0 MiB")
+    rows[1]["hbm_peak_bytes"] = 4 * gib
+    assert _last_peak_rise(hbm, rows).endswith(
+        "last raised at 'round 1' by 1022.0 MiB")
+    assert _last_peak_rise(dict(hbm, phases=hbm["phases"][:1]), []) == (
+        "; HBM peak 1.000 GiB, as on entry")
+    assert _last_peak_rise({"on_entry": {}, "phases": []}, [{}]) == ""
+
+
 def test_sync_ms_is_timed_only_where_the_host_waits(run):
     """``sync_ms`` is the host's wait on the standalone sync.  To a round
     left in flight the host comes late and waits for nothing: its row has
@@ -218,6 +367,7 @@ def test_profile_holds_the_rounds_host_spans(mesh8, tmp_path):
     assert len(files) == 1
     counts: dict[str, int] = {}
     ids: dict[str, list] = {}
+    spans_at: dict[str, list] = {}
     for plane in ProfileData.from_file(files[0]).planes:
         if not plane.name.startswith("/host:"):
             continue
@@ -226,6 +376,8 @@ def test_profile_holds_the_rounds_host_spans(mesh8, tmp_path):
                 if e.name.startswith(("round.", "setup.")):
                     counts[e.name] = counts.get(e.name, 0) + 1
                     ids.setdefault(e.name, []).append(dict(e.stats))
+                    spans_at.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns))
     assert counts["round.dispatch"] == rounds
     assert counts["round.wait"] == rounds
     assert counts["round.prep"] == rounds - 1
@@ -233,6 +385,18 @@ def test_profile_holds_the_rounds_host_spans(mesh8, tmp_path):
     assert counts["round.build"] == 1 and counts["setup.first_prep"] == 1
     assert sorted(d["round"] for d in ids["round.dispatch"]) == [0, 1]
     assert ids["round.build"] == [{"program": "round"}]
+    # the build's three stages, one after the other inside round.build,
+    # which lies inside round 0's dispatch
+    build, dispatch0 = spans_at["round.build"][0], min(
+        spans_at["round.dispatch"])
+    stages = [spans_at[f"round.build.{part}"] for part in (
+        "trace", "lower", "compile")]
+    assert [len(s) for s in stages] == [1, 1, 1]
+    edges = [dispatch0[0], build[0]] + [t for s in stages for t in s[0]] + [
+        build[1], dispatch0[1]]
+    assert edges == sorted(edges)
+    for part in ("trace", "lower", "compile"):
+        assert ids[f"round.build.{part}"] == [{"program": "round"}]
 
 
 class TestPrimitive:
@@ -275,7 +439,37 @@ class TestPrimitive:
         tp(jnp.ones(3))
         tp(jnp.ones(3))
         assert [name for name, _ in built] == ["p"]
-        assert built[0][1] > 0.0
+        row = built[0][1]
+        assert set(row) == {"build_ms", *BUILD_PARTS, *BUILD_COUNTS}
+        assert row["build_ms"] > 0.0
+        assert 0.0 <= sum(row[k] for k in BUILD_PARTS) <= (
+            row["build_ms"] + 0.002)
+
+    def test_the_cache_counts_are_the_compile_stages_delta(self,
+                                                           monkeypatch):
+        """What the persistent cache said between the start and the end
+        of ``compile()``: a stubbed counter that another lookup has
+        already moved, and that serves this program."""
+        counts = iter([{"hits": 3, "misses": 5}, {"hits": 4, "misses": 5},
+                       {"hits": 4, "misses": 5}, {"hits": 4, "misses": 7}])
+        monkeypatch.setattr(probe, "compile_cache_counts",
+                            lambda: next(counts))
+        built = []
+        for name in ("loaded", "compiled"):
+            probe.TrackedProgram(name, jax.jit(lambda a: a + 1),
+                                 built=built)(jnp.ones(3))
+        assert [(r["build_cache_hits"], r["build_cache_misses"])
+                for _, r in built] == [(1, 0), (0, 2)]
+        folded = probe.fold_builds(built)
+        assert folded["programs_built"] == ["loaded", "compiled"]
+        assert (folded["build_cache_hits"],
+                folded["build_cache_misses"]) == (1, 2)
+        assert folded["build_ms"] == pytest.approx(
+            sum(r["build_ms"] for _, r in built), abs=1e-3)
+        assert probe.fold_builds([]) == {
+            "build_ms": 0.0, "build_trace_ms": 0.0, "build_lower_ms": 0.0,
+            "build_compile_ms": 0.0, "build_cache_hits": 0,
+            "build_cache_misses": 0, "programs_built": []}
 
 
 def test_flash_kernels_are_named():
