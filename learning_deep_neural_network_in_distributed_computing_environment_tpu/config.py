@@ -135,7 +135,8 @@ class Config:
     # (models.remat_name_vocab: attn_out / mlp_out / block_out /
     # moe_dispatch) — a typo'd name would otherwise silently degrade
     # the policy to save-nothing.  All policies are bitwise-identical
-    # in fp32 (remat moves residency, never math).
+    # in fp32 (remat moves residency, never math).  What every policy
+    # keeps besides: ``models.checkpoint_policy``'s comment.
     remat_policy: str = "none"       # none | dots_saveable | everything
     #                                  | save_names:<set>
     #                                  | offload_names:<set>
@@ -1344,7 +1345,13 @@ def build_argparser() -> argparse.ArgumentParser:
                         "offload_names:<a,b> (additionally offload the "
                         "set to pinned host memory; demoted to the "
                         "same-set save_names on backends without a "
-                        "host memory space)")
+                        "host memory space).  A policy chooses among "
+                        "XLA's activations; a kernel call whose outputs "
+                        "are O(L d) and whose cost is O(L^2 d) is never "
+                        "rematerialised: where an attention call "
+                        "reaches the flash kernel, every policy also "
+                        "keeps its output and log-sum-exp (2 B x heads "
+                        "x dv + 512 B a position and layer)")
     p.add_argument("--grad_accum", type=int, default=d.grad_accum,
                    help="microbatch gradient accumulation factor: scan K "
                         "microbatches per step with a donated fp32 grad "

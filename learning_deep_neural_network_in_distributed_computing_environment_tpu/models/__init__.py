@@ -141,8 +141,18 @@ def is_token_model(name: str) -> bool:
 #   a named set);
 # - ``moe_dispatch`` — the tokens as the experts get them: the Switch
 #   layer's expert-batched [E, C, H], the routed layer's rows in expert
-#   order [M, H] (emitted only when the model has experts).
-REMAT_NAMES = ("attn_out", "mlp_out", "block_out", "moe_dispatch")
+#   order [M, H] (emitted only when the model has experts);
+# - ``flash_out`` / ``flash_lse`` — what a flash kernel call produced
+#   (``ops/pallas_ops.py::_flash_fwd_rule``): the attention output
+#   [B, L, heads, dv] and the rows' log-sum-exp, float32 over a lane
+#   tile [B, heads, L, 128].  Emitted only where the attention call
+#   reaches the kernel.  In the tuple for graftlint R6; not in
+#   ``remat_name_vocab``, because EVERY policy keeps them
+#   (``KERNEL_RESIDUALS``) and a set that named them would change nothing.
+REMAT_NAMES = ("attn_out", "mlp_out", "block_out", "moe_dispatch",
+               "flash_out", "flash_lse")
+# what every policy below keeps, whatever else it chooses
+KERNEL_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def remat_name_vocab(name: str, num_experts: int = 0) -> tuple[str, ...]:
@@ -160,11 +170,22 @@ def remat_name_vocab(name: str, num_experts: int = 0) -> tuple[str, ...]:
 
 
 # Named rematerialization policies for the layer-scan engine (ISSUE 3).
-# "everything" REMATERIALIZES everything (saves nothing — jax's
-# ``nothing_saveable``, the historical ``remat=True`` behavior);
-# "dots_saveable" saves matmul/einsum outputs and recomputes only the
-# cheap elementwise chains between them — the pjit/TPUv4 scaling report's
-# default selective-remat recipe.
+# "everything" REMATERIALIZES every activation of XLA's (saves none of
+# them: the historical ``remat=True`` behavior); "dots_saveable" saves
+# matmul/einsum outputs and recomputes only the cheap elementwise chains
+# between them — the pjit/TPUv4 scaling report's default selective-remat
+# recipe.
+#
+# Every policy keeps ``KERNEL_RESIDUALS`` besides (ISSUE 35): a policy
+# chooses among XLA's activations; a kernel call whose outputs are
+# O(L d) and whose cost is O(L^2 d) is never rematerialised.  So
+# "everything" is ``save_only_these_names(flash_out, flash_lse)``: where
+# no attention call reaches the kernel (dense attention, a length the
+# kernel does not tile, the CPU mesh inside ``shard_map``) nothing carries
+# those names and it is ``nothing_saveable`` as before; where one does, a
+# layer holds 2 B x heads x dv + 512 B a position more than it did (192
+# MiB at 1 x 8192 x 32 heads of 128), and its backward pass runs the
+# kernel's forward once, not twice.
 #
 # ISSUE 15 adds the NAMED-ACTIVATION tier: ``save_names:<a,b>`` keeps
 # exactly the ``checkpoint_name``-annotated activations in the set on
@@ -223,7 +244,8 @@ def checkpoint_policy(name):
     callable.  ``name`` is one of ``REMAT_POLICIES`` minus "none" —
     callers gate the "none" (no remat at all) case themselves — or a
     named-activation spelling ``save_names:<a,b>`` /
-    ``offload_names:<a,b>`` (ISSUE 15).
+    ``offload_names:<a,b>`` (ISSUE 15).  Whatever the spelling, what a
+    flash kernel call produced (``KERNEL_RESIDUALS``) is saved besides.
 
     ``offload_names`` demotion: on a backend without a ``pinned_host``
     memory space (nowhere distinct to offload TO) the offload set
@@ -238,7 +260,7 @@ def checkpoint_policy(name):
         if kind == "offload_names":
             if host_offload_supported():
                 return policies.save_and_offload_only_these_names(
-                    names_which_can_be_saved=[],
+                    names_which_can_be_saved=list(KERNEL_RESIDUALS),
                     names_which_can_be_offloaded=list(names),
                     offload_src="device", offload_dst="pinned_host")
             if names not in _OFFLOAD_DEMOTIONS_LOGGED:
@@ -253,15 +275,17 @@ def checkpoint_policy(name):
                     "either way",
                     ",".join(names), ",".join(names),
                     jax.default_backend())
-        return policies.save_only_these_names(*names)
+        return policies.save_only_these_names(*names, *KERNEL_RESIDUALS)
     if name not in REMAT_POLICIES or name == "none":
         raise ValueError(
             f"remat policy must be one of {REMAT_POLICIES[1:]} or a "
             f"named-activation spelling ('save_names:<a,b>' / "
             f"'offload_names:<a,b>'), got {name!r}")
+    kernel_residuals = policies.save_only_these_names(*KERNEL_RESIDUALS)
     if name == "dots_saveable":
-        return policies.dots_saveable
-    return policies.nothing_saveable
+        return policies.save_from_both_policies(policies.dots_saveable,
+                                                kernel_residuals)
+    return kernel_residuals
 
 
 MODEL_INPUT_SPECS = {

@@ -50,6 +50,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -688,6 +689,14 @@ def _flash(q, k, v, causal=False, window=None):
 
 def _flash_fwd_rule(q, k, v, causal, window):
     o, lse = _flash_forward(q, k, v, causal, with_lse=True, window=window)
+    # what the kernel produced carries a name that every remat policy
+    # keeps (``models.checkpoint_policy``): a backward pass under
+    # ``jax.checkpoint`` recomputes q, k and v from the projections and
+    # does not call the kernel a second time for two O(L) tensors.  The
+    # primal output and the residual are the one named value; outside
+    # ``jax.checkpoint`` a name lowers to nothing.
+    o = checkpoint_name(o, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, o, lse)
 
 

@@ -44,16 +44,17 @@ from learning_deep_neural_network_in_distributed_computing_environment_tpu.model
 )
 
 VOCAB, B, L_SEQ = 97, 4, 16
+L_FLASH = 128   # the shortest sequence the flash kernel tiles
 
 ALL_POLICIES = ("none", "dots_saveable", "save_names:attn_out",
                 "save_names:attn_out,block_out", "offload_names:attn_out",
                 "everything")
 
 
-def _token_fixture(seed=0):
+def _token_fixture(seed=0, seq=L_SEQ):
     rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.integers(0, VOCAB, (B, L_SEQ)), jnp.int32)
-    y = jnp.asarray(rng.integers(0, VOCAB, (B, L_SEQ)), jnp.int32)
+    x = jnp.asarray(rng.integers(0, VOCAB, (B, seq)), jnp.int32)
+    y = jnp.asarray(rng.integers(0, VOCAB, (B, seq)), jnp.int32)
     return x, y
 
 
@@ -101,12 +102,38 @@ class TestNamedActivations:
         # and nothing outside the closed vocabulary (the R6 contract)
         assert emitted <= set(REMAT_NAMES), emitted - set(REMAT_NAMES)
 
+    @pytest.mark.parametrize("name", ["gpt_tiny", "llama_tiny",
+                                      "mellum2_tiny", "kanana2_tiny"])
+    def test_flash_emits_the_kernel_residuals(self, name):
+        """ISSUE 35: where the attention call reaches the flash kernel its
+        output and log-sum-exp carry names (interpret mode here), and with
+        dense attention no such name exists."""
+        kw = dict(num_classes=models_lib.MODEL_INPUT_SPECS[name][1],
+                  scan_layers=True)
+        if name.startswith("gpt"):
+            kw["max_len"] = L_FLASH
+        x = jnp.zeros((2, L_FLASH), jnp.int32)
+        emitted = {impl: set(re.findall(r"name\[name=(\w+)\]", _grad_jaxpr(
+            get_model(name, attention_impl=impl, **kw), x)))
+            for impl in ("flash", "dense")}
+        assert set(remat_name_vocab(name)) <= emitted["flash"]
+        assert emitted["flash"] <= set(REMAT_NAMES)
+        assert emitted["flash"] - emitted["dense"] == {"flash_out",
+                                                       "flash_lse"}
+        assert emitted["dense"] == set(remat_name_vocab(name))
+
     def test_vocab_registry(self):
+        assert REMAT_NAMES == ("attn_out", "mlp_out", "block_out",
+                               "moe_dispatch", "flash_out", "flash_lse")
+        assert models_lib.KERNEL_RESIDUALS == REMAT_NAMES[-2:]
         assert remat_name_vocab("gpt_tiny") == (
             "attn_out", "mlp_out", "block_out")
         assert remat_name_vocab("llama_tiny", 4)[-1] == "moe_dispatch"
         assert remat_name_vocab("mlp") == ()
         assert remat_name_vocab("enhanced_cnn", 2) == ()
+        # every policy keeps the kernel's residuals: nothing to select
+        assert not set(models_lib.KERNEL_RESIDUALS) & set(
+            remat_name_vocab("llama_tiny", 4))
 
 
 class TestPolicyResolution:
@@ -137,6 +164,14 @@ class TestPolicyResolution:
         # non-attention family has no scanned block path at all
         with pytest.raises(ValueError, match="no scanned block"):
             Config(model="mlp", remat_policy="save_names:attn_out")
+
+    @pytest.mark.parametrize("impl", ["flash", "dense"])
+    def test_config_offers_no_kernel_residual_to_select(self, impl):
+        """Every policy keeps them (ISSUE 35), so a set that named one
+        would change nothing: the message lists what can be chosen."""
+        with pytest.raises(ValueError, match=r"flash_out.*attn_out"):
+            Config(model="gpt_tiny", attention_impl=impl,
+                   remat_policy="save_names:flash_out")
 
     def test_named_policy_without_layer_scan_keeps_rejection(self):
         cfg = Config(model="gpt_tiny", dataset="synthetic_lm",
@@ -170,12 +205,40 @@ class TestPolicyResolution:
         with pytest.raises(ValueError):
             models_lib.checkpoint_policy("none")
 
+    @pytest.mark.parametrize("policy", [
+        "everything", "dots_saveable", "save_names:attn_out",
+        "save_names:attn_out,block_out", "offload_names:attn_out"])
+    def test_every_policy_keeps_what_a_kernel_produced(self, policy):
+        """One rule over every spelling (ISSUE 35): the recomputation of a
+        rematerialised flash block holds no ``flash_fwd`` call, because
+        the call's two named outputs are saved."""
+        from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops
+        q = jnp.zeros((1, L_FLASH, 2, 64), jnp.float32)
 
-def _make_step(policy, depth=2):
+        def block(w):
+            o = pallas_ops._flash(q * w, q * w, q * w, True)
+            return jnp.sum(jnp.tanh(o) * w)
+
+        def kernels(policy):
+            f = block if policy is None else jax.checkpoint(
+                block, policy=policy)
+            return re.findall(r"name=(flash_fwd|flash_bwd)", str(
+                jax.make_jaxpr(jax.grad(f))(jnp.float32(1.0))))
+
+        assert kernels(None) == ["flash_fwd", "flash_bwd"]
+        assert kernels(models_lib.checkpoint_policy(policy)) == [
+            "flash_fwd", "flash_bwd"]
+        # the control: jax's save-nothing policy runs the forward twice
+        assert kernels(jax.checkpoint_policies.nothing_saveable) == [
+            "flash_fwd", "flash_fwd", "flash_bwd"]
+
+
+def _make_step(policy, depth=2, attention_impl="dense", seq=L_SEQ):
     model = get_model("gpt_tiny", num_classes=VOCAB, num_layers=depth,
-                      max_len=L_SEQ, scan_layers=True,
+                      max_len=seq, scan_layers=True,
+                      attention_impl=attention_impl,
                       remat_policy=None if policy == "none" else policy)
-    x, y = _token_fixture()
+    x, y = _token_fixture(seq=seq)
     tx = optax.adam(1e-3)
 
     def loss_fn(p):
@@ -196,6 +259,14 @@ def _make_step(policy, depth=2):
         return (params, jax.jit(tx.init)(params))
 
     return step, init
+
+
+@pytest.fixture(scope="module")
+def flash_baseline():
+    step, init = _make_step("none", attention_impl="flash", seq=L_FLASH)
+    state, loss = step(init())
+    return (jax.tree_util.tree_leaves(jax.device_get(state[0])),
+            np.asarray(loss).copy())
 
 
 class TestBitwiseAcrossPolicies:
@@ -220,6 +291,23 @@ class TestBitwiseAcrossPolicies:
                        for a, b in zip(base_leaves, leaves)), policy
             assert all(np.array_equal(a, b)
                        for a, b in zip(base_losses, losses)), policy
+
+
+    @pytest.mark.parametrize("policy", [p for p in ALL_POLICIES
+                                        if p != "none"] + [
+        "save_names:block_out", "offload_names:mlp_out,block_out"])
+    def test_flash_block_bitwise_the_no_policy_step(self, policy,
+                                                    flash_baseline):
+        """ISSUE 35: with the flash kernel in the block (interpret mode)
+        the saved output and log-sum-exp are the values a second run of
+        the kernel would have produced: one Adam step's loss and
+        parameters are the no-policy step's bit for bit."""
+        step, init = _make_step(policy, attention_impl="flash", seq=L_FLASH)
+        state, loss = step(init())
+        base_leaves, base_loss = flash_baseline
+        assert np.array_equal(np.asarray(loss), base_loss)
+        assert all(np.array_equal(a, b) for a, b in zip(
+            base_leaves, jax.tree_util.tree_leaves(jax.device_get(state[0]))))
 
 
 # sanitized driver-level matrix: new e2e driver cases ride the slow tier
@@ -265,6 +353,21 @@ class TestMemoryAnalysisOrdering:
         assert temps["none"] >= temps["dots_saveable"] \
             >= temps["save_names:attn_out"] >= temps["everything"]
         assert temps["none"] > temps["everything"]
+
+    def test_dense_attention_everything_is_still_save_nothing(self,
+                                                              monkeypatch):
+        """No attention call reaches the kernel here, so nothing carries
+        a kernel residual's name and ``everything`` compiles to the bytes
+        of jax's own ``nothing_saveable`` (ISSUE 35)."""
+        def temp(policy):
+            step, init = _make_step(policy, depth=4)
+            return int(step.lower(init()).compile()
+                       .memory_analysis().temp_size_in_bytes)
+        ours = temp("everything")
+        monkeypatch.setattr(
+            models_lib, "checkpoint_policy",
+            lambda name: jax.checkpoint_policies.nothing_saveable)
+        assert temp("everything") == ours
 
     def test_offload_arm_matches_save_arm_bytes(self):
         # demoted offload is the SAME executable residency-wise

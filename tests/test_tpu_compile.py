@@ -21,6 +21,13 @@ from benchmarks.lib.trace import kernel_of, load_kernels  # noqa: E402
 from learning_deep_neural_network_in_distributed_computing_environment_tpu.ops import pallas_ops  # noqa: E402
 
 
+def _mosaic_calls(text: str) -> list:
+    """The Mosaic calls of a compiled program's text, each under the name
+    the benchmark's picker files give it, sorted."""
+    return sorted(kernel_of(line.strip(), load_kernels())
+                  for line in text.splitlines() if "tpu_custom_call" in line)
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     from jax.experimental import topologies
@@ -87,10 +94,7 @@ def test_mellum_attention_lowers_for_v5e(one_chip, monkeypatch, window,
         sds(1, 8192, 4, 128)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
-    calls = [line.strip() for line in text.splitlines()
-             if "tpu_custom_call" in line]
-    assert sorted(kernel_of(line, load_kernels()) for line in calls) == [
-        "flash_dkv", "flash_fwd"]
+    assert _mosaic_calls(text) == ["flash_dkv", "flash_fwd"]
     assert pallas_ops.TILE_COUNTS == {(8192, 8192, True, window): counts}
     assert pallas_ops.GRID_COUNTS == {(8192, 8192, True, window): grid}
 
@@ -111,10 +115,7 @@ def test_latent_attention_lowers_for_v5e(one_chip, monkeypatch):
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
         sds(1, 8192, 32, 192), sds(1, 8192, 32, 192),
         sds(1, 8192, 32, 128)).compile()
-    calls = [line.strip() for line in compiled.as_text().splitlines()
-             if "tpu_custom_call" in line]
-    assert sorted(kernel_of(line, load_kernels()) for line in calls) == [
-        "flash_dkv", "flash_fwd"]
+    assert _mosaic_calls(compiled.as_text()) == ["flash_dkv", "flash_fwd"]
     assert pallas_ops.TILE_COUNTS == {(8192, 8192, True, None):
                                       (528, 1024, 32)}
     assert pallas_ops.GRID_COUNTS == {(8192, 8192, True, None):
@@ -179,3 +180,73 @@ def test_routed_layer_on_its_row_buffer_lowers_for_v5e(one_chip, monkeypatch):
     # itself); the backward's 6 of chunk 0, and 3 + 6 in its loop
     assert len(calls) == 18
     assert {kernel_of(line, load_kernels()) for line in calls} == {"moe_gmm"}
+
+
+def _scanned_attention_grad(one_chip, heads, kv, d, dv, window, policy):
+    """The compiled gradient of two scanned layers of projections, one
+    ``_flash`` call and the output projection at L = 8192 and a model width
+    of 2048, each layer rematerialised under ``policy`` as the decoder's
+    blocks are (``nn.remat`` inside ``nn.scan``, ``prevent_cse=False``): the
+    scan keeps XLA from merging a recomputed call with the forward's."""
+    hidden, layers = 2048, 2
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+
+    def layer(x, w):
+        wq, wk, wv, wo = w
+        heads_of = lambda y, n, m: y.reshape(*y.shape[:2], n, m)
+        o = pallas_ops._flash(heads_of(x @ wq, heads, d),
+                              heads_of(x @ wk, kv, d),
+                              heads_of(x @ wv, kv, dv), True, window)
+        return x + o.reshape(*x.shape[:2], heads * dv) @ wo
+
+    if policy is not None:
+        layer = jax.checkpoint(layer, policy=policy, prevent_cse=False)
+
+    def loss(x, ws):
+        y, _ = jax.lax.scan(lambda c, w: (layer(c, w), None), x, ws)
+        return y.astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss, (0, 1))).lower(
+        sds(1, 8192, hidden),
+        (sds(layers, hidden, heads * d), sds(layers, hidden, kv * d),
+         sds(layers, hidden, kv * dv), sds(layers, heads * dv, hidden))
+    ).compile()
+
+
+# the three sparse cells' attention calls: grouped queries 32 / 4 of width
+# 128 under mellum's window, trinity's and none, and kanana's latent call
+SPARSE_CALLS = {"window1024": (32, 4, 128, 128, 1024),
+                "window2048": (32, 4, 128, 128, 2048),
+                "full": (32, 4, 128, 128, None),
+                "latent": (32, 32, 192, 128, None)}
+
+
+@pytest.mark.parametrize("call", SPARSE_CALLS)
+@pytest.mark.parametrize("policy", ["none", "everything", "dots_saveable",
+                                    "save_names:attn_out"])
+def test_a_remat_policy_keeps_the_flash_residuals(one_chip, monkeypatch, call,
+                                                policy):
+    """ISSUE 35: what a flash call produced is a named residual that every
+    policy of ``models.checkpoint_policy`` keeps, so a rematerialised
+    layer's backward pass holds the one backward kernel and no second
+    forward: two Mosaic calls in the compiled gradient, as with no
+    ``jax.checkpoint`` at all."""
+    from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import checkpoint_policy
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    compiled = _scanned_attention_grad(
+        one_chip, *SPARSE_CALLS[call],
+        None if policy == "none" else checkpoint_policy(policy))
+    assert _mosaic_calls(compiled.as_text()) == ["flash_dkv", "flash_fwd"]
+
+
+def test_a_policy_that_saves_nothing_would_run_it_twice(one_chip,
+                                                        monkeypatch):
+    """The control of the test above: under jax's own ``nothing_saveable``
+    (what ``everything`` resolved to before ISSUE 35) the same program
+    holds the forward kernel twice."""
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    compiled = _scanned_attention_grad(
+        one_chip, *SPARSE_CALLS["window1024"],
+        jax.checkpoint_policies.nothing_saveable)
+    assert _mosaic_calls(compiled.as_text()) == [
+        "flash_dkv", "flash_fwd", "flash_fwd"]

@@ -55,7 +55,8 @@ DEFAULT_AXIS_VOCAB = frozenset(
 # matches a --remat_policy save_names:/offload_names: set, silently
 # degrading the policy to save-NOTHING for that activation.
 DEFAULT_REMAT_NAME_VOCAB = frozenset(
-    {"attn_out", "mlp_out", "block_out", "moe_dispatch"})
+    {"attn_out", "mlp_out", "block_out", "moe_dispatch", "flash_out",
+     "flash_lse"})
 
 # Call spellings whose string label R6 validates (the repo imports the
 # jax primitive under its own name; dotted jax spellings included so
