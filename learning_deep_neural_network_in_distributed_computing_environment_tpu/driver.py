@@ -66,6 +66,16 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult if x else mult
 
 
+def _pack_steps(need: int) -> int:
+    """The bucket of a pack whose rows need ``need`` steps: 1 or 2
+    exactly, 3 or more rounded up to a multiple of 4 (1, 2, 4, 8, 12,
+    ...).  Every packed step is computed, masked or not, so a pack is as
+    long as its rows need; the bucket above 2 bounds the round-program
+    shapes (one compiled program each) that repartition can move a run
+    through."""
+    return need if 0 < need < 3 else _round_up(need, 4)
+
+
 def _assemble_round_metrics(results: dict, mx: dict, worker_ids) -> None:
     """One round's mx arrays -> the reference metric lists.
 
@@ -1020,9 +1030,7 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
     # returns at round r+2, after round r's host->device transfer is done.
     pack_pool = PackBufferPool()
 
-    def pack_all(ds, parts, kind: str, caps=None):
-        idxs, sizes = _capped(parts, caps)
-        steps = _round_up(step_budget(sizes, batch), 4)
+    def pack_all(ds, idxs, kind: str, steps: int):
         xs = pack_pool.take((kind, "x"),
                             (n, steps, batch, *ds.images.shape[1:]),
                             ds.images.dtype)
@@ -1035,14 +1043,40 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                         out=(xs[i], ys[i], ms[i]))
         return xs, ys, ms
 
-    def chunk_feed(ds, parts, caps=None):
+    def chunk_feed(ds, idxs, steps: int):
         """Streamed alternative to pack_all: a per-epoch iterator of
         fixed-shape [N, chunk, B, ...] windows (only one window is ever
         materialized on the host; VERDICT r1 'Next' #7)."""
-        chunk = cfg.stream_chunk_steps
-        idxs, sizes = _capped(parts, caps)
-        steps = _round_up(step_budget(sizes, batch), chunk)
-        return window_feed(ds.images, ds.labels, idxs, batch, chunk, steps)
+        return window_feed(ds.images, ds.labels, idxs, batch,
+                           cfg.stream_chunk_steps, steps)
+
+    def pack_steps(tsizes, vsizes) -> tuple[int, int]:
+        """Steps of a round's train and validation packs.  Streamed: each
+        need rounded up to the chunk.  Packed: each need's bucket, unless
+        that pair has no round program yet and a built one holds the rows
+        in packs no longer than a granule of four steps gives (the one
+        rule before the buckets): a new shape is a trace and a compile
+        (none is allowed after a sanitized run's warm-up), so shards that
+        repartition moves below a built program's packs run in it, and
+        never in more masked steps than the granule left.  Of several,
+        the fewest train steps: a train step costs more than a
+        validation step."""
+        need = (step_budget(tsizes, batch), step_budget(vsizes, batch))
+        if streaming:
+            return tuple(_round_up(k, cfg.stream_chunk_steps) for k in need)
+        bucket = tuple(_pack_steps(k) for k in need)
+        if bucket in engine.built_packs:
+            return bucket
+        return min((s for s in engine.built_packs
+                    if all(k <= p <= _round_up(k, 4)
+                           for k, p in zip(need, s))),
+                   default=bucket)
+
+    def empty_steps(sizes, steps: int) -> int:
+        """Steps of a pack that carry no row, summed over the workers:
+        forward passes (and, in a train pack, steps) the round program
+        computes on masked rows alone."""
+        return sum(steps - int(np.ceil(s / batch)) for s in sizes)
 
     # --- optional profiler trace (beyond-reference, SURVEY.md section 5) --
     profiling = False
@@ -1129,17 +1163,17 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                      and not sanitize and schedule is None
                      and cfg.sync_staleness == 0)
 
-    def build_inputs(tparts, vparts, caps, row, ids):
+    def build_inputs(tidxs, vidxs, steps, row, ids):
         if streaming:
-            return (chunk_feed(trainset, tparts, caps),
-                    chunk_feed(valset, vparts))
+            return (chunk_feed(trainset, tidxs, steps[0]),
+                    chunk_feed(valset, vidxs, steps[1]))
         # pack AND stage onto device at prep time: in the overlapped
         # pipeline this runs while the previous round computes, so the
         # host->device transfer rides under device time too (h2d_ms is
         # the host's time in device_put, not the copy's)
         with span("round.prep.pack", row, "pack_ms", **ids):
-            packs = (pack_all(trainset, tparts, "train", caps),
-                     pack_all(valset, vparts, "val"))
+            packs = (pack_all(trainset, tidxs, "train", steps[0]),
+                     pack_all(valset, vidxs, "val", steps[1]))
         with span("round.prep.h2d", row, "h2d_ms", **ids):
             return engine.stage_pack(*packs)
 
@@ -1155,9 +1189,14 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
         steps_run = np.array([
             min(int(np.ceil(len(p) / batch)), caps[i])
             for i, p in enumerate(tparts)], np.float64)
+        (tidxs, tsizes), (vidxs, vsizes) = (_capped(tparts, caps),
+                                            _capped(vparts, None))
+        steps = pack_steps(tsizes, vsizes)
         return dict(caps=caps, steps_run=steps_run,
                     sizes=[len(p) for p in tparts],
-                    inputs=build_inputs(tparts, vparts, caps, row, ids))
+                    pad_steps=(empty_steps(tsizes, steps[0])
+                               + empty_steps(vsizes, steps[1])),
+                    inputs=build_inputs(tidxs, vidxs, steps, row, ids))
 
     walls_by_round: dict[int, tuple[np.ndarray, np.ndarray]] = {}
     next_wall_box = [start_epoch]  # next round whose wall the EMA consumes
@@ -1699,9 +1738,11 @@ def train_global(cfg: Config, *, mesh=None, simulated_durations=None,
                 # schema is identical every round; save rounds
                 # overwrite).  The background writer fills ckpt_write_ms
                 # when its write lands — always before results return
-                # (ckpt_engine.wait in finally).
+                # (ckpt_engine.wait in finally).  pad_steps: the packed
+                # steps of this round's packs that carry no row.
                 timing: dict[str, Any] = {"ckpt_snapshot_ms": 0.0,
-                                          "ckpt_write_ms": 0.0}
+                                          "ckpt_write_ms": 0.0,
+                                          "pad_steps": prep["pad_steps"]}
                 results["round_timings"].append(timing)
                 t_disp = timing["t_dispatch_s"] = time.perf_counter()
                 if t_ready is not None:

@@ -412,6 +412,7 @@ class SimEngine(LocalSGDEngine):
             # vmap'd round executable's memory_analysis is what the
             # sim-lab N-ceiling measurement reads on a real chip
             self._track(key, self._build_round(key), "sim_round")
+            self.built_packs.add((x.shape[1], xv.shape[1]))
             if self.sim_staleness > 0 and \
                     "sim_deliver" not in self._round_cache:
                 # the drain's delivery fold, AOT-compiled NOW (round 0 =

@@ -553,6 +553,9 @@ class LocalSGDEngine:
         # outside so StepLR can drive it per local epoch.
         self.tx = optax.scale_by_adam(b1=0.9, b2=0.999, eps=1e-8)
         self._round_cache: dict[tuple, Callable] = {}
+        # (train steps, validation steps) of every packed-round program
+        # built: the pack lengths a round can take without a compile
+        self.built_packs: set[tuple[int, int]] = set()
         # compiled-memory observability (ISSUE 15): every cached engine
         # program is wrapped in a probe.TrackedProgram (AOT lower +
         # compile on first call, executable handle retained), keyed by a
@@ -2144,6 +2147,7 @@ class LocalSGDEngine:
         if key not in self._round_cache:
             log.info("compiling round program for shapes %s", key)
             self._track(key, self._build_round(key), "round")
+            self.built_packs.add((x.shape[1], xv.shape[1]))
         if self.nan_screen and poison is None:
             poison = self.stage_poison(np.zeros(self.n_workers, np.bool_))
         extra = ((poison,) if self.nan_screen and not self.split_sync
