@@ -4,8 +4,9 @@ mesh axis.
 Beyond-reference capability (the reference is data-parallel only,
 SURVEY.md 2.3).  Two routed layers over ONE dispatch (``routed_apply``:
 the (token, expert) pairs sorted by expert, grouped products over a row
-buffer sized by the share of the experts held here, a scatter-add back;
-no ``[tokens, E, C]`` tensor):
+buffer sized by the share of the experts held here, the buffer's rows
+summed back into their tokens by one kernel, ``ops/moe_combine.py``; no
+``[tokens, E, C]`` tensor):
 
 - ``RoutedExperts``: sparse SwiGLU experts as deployed, top-k of E with
   renormalised weights, no capacity and no dropped token, told which
@@ -132,7 +133,7 @@ class MoEFFN(nn.Module):
                          approximate=False)
             return grouped_matmul(h1, w2, sizes) + b2_scale * b2[e_row]
 
-        out, _ = routed_apply(
+        out, *_ = routed_apply(
             toks.astype(self.dtype), expert_idx[:, None],
             (gate * keep)[:, None], first, e_local,
             Experts(experts, tuple(p.astype(self.dtype)
@@ -146,7 +147,7 @@ class MoEFFN(nn.Module):
 
 # ----------------------------------------------------------------------
 # Routed experts without a capacity: sort, grouped products over a row
-# buffer sized by the held share, a scatter-add back
+# buffer sized by the held share, the rows summed back into the tokens
 # ----------------------------------------------------------------------
 
 # rows of buffer for each row the held share of the router expects: the
@@ -208,13 +209,15 @@ class _Chunk(NamedTuple):
     sizes: Any     # [held] each group's rows inside this window
     expert: Any    # [c] each row's expert (``held`` past the last group)
     weight: Any    # [c] float32, the pair's weight
+    edges: Any     # the (expert, token tile) runs' bounds inside the window
 
 
-def _chunk(i, weights, order, sizes, expert_of_row) -> _Chunk:
+def _chunk(i, weights, order, sizes, expert_of_row, edges) -> _Chunk:
     """Chunk ``i`` of the sorted pairs: rows ``[i * c, (i + 1) * c)``, row
     ``i`` of ``order`` and ``expert_of_row`` [chunks, c].  The groups'
     overlaps with the window are its group sizes, so within it the rows
-    past ``sum(sizes)`` again belong to none."""
+    past ``sum(sizes)`` again belong to none; so are the runs' (``edges``,
+    ``ops.moe_combine.run_edges`` over the whole order)."""
     n, k = weights.shape
     c = order.shape[1]
     start = i * c
@@ -225,14 +228,17 @@ def _chunk(i, weights, order, sizes, expert_of_row) -> _Chunk:
         pair, jnp.minimum(pair // k, n - 1),
         start + jnp.arange(c, dtype=jnp.int32) < ends[-1],
         inside(ends) - inside(ends - sizes), expert_of_row[i],
-        _take(weights.reshape(-1), jnp.minimum(pair, n * k - 1)))
+        _take(weights.reshape(-1), jnp.minimum(pair, n * k - 1)),
+        inside(edges))
 
 
-def _combine(acc, ch: _Chunk, y):
-    """Add the chunk's weighted rows into their tokens, in float32.  The
-    kernels never wrote the rows of no group: selected, not multiplied."""
-    y = y.astype(jnp.float32) * ch.weight[:, None]
-    return acc.at[ch.tok].add(jnp.where(ch.valid[:, None], y, 0))
+def _combine(ch: _Chunk, rows, n: int, weighted: bool = True):
+    """The chunk's rows, each times its pair's weight (``weighted``),
+    summed into their ``n`` tokens in float32 (``ops.moe_combine``).  The
+    kernels never wrote the rows of no group: the sum selects them away."""
+    from ..ops.moe_combine import moe_combine
+    return moe_combine(rows, ch.tok, ch.edges,
+                       ch.weight if weighted else None, n=n)
 
 
 def _overflow_loop(order, sizes, body, carry):
@@ -246,7 +252,8 @@ def _overflow_loop(order, sizes, body, carry):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _dispatch(apply, toks, weights, params, order, sizes, expert_of_row):
+def _dispatch(apply, toks, weights, params, order, sizes, expert_of_row,
+              edges):
     """The routed layer on a buffer of ``c`` rows, ``order`` and
     ``expert_of_row`` being [chunks, c]: chunk 0 of the sorted pairs
     straight-line, the chunks after it in a loop that runs as often as
@@ -255,23 +262,25 @@ def _dispatch(apply, toks, weights, params, order, sizes, expert_of_row):
     its own: the backward walks the same chunks, chunk 0 from the forward's
     residuals and the others recomputed."""
     return _dispatch_fwd(apply, toks, weights, params, order, sizes,
-                         expert_of_row)[0]
+                         expert_of_row, edges)[0]
 
 
-def _dispatch_fwd(apply, toks, weights, params, order, sizes, expert_of_row):
-    ints = (order, sizes, expert_of_row)
+def _dispatch_fwd(apply, toks, weights, params, order, sizes, expert_of_row,
+                  edges):
+    ints = (order, sizes, expert_of_row, edges)
     ch = _chunk(0, weights, *ints)
     # named activation "moe_dispatch" (ISSUE 15): the tokens in expert
     # order, the residual a save_names: / offload_names: policy may pin
     rows = checkpoint_name(_take(toks, ch.tok), "moe_dispatch")
     y, pull = jax.vjp(lambda p, r: apply(p, r, ch.sizes, ch.expert),
                       params, rows)
-    acc = _combine(_zeros(toks.shape, jnp.float32, toks), ch, y)
+    n = toks.shape[0]
+    acc = _combine(ch, y, n)
 
     def more(i, acc):
         ch = _chunk(i, weights, *ints)
-        return _combine(acc, ch, apply(params, _take(toks, ch.tok),
-                                       ch.sizes, ch.expert))
+        return acc + _combine(ch, apply(params, _take(toks, ch.tok),
+                                        ch.sizes, ch.expert), n)
 
     acc = _overflow_loop(order, sizes, more, acc)
     return acc.astype(toks.dtype), (pull, y, toks, weights, params, ints)
@@ -289,9 +298,7 @@ def _dispatch_bwd(apply, res, g):
         d_y = jnp.where(ch.valid[:, None], g_rows * ch.weight[:, None], 0)
         d_params, d_rows = pull(d_y.astype(y.dtype))
         d_w = (g_rows * y.astype(jnp.float32)).sum(-1)
-        return (d_params,
-                d_toks.at[ch.tok].add(jnp.where(
-                    ch.valid[:, None], d_rows.astype(jnp.float32), 0)),
+        return (d_params, d_toks + _combine(ch, d_rows, n, weighted=False),
                 d_pairs.at[ch.pair].add(jnp.where(ch.valid, d_w, 0),
                                         mode="drop"))
 
@@ -308,7 +315,7 @@ def _dispatch_bwd(apply, res, g):
         _zeros((n * k,), jnp.float32, toks)))
     return (d_toks.astype(toks.dtype),
             d_pairs.reshape(n, k).astype(weights.dtype), d_params,
-            None, None, None)
+            None, None, None, None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -321,7 +328,10 @@ def routed_apply(toks, expert_idx, weights, first, held: int,
     results back into the tokens, weighted.  No capacity and no dropped
     pair, for any routing: the pairs are sorted by expert and the held
     ones, which sort first, go through ``experts.apply`` a buffer of ``C``
-    rows at a time and come back by a scatter-add in float32.  ``C``
+    rows at a time and come back into their tokens in float32 by one kernel
+    (``ops/moe_combine.py``), which reads each held expert's run of rows
+    for a tile of tokens: the runs' bounds are counted once, beside the
+    sort, and shared by the forward and the backward pass.  ``C``
     (``row_buffer``) follows the share of the router's experts held here,
     with ``HEADROOM``: one pass where the router is near balance, and as
     many more as the counted rows need where it is not (``_dispatch``).
@@ -331,31 +341,40 @@ def routed_apply(toks, expert_idx, weights, first, held: int,
     never written by the kernels and are selected away before use.
 
     ``toks`` [N, H], ``expert_idx`` / ``weights`` [N, k].  Returns ``(out
-    [N, H], sizes [held])``: the held experts' part of the layer's sum and
-    the rows each of them got."""
+    [N, H], sizes [held], walked)``: the held experts' part of the layer's
+    sum, the rows each of them got, and the row slots the way back's
+    products walk in one forward pass (``ops.moe_combine.row_slots`` over
+    the buffers that run)."""
     from ..ops.grouped_matmul import vary_alike
+    from ..ops.moe_combine import row_slots, run_edges
     n, k = expert_idx.shape
     m = n * k
     m_pad, c = row_buffer(m, held, experts.router_width)
     local = expert_idx.reshape(-1).astype(jnp.int32) - first
-    key = jnp.pad(jnp.where((local >= 0) & (local < held), local, held),
-                  (0, m_pad - m), constant_values=held)
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    key = jnp.pad(local, (0, m_pad - m), constant_values=held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = (jnp.arange(held)[:, None] == key[None]).sum(1, dtype=jnp.int32)
+    edges = run_edges(local.reshape(n, k), n, held)
+    sizes = jnp.diff(edges).reshape(held, -1).sum(1)
     # [chunks, c] windows of the sorted order; the last may reach past
     # m_pad, where no group has rows
     past = -m_pad % c
     expert_of_row = jnp.pad(key[order], (0, past),
                             constant_values=held).reshape(-1, c)
     order = jnp.pad(order, (0, past), constant_values=m_pad).reshape(-1, c)
+    chunks = jnp.arange(order.shape[0])
+    walked = jax.vmap(lambda e: row_slots(
+        e, n, c, held, toks.dtype.itemsize))(
+            jnp.clip(edges - c * chunks[:, None], 0, c))
+    walked = jnp.where(chunks <= overflow_chunks(sizes, c), walked, 0).sum()
     leaves, tree = jax.tree_util.tree_flatten(experts.params)
-    toks, weights, order, sizes, expert_of_row, *leaves = vary_alike(
-        toks, weights.astype(jnp.float32), order, sizes, expert_of_row,
+    toks, weights, order, sizes, expert_of_row, edges, *leaves = vary_alike(
+        toks, weights.astype(jnp.float32), order, sizes, expert_of_row, edges,
         *leaves)
     out = _dispatch(experts.apply, toks, weights,
                     jax.tree_util.tree_unflatten(tree, leaves), order, sizes,
-                    expert_of_row)
-    return out, sizes
+                    expert_of_row, edges)
+    return out, sizes, walked
 
 
 # jitted so that every call site (chunk 0, the overflow loops, their
@@ -388,7 +407,10 @@ class RoutedExperts(nn.Module):
     returns the part of ``count`` experts from ``first`` on.  The ranks'
     outputs sum to the whole layer's; nothing stands in for the experts
     held elsewhere.  Sown into ``counters``: the rows that landed on held
-    experts and the fullest held expert's rows over the mean."""
+    experts, the fullest held expert's rows over the mean, the passes the
+    rows needed over the buffer after the first, and the share of the row
+    slots that the way back into the tokens walks which carry a row
+    (``combine_row_fill``, %)."""
 
     num_experts: int               # the router's width
     ffn_dim: int                   # per-expert SwiGLU width
@@ -431,11 +453,13 @@ class RoutedExperts(nn.Module):
         w2 = self.param("w2", _init, (held, self.ffn_dim, h)).astype(
             self.dtype)
 
-        out, sizes = routed_apply(
+        out, sizes, walked = routed_apply(
             toks.astype(self.dtype), idx, weights, first, held,
             Experts(_swiglu_experts, (w1, w3, w2), self.num_experts))
         rows = sizes.sum().astype(jnp.float32)
         self.sow("counters", "expert_rows", rows)
+        self.sow("counters", "combine_row_fill",
+                 100.0 * rows / jnp.maximum(walked, 1).astype(jnp.float32))
         self.sow("counters", "expert_load_max_over_mean",
                  sizes.max() * held / jnp.maximum(rows, 1.0))
         self.sow("counters", "expert_overflow_chunks", overflow_chunks(

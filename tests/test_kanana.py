@@ -197,7 +197,9 @@ class TestRoutedExperts:
         (0e9225a), read again by this code at ISSUE 33, whose row buffer
         changes every routed layer's program, and at ISSUE 35 (the whole
         model's text differs in the printed name of ``everything``'s
-        policy alone; the routed layer's pin stands)."""
+        policy alone; the routed layer's pin stands); read again where the
+        way back into the tokens became one kernel
+        (``ops/moe_combine.py``), which changes both programs."""
         layer = RoutedExperts(64, 896, 8, experts_held=(0, 16),
                               dtype=jnp.bfloat16)
         x = jax.ShapeDtypeStruct((1, 512, 2304), jnp.bfloat16)
@@ -205,7 +207,7 @@ class TestRoutedExperts:
             jax.random.key(0), jnp.zeros(x.shape, x.dtype))["params"])
         assert _jaxpr_pin(jax.grad(lambda p, x: layer.apply(
             {"params": p}, x).astype(jnp.float32).sum(), (0, 1)),
-            params, x) == "bf35b2a9b528ec55"
+            params, x) == "8daac4e0b7c2d2b5"
         model = get_model("mellum2_tiny", num_classes=1000, scan_layers=True,
                           remat_policy="everything")
         ids = jax.ShapeDtypeStruct((2, 64), jnp.int32)
@@ -213,7 +215,7 @@ class TestRoutedExperts:
             jax.random.key(0), jnp.zeros((2, 64), jnp.int32))["params"])
         assert _jaxpr_pin(jax.grad(lambda p, i: model.apply(
             {"params": p}, i, train=True).sum()), mp, ids) == \
-            "617100ca8765e6b3"
+            "d01798a2fb5e83df"
 
 
 def _jaxpr_pin(fn, *args) -> str:

@@ -168,10 +168,11 @@ def jaxpr_pins(engine, params, x):
 # ``_loss_and_metrics`` and ``eval_step`` to what they lowered to before
 # the row buffer came.  A PR that changes these programs on purpose reads
 # new pins (ISSUE 33 did for ``mellum2_tiny``: the routed layer's own row
-# buffer changes every sparse model's program).
+# buffer changes every sparse model's program; and so did the way back into
+# the tokens becoming one kernel, ``ops/moe_combine.py``).
 @pytest.mark.parametrize("name,model_kw,gathered,pins", [
     ("gpt_tiny", {"max_len": 64}, None, ("c57bfdbd21618319", "a1ab63707a8bd3de")),
-    ("mellum2_tiny", {"num_classes": 1000}, None, ("3e29d7343587c5c0", "b2860b11533c0216")),
+    ("mellum2_tiny", {"num_classes": 1000}, None, ("666f79f13a1d7820", "9785f970214b23a8")),
     ("bert_tiny", {"max_len": 64}, False, ("0c671134bb022f23", "449a1135f0dcdc7a")),
 ])
 def test_unmarked_programs_are_the_parents(devices, name, model_kw, gathered,

@@ -466,8 +466,11 @@ def _per_token_loop(toks, idx, weights, first, params):
 
 
 def _has_loop(jaxpr) -> bool:
-    return any(eqn.primitive.name == "while" or any(
-        _has_loop(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+    """A ``while`` outside the Pallas kernels, whose own walks are loops
+    of their grid steps: the overflow walk's."""
+    return any(eqn.primitive.name == "while" or (
+        eqn.primitive.name != "pallas_call" and any(
+            _has_loop(sub) for sub in jax.core.jaxprs_in_params(eqn.params)))
         for eqn in jaxpr.eqns)
 
 
@@ -503,7 +506,7 @@ class TestRowBuffer:
         toks, weights, params, probe = self._inputs(held)
 
         def program(toks, weights, params):
-            out, sizes = moe.routed_apply(
+            out, sizes, _ = moe.routed_apply(
                 toks, idx, weights, first, held,
                 moe.Experts(moe._swiglu_experts, params, width))
             return (out * probe).sum(), (out, sizes)
@@ -531,7 +534,7 @@ class TestRowBuffer:
         assert _has_loop(jaxpr) == (held != width)
 
     def test_the_same_step_twice_is_the_same_bits(self):
-        """The way back into the tokens is a scatter-add, in a fixed order:
+        """The way back into the tokens is one kernel, in a fixed order:
         one program run twice gives the same bits, overflow walked or not."""
         from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import moe
         toks, weights, params, probe = self._inputs(2)

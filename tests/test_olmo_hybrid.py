@@ -473,9 +473,12 @@ def _hlo_pin(name: str) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("name,pin", [("trinity_tiny", "8e5853079a309d1c"),
-                                      ("kanana2_tiny", "b6ae1d12f3f3c1a7")])
+@pytest.mark.parametrize("name,pin", [("trinity_tiny", "9bb8ec0286e5f2ef"),
+                                      ("kanana2_tiny", "b4e37fcb57666706")])
 def test_the_sparse_presets_lower_to_the_parents_programs(name, pin):
     """The shared record's new fields at their defaults change nothing: the
-    step's lowered program is the parent's (bdccbd5) text for text."""
+    step's lowered program is the parent's (bdccbd5) text for text, read
+    again where the way back into the tokens became one kernel
+    (``ops/moe_combine.py``), which changes every routed layer's
+    program."""
     assert _hlo_pin(name) == pin

@@ -144,26 +144,41 @@ def test_grouped_expert_products_lower_for_v5e(one_chip, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") == 8
 
 
-def test_routed_layer_on_its_row_buffer_lowers_for_v5e(one_chip, monkeypatch):
-    """trinity_mini_train_8k's routed layer (ISSUE 33), forward and
-    backward under ``jax.checkpoint``: 8 of 128 experts held, 8 a token,
-    8,192 tokens of 2,048.  Everything with 2,048 or 1,024 columns has the
-    buffer's 5,120 rows: no array of the worst case's 65,536 rows has more
-    than one column (the sort's int32 vectors do).  The grouped products,
-    chunk 0's and the overflow loop's, are the Mosaic calls of four
-    operands and one output that the accepted ``kernels/moe_gmm.json``
-    picks."""
+# the three sparse cells' routed layers: (router width, expert width, k,
+# held, model width, row buffer C)
+ROUTED_CELLS = {"mellum": (64, 896, 8, 16, 2304, 20480),
+                "kanana": (128, 768, 6, 16, 2048, 7680),
+                "trinity": (128, 1024, 8, 8, 2048, 5120)}
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_routed_layer_on_its_row_buffer_lowers_for_v5e(one_chip, monkeypatch,
+                                                       cell):
+    """A sparse cell's routed layer, forward and backward under
+    ``jax.checkpoint``: 8,192 tokens, ``held`` of the router's experts.
+    Everything with ``H`` or ``F`` columns has the buffer's ``C`` rows: no
+    array of the worst case's ``m_pad`` rows has more than one column (the
+    sort's int32 vectors do).  The grouped products, chunk 0's and the
+    overflow loop's, are the Mosaic calls of four operands and one output
+    that the accepted ``kernels/moe_gmm.json`` picks.  The way back into
+    the tokens is ``moe_combine``: five operands and one output, which no
+    picker file takes, so no roofline reads it as a grouped product; the
+    gradient of a sum needs the backward's two (chunk 0's and the overflow
+    loop's), the forward's being dead code.  XLA's scatter-add into the
+    ``f32[8192, H]`` sums is gone."""
     import re
     from learning_deep_neural_network_in_distributed_computing_environment_tpu.models import moe
     from learning_deep_neural_network_in_distributed_computing_environment_tpu.models.arch import Router
+    width, ffn, k, held, hidden, c = ROUTED_CELLS[cell]
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
-    assert moe.row_buffer(8192 * 8, 8, 128) == (65536, 5120)
-    layer = moe.RoutedExperts(128, 1024, 8, experts_held=(0, 8),
+    m_pad = 8192 * k
+    assert moe.row_buffer(m_pad, held, width) == (m_pad, c)
+    layer = moe.RoutedExperts(width, ffn, k, experts_held=(0, held),
                               dtype=jnp.bfloat16,
                               router=Router(score="sigmoid"))
     shaped = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                             sharding=one_chip)
-    x = shaped(jnp.zeros((1, 8192, 2048), jnp.bfloat16))
+    x = shaped(jnp.zeros((1, 8192, hidden), jnp.bfloat16))
     params = jax.tree_util.tree_map(shaped, jax.eval_shape(
         lambda: layer.init(jax.random.key(0), jnp.zeros(
             x.shape, x.dtype))["params"]))
@@ -171,15 +186,20 @@ def test_routed_layer_on_its_row_buffer_lowers_for_v5e(one_chip, monkeypatch):
         {"params": p}, x, mutable=["counters"])[0])
     text = jax.jit(jax.grad(lambda p, x: block(p, x).astype(
         jnp.float32).sum(), (0, 1))).lower(params, x).compile().as_text()
-    assert not re.findall(r"\w+\[65536,\d+\]", text)
-    assert re.findall(r"bf16\[5120,2048\]", text)
+    assert not re.findall(rf"\w+\[{m_pad},\d+\]", text)
+    assert re.findall(rf"bf16\[{c},{hidden}\]", text)
     calls = [line.strip() for line in text.splitlines()
              if "tpu_custom_call" in line]
+    picked = [kernel_of(line, load_kernels()) for line in calls]
     # chunk 0's 3 forward products, recomputed (the recomputed forward's
     # overflow loop is dead code: the backward recomputes those chunks
     # itself); the backward's 6 of chunk 0, and 3 + 6 in its loop
-    assert len(calls) == 18
-    assert {kernel_of(line, load_kernels()) for line in calls} == {"moe_gmm"}
+    assert picked.count("moe_gmm") == 18
+    combine = [p for line, p in zip(calls, picked) if "moe_combine" in line]
+    assert combine == [None, None]
+    assert len(calls) == 20
+    assert not [line for line in text.splitlines()
+                if re.search(rf"f32\[8192,{hidden}\]\S* scatter\(", line)]
 
 
 def _scanned_attention_grad(one_chip, heads, kv, d, dv, window, policy):

@@ -183,9 +183,12 @@ class TestProgramAgainstReference:
         changes every routed layer's program, and at ISSUE 35, where the
         text differs from the parent's in the printed name of the remat
         policy alone: ``save_only_these_names.<locals>.policy`` where it
-        read ``nothing_saveable``, and no equation)."""
-        for name, pin in (("mellum2_tiny", "617100ca8765e6b3"),
-                          ("kanana2_tiny", "031067d7d8319fe4")):
+        read ``nothing_saveable``, and no equation); read again where the
+        way back into the tokens became one kernel
+        (``ops/moe_combine.py``), which changes every routed layer's
+        program."""
+        for name, pin in (("mellum2_tiny", "d01798a2fb5e83df"),
+                          ("kanana2_tiny", "0bb7f85216ae7ab9")):
             model = get_model(name, num_classes=1000, scan_layers=True,
                               remat_policy="everything")
             ids = jax.ShapeDtypeStruct((2, 64), jnp.int32)
